@@ -178,20 +178,10 @@ def build_jpeg_tar_fixture(
     size: int = 256,
     quality: int = 87,
     seed: int = 0,
-    deadline_left_fn: Optional[Callable[[], Optional[float]]] = None,
-    deadline_margin_s: float = 60.0,
 ) -> str:
     """Write a tar of ``num_images`` synthetic JPEGs (block-textured so
     file sizes land near real photo entropy, ~20-40 KB at 256²). Cached:
-    an existing file at ``path`` with the right entry count is reused.
-
-    ``deadline_left_fn`` makes the build TIME-BUDGETED: the serial PIL
-    encode loop is the single longest uninterruptible phase of the bench
-    ingest leg (BENCH_r05 died inside it with a bare child timeout), so
-    when fewer than ``deadline_margin_s`` seconds remain the tar is
-    finalized with however many images were written — the measuring
-    phases downstream then report partial results instead of nothing.
-    """
+    an existing file at ``path`` with the right entry count is reused."""
     from PIL import Image
 
     if os.path.exists(path):
@@ -206,10 +196,6 @@ def build_jpeg_tar_fixture(
     tmp = path + ".tmp"
     with tarfile.open(tmp, "w") as tar:
         for i in range(num_images):
-            if deadline_left_fn is not None and i and i % 128 == 0:
-                left = deadline_left_fn()
-                if left is not None and left <= deadline_margin_s:
-                    break  # finalize a partial (still valid) fixture
             # Low-res random field upsampled ×8 + noise: JPEG-compressible
             # structure, photo-like size on disk.
             low = rng.integers(0, 256, (size // 8, size // 8, 3), dtype=np.uint8)

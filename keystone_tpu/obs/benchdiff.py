@@ -30,8 +30,8 @@ Comparison rules, per flattened leg key:
 
 Artifact formats accepted: the driver wrapper (``{"tail": ...}`` with
 the result JSON inside the tail — possibly truncated, in which case
-whole-leg objects are still recovered line-by-line), the bench's own
-single-line result / ``BENCH_PARTIAL.json`` dump, and a raw
+whole-leg objects are still recovered line-by-line), the single-line
+result of the older ``BENCH_r0N.json`` artifacts, and a raw
 ``BENCH_CHILD_JSON`` report. Stdlib-only: the CLI help path and CI can
 run this without jax.
 """
@@ -45,7 +45,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 # Leg-level keys that are run metadata, never measurements.
 _META_KEYS = {
-    "platform", "device_kind", "backend_init_s", "small_shapes",
+    "platform", "device_kind", "device_count", "backend_init_s", "small_shapes",
     "compilation_cache", "diagnostics", "metric", "value", "unit",
     "vs_baseline", "partial", "phase", "best_onchip_run",
 }

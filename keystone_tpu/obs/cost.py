@@ -10,9 +10,10 @@ decision downstream (the ROADMAP's measure-or-delete discipline). This
 module closes that loop:
 
 - **Harvest** — ``jax.stages.Lowered.cost_analysis()`` gives per-program
-  flop and byte counts. On jax 0.4.37, ``jitted.lower(*args)`` after the
-  function has executed hits the jit's trace cache: no re-trace, no
-  backend compile (``keystone_cost_harvest_compiles_total`` counts any
+  flop and byte counts. ``jitted.lower(*args)`` after the function has
+  executed hits the jit's trace cache: the function body does not run
+  again and nothing reaches the backend compiler (re-checked on jax
+  0.9.0; ``keystone_cost_harvest_compiles_total`` counts any
   violation of that invariant and must stay 0 — the explain smoke gates
   it). ``cost_analysis`` can return ``None``, a list, or a dict with
   missing keys depending on backend — every read is guarded here, and a

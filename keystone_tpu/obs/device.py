@@ -92,23 +92,19 @@ def memory_snapshot() -> Dict[str, Any]:
     """Best-available memory numbers right now.
 
     Returns ``{"source": "device"|"rss", "bytes_in_use": int,
-    "peak_bytes_in_use": int}``; device stats only when the backend
-    exposes them (TPU/GPU — CPU meshes report RSS)."""
-    try:
-        import jax
+    "peak_bytes_in_use": int}``: the fullest local device's stats when
+    the backend exposes them (TPU/GPU — CPU meshes report RSS)."""
+    from ..parallel.mesh import local_memory_stats
 
-        dev = jax.devices()[0]
-        stats = dev.memory_stats()
-        if stats and "bytes_in_use" in stats:
-            return {
-                "source": "device",
-                "bytes_in_use": int(stats.get("bytes_in_use", 0)),
-                "peak_bytes_in_use": int(
-                    stats.get("peak_bytes_in_use", stats.get("bytes_in_use", 0))
-                ),
-            }
-    except Exception:
-        pass
+    stats = [s for s in local_memory_stats() if "bytes_in_use" in s]
+    if stats:
+        return {
+            "source": "device",
+            "bytes_in_use": max(int(s["bytes_in_use"]) for s in stats),
+            "peak_bytes_in_use": max(
+                int(s.get("peak_bytes_in_use", s["bytes_in_use"])) for s in stats
+            ),
+        }
     return {
         "source": "rss",
         "bytes_in_use": rss_bytes(),

@@ -23,7 +23,7 @@ valid:
 
 Every entry additionally carries an **environment fingerprint** (jax
 version, backend, device kind). A fingerprint mismatch at lookup time
-invalidates the entry — a store written under jax 0.4.37 on a v5e says
+invalidates the entry — a store written under one jax on a v5e says
 nothing about the next jax on a v6 — counted in
 ``keystone_profile_store_invalidations_total``.
 
@@ -546,16 +546,18 @@ def store_enabled() -> bool:
 
 def default_store_path() -> str:
     """The store file location: ``KEYSTONE_PROFILE_STORE`` when it names
-    a path, else ``profile-store.jsonl`` under the same root as the XLA
-    compilation cache (the two persistence layers travel together)."""
+    a path, else ``profile-store.jsonl`` beside the XLA compilation cache
+    (the two persistence layers travel together: wherever
+    utils/compilation_cache.py resolves the cache — the launcher's
+    ``JAX_COMPILATION_CACHE_DIR``, the KEYSTONE knob, or the fixed
+    in-checkout path)."""
+    from ..utils.compilation_cache import STATE_ROOT, resolve_cache_dir
+
     env = env_str("KEYSTONE_PROFILE_STORE")
     if env and env.lower() not in ("on", "1", "true"):
         return env
-    cache = env_str("KEYSTONE_COMPILATION_CACHE")
-    if cache and cache.lower() not in ("off", "0", "disabled"):
-        root = os.path.dirname(cache.rstrip(os.sep)) or cache
-    else:
-        root = os.path.join(os.path.expanduser("~"), ".cache", "keystone_tpu")
+    cache = resolve_cache_dir()
+    root = (os.path.dirname(cache.rstrip(os.sep)) or cache) if cache else STATE_ROOT
     return os.path.join(root, "profile-store.jsonl")
 
 

@@ -129,8 +129,7 @@ class SIFTExtractor(BatchTransformer):
         # reference's x512 quantization, while bf16 SMOOTHING fails the
         # 99.5%-within-1 gate (97.5%) because the gradient stencil
         # amplifies its rounding — so the smoother is always fp32.
-        # Default fp32; flip after an on-chip throughput A/B
-        # (docs/NEXT_LEVERS.md item 3).
+        # Default fp32; flip after an on-chip throughput A/B.
         self.binning_dtype = binning_dtype
 
     @property

@@ -323,9 +323,8 @@ def _conv_bcd_step_fn(
         out_specs=(P(), P(axes, None), P(), P()),
     )
     # arg 3 is the loop-owned residual carry, rebuilt every call from
-    # this jit's own output. Suppressed where the persistent cache makes
-    # donation unsound (linalg.donation_safe).  # keystone: owns-donated
-    return jax.jit(fn, donate_argnums=(3,) if linalg.donation_safe() else ())
+    # this jit's own output.  # keystone: owns-donated
+    return jax.jit(fn, donate_argnums=(3,))
 
 
 def _round_up(x: int, m: int) -> int:

@@ -76,12 +76,9 @@ def default_cost_weights(backend: Optional[str] = None) -> CostWeights:
     TPU on accelerators; the reference's cluster constants on CPU (where
     they keep solver choices aligned with the reference's behavior)."""
     if backend is None:
-        try:
-            import jax
+        import jax
 
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
+        backend = jax.default_backend()  # a backend that fails to start raises
     if backend == "cpu":
         return DEFAULT_COST_WEIGHTS
     return measured_tpu_weights() or tpu_weights()

@@ -14,16 +14,18 @@ so the device kernels run a static grid with no host-side raggedness.
 
 Two interchangeable implementations of one interface:
 
-- ``impl="pallas"`` — a TPU Pallas kernel: grid over block rows, the ELL
-  column indices scalar-prefetched into SMEM
-  (``PrefetchScalarGridSpec``), each program ``fori_loop``-ing its K
-  slots, gathering the matching (bn, N) panel of the dense operand with a
-  dynamic ``pl.ds`` load and accumulating on the MXU. Selected
-  automatically on a TPU backend; ``interpret=True`` runs the same kernel
-  on CPU for parity tests ONLY (it is not a fast path).
-- ``impl="lax"`` — a ``jax.lax`` block-gather fallback (take + einsum /
-  scatter-add) with identical semantics, the default off-TPU. CI gates
-  interpret-vs-fallback parity at ≤1e-5 (scripts/tune_smoke.sh).
+- ``impl="pallas"`` — a TPU Pallas kernel: grid over (block row, output
+  column tile, ELL slot), the ELL column indices scalar-prefetched into
+  SMEM (``PrefetchScalarGridSpec``) where they drive the dense operand's
+  ``index_map`` — each grid step DMAs the one (bn, tile) panel its slot
+  names and accumulates on the MXU into a resident output tile. Selected
+  on a TPU backend, where ``auto`` runs this kernel or raises — it never
+  gives way to ``lax``. ``interpret=True`` runs the same kernel on CPU
+  for parity tests ONLY (it is not a fast path).
+- ``impl="lax"`` — a ``jax.lax`` block-gather path (take + einsum) with
+  identical semantics, what ``auto`` means off-TPU. CI gates
+  interpret-vs-lax parity at ≤1e-5 (scripts/tune_smoke.sh); the compiled
+  kernel is checked against ``lax`` on the chip by ``chip_smoke.py``.
 
 Gram accumulation (``bsr_gram_totals``) returns the SAME raw sufficient
 statistics tuple as ``linalg.gram_stream_init``'s carry — (AᵀA, AᵀY, Σx,
@@ -113,18 +115,14 @@ def density_threshold(rows: Optional[str] = None) -> float:
     return DEFAULT_DENSITY_THRESHOLD
 
 
-def _backend() -> str:
-    import jax
-
-    try:
-        return jax.devices()[0].platform
-    except Exception:
-        return "cpu"
-
-
 def resolve_impl(impl: str = "auto") -> str:
+    """``auto`` → the compiled Pallas kernel on a TPU, ``lax`` elsewhere.
+    A backend that fails to initialize raises here; it is not read as
+    "cpu"."""
     if impl == "auto":
-        return "pallas" if _backend() == "tpu" else "lax"
+        import jax
+
+        return "pallas" if jax.default_backend() == "tpu" else "lax"
     return impl
 
 
@@ -151,56 +149,92 @@ def _ell_matmul_lax_fn(bm: int, bn: int, precision):
     return jax.jit(run)
 
 
-
-
 # ------------------------------------------------------------ pallas kernel
 
+#: Output-column tile of the Pallas kernel: one (bn, _N_TILE) panel of the
+#: dense operand and one (bm, _N_TILE) accumulator are resident per grid
+#: step (256 KiB + 16 KiB at the default 8×128 feature tile, double
+#: buffered — far inside VMEM at any operand size).
+_N_TILE = 512
 
-def _ell_matmul_pallas(indices, blocks, b, *, bm, bn, interpret):
-    """The Pallas TPU kernel (docstring up top): one program per block
-    row, ELL indices scalar-prefetched, K-slot ``fori_loop`` gathering
-    (bn, N) panels of ``b`` with dynamic ``pl.ds`` loads."""
+
+def _ell_matmul_pallas(indices, blocks, b, *, bm, bn, precision, interpret):
+    """The Pallas TPU kernel (docstring up top): grid (block row, output
+    column tile, ELL slot). The ELL column indices are scalar-prefetched
+    and drive the dense operand's ``index_map``, so Pallas itself
+    double-buffers the (bn, tile) panel DMAs out of HBM; the output tile
+    stays resident across the innermost slot axis and accumulates."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     nbr, k_slots = indices.shape
-    d_pad, n_out = b.shape
+    n_out = b.shape[1]
+    if not interpret and (bm % 8 or bn % 8):
+        raise ValueError(
+            f"compiled block-sparse kernel needs tile dims in multiples of "
+            f"8 (float32 sublanes), got {bm}x{bn}"
+        )
+    # Lane alignment: output columns padded to whole tiles, cropped below.
+    tn = min(_N_TILE, -(-n_out // 128) * 128)
+    n_pad = -(-n_out // tn) * tn
+    if n_pad != n_out:
+        b = jnp.pad(b, ((0, 0), (0, n_pad - n_out)))
 
     def kernel(idx_ref, blocks_ref, b_ref, o_ref):
-        i = pl.program_id(0)
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            o_ref[...] = jnp.zeros_like(o_ref)
 
-        def body(k, acc):
-            j = idx_ref[i, k]
-            blk = blocks_ref[0, k]
-            panel = pl.load(b_ref, (pl.ds(j * bn, bn), slice(None)))
-            return acc + jnp.dot(
-                blk, panel, preferred_element_type=jnp.float32
-            )
-
-        acc = jax.lax.fori_loop(
-            0, k_slots, body, jnp.zeros((bm, n_out), jnp.float32)
+        o_ref[...] += jnp.dot(
+            blocks_ref[0, 0], b_ref[...],
+            precision=precision, preferred_element_type=jnp.float32,
         )
-        o_ref[...] = acc
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(nbr,),
+        grid=(nbr, n_pad // tn, k_slots),
         in_specs=[
             pl.BlockSpec(
-                (1, k_slots, bm, bn), lambda i, idx_ref: (i, 0, 0, 0)
+                (1, 1, bm, bn), lambda i, j, k, idx_ref: (i, k, 0, 0)
             ),
-            pl.BlockSpec((d_pad, n_out), lambda i, idx_ref: (0, 0)),
+            # Padded slots point at panel 0 against a zero block: inert.
+            pl.BlockSpec(
+                (bn, tn), lambda i, j, k, idx_ref: (idx_ref[i * k_slots + k], j)
+            ),
         ],
-        out_specs=pl.BlockSpec((bm, n_out), lambda i, idx_ref: (i, 0)),
+        out_specs=pl.BlockSpec((bm, tn), lambda i, j, k, idx_ref: (i, j)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nbr * bm, n_out), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nbr * bm, n_pad), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(indices, blocks, b)
+        name="ell_matmul",
+        # Indices flattened: a 2-D SMEM array pads its minor dim to 128.
+    )(indices.reshape(-1), blocks, b)
+    return out[:, :n_out]
+
+
+@functools.lru_cache(maxsize=None)
+def _ell_matmul_pallas_fn(bm: int, bn: int, precision, interpret: bool):
+    import jax
+    from jax import lax
+
+    # Mosaic contracts at DEFAULT (one bf16 pass) or HIGHEST (fp32) only;
+    # the 3-pass HIGH the lax path accepts rounds up.
+    if precision == lax.Precision.HIGH:
+        precision = lax.Precision.HIGHEST
+    return jax.jit(
+        functools.partial(
+            _ell_matmul_pallas, bm=bm, bn=bn, precision=precision,
+            interpret=interpret,
+        )
+    )
 
 
 # -------------------------------------------------------------- public API
@@ -235,13 +269,12 @@ def ell_matmul(
         raise ValueError(
             f"dense operand rows {b.shape[0]} not a multiple of bn={bn}"
         )
+    precision = _precision(precision)
     if impl == "pallas":
-        return _ell_matmul_pallas(
-            indices, blocks, b, bm=bm, bn=bn, interpret=interpret
+        return _ell_matmul_pallas_fn(bm, bn, precision, bool(interpret))(
+            indices, blocks, b
         )
-    return _ell_matmul_lax_fn(bm, bn, _precision(precision))(
-        indices, blocks, b
-    )
+    return _ell_matmul_lax_fn(bm, bn, precision)(indices, blocks, b)
 
 
 def bsr_matmul(

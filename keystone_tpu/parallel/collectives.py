@@ -24,22 +24,8 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # newer jax exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The replication-check kwarg was renamed check_rep → check_vma; pick by
-# signature, not import location (top-level shard_map existed with either).
-import inspect as _inspect
-
-_CHECK_KWARG = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
 
 from .mesh import DATA_AXIS, get_mesh
 
@@ -47,8 +33,9 @@ from .mesh import DATA_AXIS, get_mesh
 def shard_map(f, mesh=None, in_specs=None, out_specs=None, check_vma=False):
     """Thin wrapper pinning this framework's defaults."""
     mesh = mesh or get_mesh()
-    kwargs = {_CHECK_KWARG: check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    return _shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
+    )
 
 
 def allreduce_sum(x: jnp.ndarray, axis: str = DATA_AXIS) -> jnp.ndarray:

@@ -210,11 +210,8 @@ def distributed_init(
         "SLURM_JOB_ID", "TPU_WORKER_HOSTNAMES", "MEGASCALE_COORDINATOR_ADDRESS",
     )
     in_cluster = explicit or any(env_set(v) for v in cluster_signals)
-    try:
-        if jax.distributed.is_initialized():
-            return
-    except AttributeError:
-        pass  # older jax without is_initialized
+    if jax.distributed.is_initialized():
+        return
     try:
         if explicit:
             jax.distributed.initialize(
@@ -266,20 +263,34 @@ def num_devices() -> int:
     return len(jax.devices())
 
 
+def local_memory_stats() -> list:
+    """``memory_stats()`` of every local device that reports them — one
+    dict per chip, so a residency decision sees the fullest chip, not
+    chip 0. Empty on backends that report none (CPU test meshes)."""
+    return [s for s in (d.memory_stats() for d in jax.local_devices()) if s]
+
+
+def device_memory_limit_bytes() -> Optional[int]:
+    """The smallest ``bytes_limit`` over the local devices, or None where
+    the backend reports no memory statistics (CPU test meshes)."""
+    limits = [int(s["bytes_limit"]) for s in local_memory_stats() if "bytes_limit" in s]
+    return min(limits) if limits else None
+
+
 def device_memory_budget_bytes(fraction: float = 0.75) -> int:
     """Per-device memory budget for residency planning.
 
     Analog of the reference's 75%-of-cluster-free-memory default cache
-    budget (reference: workflow/AutoCacheRule.scala:572-585). Falls back to
-    a conservative constant when the platform exposes no memory stats
+    budget (reference: workflow/AutoCacheRule.scala:572-585), taken on
+    the local device with the least headroom. Falls back to a
+    conservative constant when the platform exposes no memory stats
     (CPU test meshes).
     """
-    dev = jax.devices()[0]
-    try:
-        stats = dev.memory_stats()
-        if stats and "bytes_limit" in stats:
-            in_use = stats.get("bytes_in_use", 0)
-            return int((stats["bytes_limit"] - in_use) * fraction)
-    except Exception:
-        pass
+    headroom = [
+        s["bytes_limit"] - s.get("bytes_in_use", 0)
+        for s in local_memory_stats()
+        if "bytes_limit" in s
+    ]
+    if headroom:
+        return int(min(headroom) * fraction)
     return int(4e9 * fraction)

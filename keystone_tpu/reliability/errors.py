@@ -5,15 +5,15 @@ partition was recomputed from its parents, and the framework never had to
 name its failure modes. The TPU-native port executes through a memoizing
 in-process interpreter, so failures must be classified explicitly:
 
-- ``TRANSIENT``   — relay/coordinator hiccups, preemptions, dropped
+- ``TRANSIENT``   — coordinator hiccups, preemptions, dropped
                     connections. Worth retrying with backoff (retry.py).
 - ``OOM``         — RESOURCE_EXHAUSTED / allocator failures. Retrying the
                     same shape re-OOMs; the recovery is a
                     :class:`~keystone_tpu.reliability.degrade.DegradationLadder`
                     rung at a smaller block/batch size.
-- ``DEADLINE``    — a node ran past its execution deadline (a hung relay
-                    looks like an infinite compile). Retryable: the retry
-                    re-dispatches, usually onto a healthy channel.
+- ``DEADLINE``    — a node ran past its execution deadline (a hung
+                    channel looks like an infinite compile). Retryable:
+                    the retry re-dispatches, usually onto a healthy one.
 - ``CORRUPT_DATA``— undecodable / malformed input records. Neither retry
                     nor shrinking helps; the recovery is skip-and-quarantine
                     at the ingest layer (data/ingest.py, data/loaders/*).
@@ -49,7 +49,7 @@ class CorruptRecordError(ValueError):
 
 
 # (class, uppercase substrings of str(exc)) — first match wins, in order.
-# OOM before TRANSIENT: an OOM raised through a relay RPC can carry both
+# OOM before TRANSIENT: an OOM raised through an RPC layer can carry both
 # RESOURCE_EXHAUSTED and connection noise in one message, and shrinking is
 # the recovery that actually converges.
 CLASSIFICATION_TABLE: Tuple[Tuple[ErrorClass, Tuple[str, ...]], ...] = (

@@ -60,7 +60,7 @@ class InjectedOOM(RuntimeError):
 
 
 class InjectedTransient(ConnectionError):
-    """Injected relay/coordinator failure; message classifies as transient."""
+    """Injected coordinator failure; message classifies as transient."""
 
     def __init__(self, label: str):
         super().__init__(f"UNAVAILABLE: injected transient fault at {label}")

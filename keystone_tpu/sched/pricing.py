@@ -160,16 +160,12 @@ def _residency_budget_bytes() -> int:
     explicit = env_int("KEYSTONE_SCHED_RESIDENCY_BYTES", 0)
     if explicit > 0:
         return explicit
-    try:
-        import jax
+    from ..parallel.mesh import device_memory_limit_bytes
 
-        stats = jax.devices()[0].memory_stats()  # keystone: allow-sync
-        limit = int((stats or {}).get("bytes_limit", 0))
-        if limit > 0:
-            # Same fraction KV304 allows a fit's working set.
-            return limit // 4
-    except Exception:
-        pass
+    limit = device_memory_limit_bytes()
+    if limit:
+        # Same fraction KV304 allows a fit's working set.
+        return limit // 4
     return 256 * 1024 * 1024
 
 

@@ -189,18 +189,27 @@ class BootImageModel:
 
 
 def _active_cache_dir() -> Optional[str]:
-    try:
-        import jax
-
-        return jax.config.jax_compilation_cache_dir or None
-    except Exception:
-        return None
-
-
-def _set_cache_dir(target: Optional[str]) -> None:
+    """The process's persistent-cache directory, enabling the program's
+    own (utils/compilation_cache.py) when none is configured yet. Boot
+    images copy entries out of and into this directory; they never
+    re-point it — where the cache lives is the launcher's decision
+    (``JAX_COMPILATION_CACHE_DIR``) or the one fixed path."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", target)
+    from ..utils.compilation_cache import enable_persistent_cache
+
+    return enable_persistent_cache() or jax.config.jax_compilation_cache_dir or None
+
+
+def _cache_entries(cache_dir: Optional[str]) -> set:
+    if not cache_dir or not os.path.isdir(cache_dir):
+        return set()
+    return set(os.listdir(cache_dir))
+
+
+#: File-name prefix jax gives the persistent-cache entries of
+#: ``Exported.call`` programs — what a boot image's executables compile to.
+_EXPORTED_ENTRY_PREFIX = "jit_call_exported-"
 
 
 def build_boot_image(
@@ -242,35 +251,42 @@ def build_boot_image(
     os.makedirs(image_cache, exist_ok=True)
 
     # Export each bucket at FULL occupancy (see module docstring), then
-    # immediately round-trip it through deserialize+call with the image's
-    # own cache dir active — that one call is what writes the persistent
-    # cache entries a loading worker will hydrate from.
+    # immediately round-trip it through deserialize+call — that one call
+    # is what writes the persistent cache entries a loading worker will
+    # hydrate from.
     def fn(data):
         out = batch_apply(ArrayDataset(data))
         return getattr(out, "data", out)
 
+    from ..utils.compilation_cache import cache_hit_count, install_compile_counter
+
     executables: Dict[int, Any] = {}
     files: Dict[str, str] = {}
-    prior_cache = _active_cache_dir()
-    from ..utils.compilation_cache import enable_persistent_cache
-
-    enable_persistent_cache(image_cache)
-    try:
-        for b in buckets:
-            in_spec = jax.ShapeDtypeStruct((b,) + example.shape, example.dtype)
-            blob = jax_export.export(jax.jit(fn))(in_spec).serialize()
-            filename = f"bucket_{b}.bin"
-            with open(os.path.join(out_dir, filename), "wb") as f:
-                f.write(bytes(blob))
-            files[str(b)] = filename
-            executables[b] = jax_export.deserialize(blob)
-            jax.block_until_ready(
-                executables[b].call(
-                    np.zeros((b,) + example.shape, example.dtype)
-                )
+    active = _active_cache_dir()
+    install_compile_counter()
+    before, hits_before = _cache_entries(active), cache_hit_count()
+    for b in buckets:
+        in_spec = jax.ShapeDtypeStruct((b,) + example.shape, example.dtype)
+        blob = jax_export.export(jax.jit(fn))(in_spec).serialize()
+        filename = f"bucket_{b}.bin"
+        with open(os.path.join(out_dir, filename), "wb") as f:
+            f.write(bytes(blob))
+        files[str(b)] = filename
+        executables[b] = jax_export.deserialize(blob)
+        jax.block_until_ready(
+            executables[b].call(
+                np.zeros((b,) + example.shape, example.dtype)
             )
-    finally:
-        _set_cache_dir(prior_cache)
+        )
+    # Bundle what those calls wrote. A program the active cache already
+    # held wrote nothing new and its entry cannot be told from other
+    # exported programs', so after any hit every exported-call entry is
+    # bundled: a superset, never a gap.
+    bundle = _cache_entries(active) - before
+    if cache_hit_count() > hits_before:
+        bundle |= {n for n in before if n.startswith(_EXPORTED_ENTRY_PREFIX)}
+    for name in bundle:
+        shutil.copy2(os.path.join(active, name), os.path.join(image_cache, name))
 
     with open(os.path.join(out_dir, WEIGHTS), "wb") as f:
         pickle.dump(entry.model, f)
@@ -331,15 +347,9 @@ def _parity_gate(manifest, executables, entry, example) -> None:
 
 def _install_cache_entries(image_cache: str) -> None:
     """Make the image's bundled persistent-cache entries visible to this
-    process: copy them into the active cache dir, or point the cache at
-    the image's bundle when none is configured."""
-    if not os.path.isdir(image_cache):
-        return
+    process by copying them into the active cache directory."""
     active = _active_cache_dir()
-    if active is None:
-        from ..utils.compilation_cache import enable_persistent_cache
-
-        enable_persistent_cache(image_cache)
+    if active is None or not os.path.isdir(image_cache):
         return
     if os.path.abspath(active) == os.path.abspath(image_cache):
         return

@@ -331,7 +331,11 @@ def serve_multiworker_from_args(args) -> int:
     default_deadline_s = (
         args.deadline_ms / 1e3 if getattr(args, "deadline_ms", None) else None
     )
-    supervisor = WorkerSupervisor(spec, config).start()
+    try:
+        supervisor = WorkerSupervisor(spec, config).start()
+    except RuntimeError as exc:  # more workers than chips, or no backend
+        print(f"serve: {exc}", file=sys.stderr)
+        return 2
     # --autoscale closes the loop between SLO pressure and fleet size
     # (docs/SERVING.md "Elastic fleet"): the supervisor starts at
     # --workers and the autoscaler moves it within [--min-workers,
@@ -347,8 +351,11 @@ def serve_multiworker_from_args(args) -> int:
                 if args.slo_p99_ms is not None
                 else AutoscalerConfig.target_p99_ms,
                 min_workers=getattr(args, "min_workers", None) or 1,
-                max_workers=getattr(args, "max_workers", None)
-                or max(4, args.workers),
+                # On a TPU host the fleet cannot outgrow its chips.
+                max_workers=min(
+                    getattr(args, "max_workers", None) or max(4, args.workers),
+                    supervisor.worker_ceiling or sys.maxsize,
+                ),
             ),
         ).start()
     frontend = None

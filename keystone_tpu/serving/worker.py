@@ -223,6 +223,9 @@ class StubBackend:
     def close(self) -> None:
         pass
 
+    def devices(self) -> list:
+        return []  # no backend loaded
+
 
 class ServerBackend:
     """The real thing: a :class:`~keystone_tpu.serving.server.
@@ -372,6 +375,16 @@ class ServerBackend:
     def close(self) -> None:
         self.server.stop(drain=True)
 
+    def devices(self) -> list:
+        """The devices this worker process holds, for the ready message:
+        on a TPU host, the evidence that it got one chip of its own."""
+        import jax
+
+        return [
+            f"{d.platform}:{d.id}@{getattr(d, 'coords', None)}"
+            for d in jax.local_devices()
+        ]
+
 
 def _load_spec(registry, name: str, spec: Dict[str, Any]) -> Optional[Any]:
     """Publish one model described by ``spec`` into ``registry``; returns
@@ -446,6 +459,7 @@ def main(argv: Optional[list] = None) -> int:
             "pid": os.getpid(),
             "mode": backend.mode,
             "boot_image": getattr(backend, "boot_image", None),
+            "devices": backend.devices(),
             "init_s": round(time.monotonic() - t0, 3),
             # Clock anchor for the fleet trace's alignment handshake.
             "clock": {"unix": time.time(), "perf": time.perf_counter()},

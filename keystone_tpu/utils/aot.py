@@ -1,7 +1,7 @@
 """Ahead-of-time compilation for known-shape flagship configs.
 
 The flagship's cold numbers were dominated by XLA compiles, not compute
-(r3: GMM fit 29.4 s cold ≈ ~100 ms of EM + compile; docs/NEXT_LEVERS.md).
+(round 3: GMM fit 29.4 s cold ≈ ~100 ms of EM + compile; not re-measured).
 The persistent compilation cache (``utils.compilation_cache``) already
 makes every SECOND process fast; this module closes the remaining gap —
 the first-ever run — by tracing + compiling the streaming flagship's

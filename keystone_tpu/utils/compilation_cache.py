@@ -1,118 +1,134 @@
 """Persistent XLA compilation cache.
 
-First compilation of a solver or featurizer program on TPU costs
-~20-40 s — on short workloads (a GMM fit, a per-class solve) that is the
-dominant wall-clock, and every new process pays it again. Pointing JAX's
-persistent compilation cache at a shared directory makes the second and
-later runs (including separate bench child processes) load the compiled
-executable from disk instead.
+First compilation of a solver or featurizer program on TPU costs tens of
+seconds — on short workloads (a GMM fit, a per-class solve) that is the
+dominant wall-clock, and every new process pays it again. JAX's
+persistent compilation cache makes the second and later runs load the
+compiled executable from disk instead.
 
-The reference had no analogous cost (JVM bytecode + native kernels were
-ahead-of-time compiled); enabling this by default in the CLI and bench is
-what makes repeat-run wall-clock comparable to an AOT framework.
+Where the cache lives (one place per launch environment: a directory
+that moves between runs never hits):
 
-Env knobs:
-  KEYSTONE_COMPILATION_CACHE       cache dir (default
-                                   ~/.cache/keystone_tpu/xla-cache)
-  KEYSTONE_COMPILATION_CACHE=off   disable entirely
+1. ``JAX_COMPILATION_CACHE_DIR`` set: JAX itself reads it at import and
+   this module sets NO directory in code — whoever launched the process
+   (a chip tool, a deployment) owns the placement.
+2. else ``KEYSTONE_COMPILATION_CACHE=<dir>`` (tests isolate with it);
+   ``KEYSTONE_COMPILATION_CACHE=off`` disables the program's own set-up.
+3. else one fixed, gitignored path inside the checkout:
+   ``<repo>/.keystone_cache/xla-cache`` — never ``~/.cache``, which does
+   not travel with the tree.
 """
 
 from __future__ import annotations
 
-import logging
 import os
 
 from ..envknobs import env_disabled, env_str
 from typing import Callable
 
-_DEFAULT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "keystone_tpu", "xla-cache"
+#: Root of the program's own persistent state (XLA cache + profile
+#: store), next to the package directory: inside the checkout, listed in
+#: .gitignore.
+STATE_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".keystone_cache",
 )
+_DEFAULT_DIR = os.path.join(STATE_ROOT, "xla-cache")
 
 
-def enable_persistent_cache(cache_dir: str | None = None) -> str | None:
-    """Enable JAX's on-disk compilation cache; returns the dir (or None
-    when disabled/unavailable). Safe to call more than once and before
-    any backend is initialized (it only sets jax config values)."""
-    env = env_str("KEYSTONE_COMPILATION_CACHE")
+def resolve_cache_dir() -> "str | None":
+    """The directory the persistent cache lives in under the rules in the
+    module docstring; None when disabled. Pure: touches neither jax nor
+    the filesystem."""
     if env_disabled("KEYSTONE_COMPILATION_CACHE"):
         return None
-    target = cache_dir or env or _DEFAULT_DIR
-    try:
-        import jax
+    return (
+        env_str("JAX_COMPILATION_CACHE_DIR")
+        or env_str("KEYSTONE_COMPILATION_CACHE")
+        or _DEFAULT_DIR
+    )
 
+
+def enable_persistent_cache() -> "str | None":
+    """Enable JAX's on-disk compilation cache; returns the directory (None
+    when disabled). Safe to call more than once and before any backend is
+    initialized (it only sets jax config values). A directory that cannot
+    be created raises: a run that silently recompiles everything is a
+    slower run nobody can explain."""
+    import jax
+
+    target = resolve_cache_dir()
+    if target is None:
+        return None
+    if not env_str("JAX_COMPILATION_CACHE_DIR"):
         os.makedirs(target, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", target)
-        # Cache every program: the workloads here are few large programs,
-        # not thousands of tiny ones, so the default 1 MiB floor and 1 s
-        # compile-time floor would skip exactly the entries we want warm.
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        return target
-    except Exception as e:  # never let cache plumbing break a workload
-        logging.getLogger(__name__).warning(
-            "persistent compilation cache unavailable (%s)", e
-        )
-        return None
-
-
-def persistent_cache_active() -> bool:
-    """True when a persistent compilation cache directory is configured
-    (via :func:`enable_persistent_cache` or raw jax config). Donation
-    sites consult this: see :func:`~keystone_tpu.parallel.linalg.
-    donation_safe` for the CPU deserialized-executable aliasing hazard."""
-    try:
-        import jax
-
-        return bool(jax.config.jax_compilation_cache_dir)
-    except Exception:
-        return False
+    # Cache every program: the workloads here are few large programs,
+    # not thousands of tiny ones, so the default 1 MiB floor and 1 s
+    # compile-time floor would skip exactly the entries we want warm.
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return target
 
 
 # ------------------------------------------------------- compile accounting
 
-# Backend-compile event counter. The serving layer warms a fixed bucket
-# set and then asserts (in tests) / reports (in telemetry) that steady-
-# state traffic triggers ZERO further XLA compiles — the counter is the
+# Backend-compile counter. The serving layer warms a fixed bucket set and
+# then asserts (in tests) / reports (in telemetry) that steady-state
+# traffic triggers ZERO further XLA compiles — the counter is the
 # evidence. jax.monitoring fires one
-# "/jax/core/compile/backend_compile_duration" event per executable
-# actually built (cache hits, persistent or in-memory, don't fire).
+# "/jax/core/compile/backend_compile_duration" event per program that
+# missed the in-memory executable cache. That INCLUDES programs then
+# loaded from the persistent cache (checked on jax 0.9.0): for a steady
+# state both are a stall, so both count. The persistent loads announce
+# themselves with "/jax/compilation_cache/cache_hits" and are counted on
+# the side, so a warm run can show how many programs it really built:
+# ``compile_count() - cache_hit_count()``.
 _COMPILE_EVENT_SUBSTRING = "backend_compile"
-_compile_events = {"count": 0}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_compile_events = {"count": 0, "cache_hits": 0}
 _counter_installed = False
 
 
 def install_compile_counter() -> Callable[[], int]:
-    """Idempotently register a jax.monitoring listener counting backend
-    compiles; returns :func:`compile_count`. Registration is permanent
-    for the process (jax.monitoring has no unregister), which is fine:
-    the listener is one substring check per compile event."""
+    """Idempotently register the jax.monitoring listeners behind
+    :func:`compile_count` (which it returns) and
+    :func:`cache_hit_count`. Registration is permanent for the process
+    (jax.monitoring has no unregister), which is fine: each listener is
+    one string check per event. Raises if jax.monitoring cannot register
+    — a dead counter would read as 'no recompiles'."""
     global _counter_installed
     if not _counter_installed:
-        try:
-            import jax.monitoring
+        import jax.monitoring
 
-            def _listener(event: str, duration: float, **kw) -> None:
-                if _COMPILE_EVENT_SUBSTRING in event:
-                    _compile_events["count"] += 1
-                    # Mirror into the metrics registry so Prometheus
-                    # snapshots carry the compile count without callers
-                    # having to diff compile_count() themselves.
-                    from ..obs import names as _names
+        def _on_event(event: str, **kw) -> None:
+            if event == _CACHE_HIT_EVENT:
+                _compile_events["cache_hits"] += 1
 
-                    _names.metric(_names.XLA_COMPILES).inc()
+        def _on_duration(event: str, duration: float, **kw) -> None:
+            if _COMPILE_EVENT_SUBSTRING in event:
+                _compile_events["count"] += 1
+                # Mirror into the metrics registry so Prometheus
+                # snapshots carry the compile count without callers
+                # having to diff compile_count() themselves.
+                from ..obs import names as _names
 
-            jax.monitoring.register_event_duration_secs_listener(_listener)
-            _counter_installed = True
-        except Exception as e:  # same contract as the cache: never fatal
-            logging.getLogger(__name__).warning(
-                "compile counter unavailable (%s)", e
-            )
+                _names.metric(_names.XLA_COMPILES).inc()
+
+        jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _counter_installed = True
     return compile_count
 
 
 def compile_count() -> int:
-    """Backend compiles observed since :func:`install_compile_counter`
-    (0 if never installed — callers diff snapshots, so a dead counter
-    reads as 'no recompiles' rather than an error)."""
+    """Programs that missed the in-memory executable cache since
+    :func:`install_compile_counter` (0 if never installed): built by the
+    backend or loaded from the persistent cache."""
     return _compile_events["count"]
+
+
+def cache_hit_count() -> int:
+    """The part of :func:`compile_count` that was loaded from the
+    persistent cache instead of built."""
+    return _compile_events["cache_hits"]

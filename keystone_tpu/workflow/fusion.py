@@ -1,9 +1,8 @@
 """Whole-pipeline XLA fusion: collapse transformer chains into one dispatch.
 
 The executor launches every transformer node as its own XLA dispatch with
-a host round-trip between nodes — and on relay-backed attachments the
-round-trip dwarfs the kernel time (BENCH_r05 gram leg: 97.9 ms dispatch
-vs 8.3 ms bf16 compute). This module closes that gap at the *plan* level:
+a host round-trip between nodes. This module closes that gap at the
+*plan* level:
 :class:`NodeFusionRule` rewrites maximal chains of array-in/array-out
 transformers (``BatchTransformer`` subclasses implementing
 ``apply_arrays``) into a single :class:`FusedTransformerOperator` whose

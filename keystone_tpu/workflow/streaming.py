@@ -464,14 +464,10 @@ def _shared_step_jit(members: tuple, step_fn, partition=None):
             probe = leaf.ravel()[:1]
             return new_carry, probe
 
-    from ..parallel.linalg import donation_safe
-
     # carry is owned by the fold loop: created by gram_stream_init (or a
-    # refit state seed) and threaded only through this step. Donation is
-    # suppressed where the persistent cache makes it unsound
-    # (linalg.donation_safe — CPU deserialized-executable aliasing).
+    # refit state seed) and threaded only through this step.
     # keystone: owns-donated
-    jitted = jax.jit(fused, donate_argnums=(0,) if donation_safe() else ())
+    jitted = jax.jit(fused, donate_argnums=(0,))
     with _step_cache_lock:
         _STEP_JIT_CACHE[key] = ((members, step_fn, partition), jitted, traces)
         _STEP_JIT_CACHE.move_to_end(key)
@@ -491,6 +487,41 @@ def _tree_nbytes(tree) -> int:
     )
 
 
+def _stacked_from_blocks(shape, dtype, sharding, seed_block):
+    """A global ``shape`` array, leading axis sharded one block per
+    device, assembled from blocks built ON the device that holds them:
+    ``seed_block(i)`` is leading block i's initial value (shape
+    ``shape[1:]``) or None for zeros. Nothing of global size ever exists
+    on one device — at TIMIT width on four chips the old
+    zeros-then-``.at[0].set``-then-reshard route put 4 GiB plus a 4 GiB
+    copy on chip 0."""
+    import jax
+    import jax.numpy as jnp
+
+    blocks = []
+    for dev, index in sharding.addressable_devices_indices_map(shape).items():
+        seed = seed_block(index[0].start or 0)
+        if seed is None:
+            # default_device, not zeros(device=dev): jax 0.9.0 builds the
+            # latter on the DEFAULT device and copies it over, and with
+            # async dispatch those staging copies pile up on chip 0
+            # (measured on four v5e chips: 5 carries on chip 0, 3 elsewhere).
+            with jax.default_device(dev):
+                blocks.append(jnp.zeros((1,) + tuple(shape[1:]), dtype))
+        else:
+            blocks.append(jax.device_put(seed[None], dev))
+    return jax.make_array_from_single_device_arrays(shape, sharding, blocks)
+
+
+def _stack_seeded(a, blocks: int, sharding):
+    """``blocks`` leading blocks shaped like ``a``: block 0 is ``a``, the
+    rest zeros."""
+    return _stacked_from_blocks(
+        (blocks,) + tuple(a.shape), a.dtype, sharding,
+        lambda i: a if i == 0 else None,
+    )
+
+
 def _stack_carry(carry, shards: int, sharding):
     """Per-device carry blocks: a leading ``(shards,)`` axis sharded over
     the row axes. Shard 0 seeds the estimator's initial carry (or a
@@ -500,12 +531,9 @@ def _stack_carry(carry, shards: int, sharding):
     import jax
     import jax.numpy as jnp
 
-    def stack(a):
-        a = jnp.asarray(a)
-        z = jnp.zeros((shards,) + tuple(a.shape), a.dtype)
-        return jax.device_put(z.at[0].set(a), sharding)
-
-    return jax.tree_util.tree_map(stack, carry)
+    return jax.tree_util.tree_map(
+        lambda a: _stack_seeded(jnp.asarray(a), shards, sharding), carry
+    )
 
 
 def _carry_layout(step_fn, carry) -> Optional[Tuple[Optional[int], ...]]:
@@ -542,15 +570,17 @@ def _stack_carry_2d(carry, row_shards: int, model_shards: int, layout, sharding)
     def stack(a, ax):
         a = jnp.asarray(a)
         if ax is None:
-            z = jnp.zeros((total,) + tuple(a.shape), a.dtype)
-            return jax.device_put(z.at[0].set(a), sharding)
+            return _stack_seeded(a, total, sharding)
         b = a.shape[ax] // model_shards
         block_shape = a.shape[:ax] + (b,) + a.shape[ax + 1:]
-        z = jnp.zeros((total,) + block_shape, a.dtype)
-        for j in range(model_shards):
-            blk = lax.slice_in_dim(a, j * b, (j + 1) * b, axis=ax)
-            z = z.at[j].set(blk)
-        return jax.device_put(z, sharding)
+        return _stacked_from_blocks(
+            (total,) + block_shape, a.dtype, sharding,
+            lambda i: (
+                lax.slice_in_dim(a, i * b, (i + 1) * b, axis=ax)
+                if i < model_shards
+                else None
+            ),
+        )
 
     return jax.tree_util.tree_unflatten(
         treedef, [stack(a, ax) for a, ax in zip(leaves, layout)]
@@ -872,9 +902,8 @@ class ChunkStream:
             # Commit-before-continue barrier: the carry is host-fetched
             # (device_get blocks until the last dispatch retired) and the
             # atomic store write completes BEFORE the next chunk's
-            # dispatch may donate the buffer — a persisted carry is never
-            # stale (the linalg.donation_safe discipline applied to
-            # persistence).  # keystone: allow-sync
+            # dispatch donates the buffer — a persisted carry is never
+            # stale.  # keystone: allow-sync
             host = jax.device_get(carry)
             if part is not None:
                 # Per-shard partials merge via the additive contract into

@@ -106,12 +106,10 @@ def _force(value: Any) -> None:
     """Force lazy/async results so timings measure real work.
 
     Datasets are unwrapped to their array pytree; device arrays are
-    synced with block_until_ready plus a one-element host fetch (some
-    accelerator relays only guarantee completion on a host readback)."""
+    synced with block_until_ready."""
     data = getattr(value, "data", value)  # ArrayDataset → pytree
     try:
         import jax
-        import numpy as np
 
         leaves = [
             l for l in jax.tree_util.tree_leaves(data) if hasattr(l, "dtype")
@@ -119,9 +117,6 @@ def _force(value: Any) -> None:
         # This IS the sync primitive: every call site gates it behind
         # the session's sync_timings (timed_execute's `if sync:`).
         jax.block_until_ready(leaves)  # keystone: allow-sync
-        for leaf in leaves[:1]:
-            if leaf.size:
-                np.asarray(leaf.ravel()[:1])  # scalar host fetch  # keystone: allow-sync
     except Exception:
         pass
 

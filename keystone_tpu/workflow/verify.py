@@ -1050,24 +1050,6 @@ def _sketch_feasibility(
         )
 
 
-# ------------------------------------------------------------------ memory
-
-
-def _device_memory_limit() -> Optional[int]:
-    """The accelerator's reported capacity (bytes_limit), when the
-    backend exposes one. CPU test meshes report none — the memory check
-    then only runs with an explicit budget."""
-    try:
-        import jax
-
-        stats = jax.devices()[0].memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return None
-
-
 _AUTO = object()
 
 
@@ -1088,10 +1070,13 @@ def verify_graph(
     t0 = time.perf_counter()
     report = VerifyReport(context=context)
     interp = _Interpreter(graph, report.diagnostics, probe_objects)
-    memory_limit = (
-        _device_memory_limit() if device_memory_bytes is _AUTO
-        else device_memory_bytes
-    )
+    if device_memory_bytes is _AUTO:
+        # CPU test meshes report no capacity: the memory check then only
+        # runs with an explicit budget.
+        from ..parallel.mesh import device_memory_limit_bytes
+
+        device_memory_bytes = device_memory_limit_bytes()
+    memory_limit = device_memory_bytes
 
     try:
         order = linearize_whole(graph)

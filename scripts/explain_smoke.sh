@@ -26,7 +26,6 @@ OUT="${1:-$(mktemp -d)}"
 mkdir -p "$OUT"
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 export KEYSTONE_PROFILE_STORE="$OUT/profile-store.jsonl"
-export KEYSTONE_COMPILATION_CACHE="$OUT/xla-cache"
 
 # Shapes sized for walls in the tens of milliseconds: large enough that
 # ambient CI load can't swing them across the 4x drift band, small

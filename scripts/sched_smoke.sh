@@ -28,7 +28,6 @@ cd "$(dirname "$0")/.."
 OUT="${1:-$(mktemp -d)}"
 mkdir -p "$OUT"
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-export KEYSTONE_COMPILATION_CACHE="${KEYSTONE_COMPILATION_CACHE:-$OUT/xla-cache}"
 
 timeout -k 10 420 python -m keystone_tpu explain --schedule --json \
     --out "$OUT/sched.json" 2>&1 | tee "$OUT/sched.log"

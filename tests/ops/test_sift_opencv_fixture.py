@@ -118,7 +118,7 @@ def test_convention_map_is_the_best_one():
 
 
 def test_bf16_binning_passes_the_reference_tolerance():
-    """bf16 spatial binning (docs/NEXT_LEVERS.md item 3) must hold the
+    """bf16 spatial binning must hold the
     reference's own acceptance gate vs the fp32 build: 99.5% of
     x512-quantized entries within 1 (VLFeatSuite.scala:47-52), plus the
     OpenCV-fixture cosine gate. (Full-pyramid bf16 was measured FAILING

@@ -173,42 +173,61 @@ def test_unknown_format_version_is_a_miss(tmp_path):
 
 
 def test_seeded_fold_correct_under_warm_cache(tmp_path):
-    """The donation gate (linalg.donation_safe): with a persistent
-    compilation cache configured on the CPU backend, the streaming step
-    jit must NOT donate its carry — jax 0.4.37 CPU executables
-    deserialized from the cache misapply input→output aliasing, and a
-    donated seeded carry silently accumulates garbage across folds
-    (minimal repro: jit(f, donate_argnums=(0,)) + persistent cache →
-    second process's results drift by hundreds). Asserted structurally:
-    carry buffers survive the step when the cache is active, and are
-    donated (deleted) when it is not."""
+    """The streaming step donates its carry with a persistent compilation
+    cache active, and a seeded fold through an executable DESERIALIZED
+    from that cache is exact. (On jax 0.4.37's CPU backend such
+    executables misapplied input→output aliasing and a donated seeded
+    carry accumulated garbage, so donation was gated off there; on jax
+    0.9.0 the hazard is gone and CPU tests donate exactly as the chip
+    does. This test is what would catch it coming back.)"""
     import jax
     import jax.numpy as jnp
 
-    from keystone_tpu.parallel.linalg import donation_safe
+    from keystone_tpu.utils.compilation_cache import (
+        cache_hit_count,
+        install_compile_counter,
+    )
     from keystone_tpu.workflow import streaming as streaming_mod
 
-    saved = jax.config.jax_compilation_cache_dir
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-        assert donation_safe()
-        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
-        assert not donation_safe()
+    install_compile_counter()
+    rng = np.random.default_rng(11)
+    seed = rng.normal(size=(D, D)).astype(np.float32)
+    chunks = [rng.normal(size=(8, D)).astype(np.float32) for _ in range(4)]
+    want = seed + sum(c.T @ c for c in chunks)
 
-        def step(carry, x_feat, y_b):  # fresh fn: bypass the step cache
-            (g,) = carry
-            return (g + x_feat.T @ x_feat,)
+    def step(carry, x_feat, y_b):  # fresh fn: bypass the step cache
+        (g,) = carry
+        return (g + x_feat.T @ x_feat,)
 
+    def fold():
         jitted, _ = streaming_mod._shared_step_jit((), step)
-        carry = (jnp.zeros((D, D)),)
-        x_b = jnp.ones((8, D))
-        y_b = jnp.ones((8, K))
-        mask = jnp.ones((8, 1))
-        out, _probe = jitted(carry, x_b, y_b, mask)
-        jax.block_until_ready(out)
-        assert not carry[0].is_deleted(), (
-            "carry was donated under an active persistent cache — the "
-            "deserialized-executable aliasing hazard is live again"
-        )
+        carry = (jnp.asarray(seed),)
+        for c in chunks:
+            spent = carry
+            carry, _probe = jitted(
+                carry, jnp.asarray(c), jnp.ones((8, K)), jnp.ones((8, 1))
+            )
+            jax.block_until_ready(carry)
+            assert spent[0].is_deleted(), "carry was not donated"
+        return np.asarray(carry[0])
+
+    saved = (
+        jax.config.jax_compilation_cache_dir,
+        jax.config.jax_persistent_cache_min_entry_size_bytes,
+        jax.config.jax_persistent_cache_min_compile_time_secs,
+    )
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        cold = fold()
+        jax.clear_caches()  # drop in-memory executables: the next fold loads
+        hits = cache_hit_count()
+        warm = fold()
+        assert cache_hit_count() > hits, "second fold did not load from the cache"
     finally:
-        jax.config.update("jax_compilation_cache_dir", saved)
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", saved[1])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", saved[2])
+    np.testing.assert_allclose(cold, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(warm, want, rtol=1e-5, atol=1e-5)

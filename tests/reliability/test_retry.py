@@ -81,7 +81,7 @@ def test_call_sleeps_the_published_schedule(no_sleep_policy):
     def flaky():
         calls.append(1)
         if len(calls) < 3:
-            raise ConnectionError("UNAVAILABLE: relay hiccup")
+            raise ConnectionError("UNAVAILABLE: coordinator hiccup")
         return "ok"
 
     assert policy.call(flaky, label="flaky") == "ok"

@@ -469,3 +469,65 @@ def test_tracing_off_adds_no_wire_field():
         assert requests and all("trace" not in r for r in requests)
     finally:
         sup.stop()
+
+
+# ------------------------------------------------------ one process per chip
+
+
+def _chip_fleet(monkeypatch, chips, workers):
+    """A server-spec supervisor on a host the probe says has ``chips``
+    TPU chips; its workers are stub processes, so no backend starts."""
+    from keystone_tpu.serving import supervisor as sup
+
+    monkeypatch.setattr(sup, "probe_local_chips", lambda env: chips)
+    stub = json.dumps({"stub": {}})
+    return WorkerSupervisor(
+        {"synthetic": {"d": 4}},
+        SupervisorConfig(
+            workers=workers, heartbeat_s=0.05, hang_timeout_s=0.8,
+            ready_timeout_s=15.0, monitor_interval_s=0.02,
+        ),
+        worker_cmd=lambda worker_id: [
+            sys.executable, "-m", "keystone_tpu.serving.worker",
+            "--spec", stub, "--worker-id", worker_id, "--heartbeat-s", "0.05",
+        ],
+    )
+
+
+def test_more_workers_than_chips_fails_at_start(monkeypatch):
+    supervisor = _chip_fleet(monkeypatch, chips=1, workers=2)
+    with pytest.raises(RuntimeError, match="1 TPU chip"):
+        supervisor.start()
+    assert all(w.proc is None for w in supervisor._workers.values())
+
+
+def test_each_worker_owns_one_chip_and_a_retired_chip_is_reused(monkeypatch):
+    from keystone_tpu.serving.supervisor import chip_env
+
+    assert chip_env(1)["TPU_VISIBLE_CHIPS"] == "1"
+    assert chip_env(0)["TPU_PROCESS_PORT"] != chip_env(1)["TPU_PROCESS_PORT"]
+    with _chip_fleet(monkeypatch, chips=2, workers=2) as supervisor:
+        supervisor.wait_ready()
+        rows = supervisor.stats()["workers"]
+        assert sorted(row["chip"] for row in rows.values()) == [0, 1]
+        assert supervisor.worker_ceiling == 2
+        with pytest.raises(RuntimeError, match="every local TPU chip"):
+            supervisor.add_worker()
+        freed = rows[supervisor.remove_worker()]["chip"]
+        deadline = time.monotonic() + 15
+        while len(supervisor.stats()["workers"]) > 1:
+            assert time.monotonic() < deadline, "drained worker never retired"
+            time.sleep(0.02)
+        new_id = supervisor.add_worker()
+        supervisor.wait_ready()
+        assert supervisor.stats()["workers"][new_id]["chip"] == freed
+
+
+def test_cpu_workers_are_not_probed_or_pinned(monkeypatch):
+    from keystone_tpu.serving.supervisor import probe_local_chips
+
+    assert probe_local_chips({"JAX_PLATFORMS": "cpu"}) == 0
+    with make_supervisor(workers=2) as supervisor:
+        supervisor.wait_ready()
+        assert supervisor.worker_ceiling is None
+        assert {r["chip"] for r in supervisor.stats()["workers"].values()} == {None}

@@ -140,9 +140,9 @@ def _manual_state(x, y, s, seed, index_base, est):
 
 
 def test_merge_at_global_offsets_matches_oneshot():
-    """Halves sketched at their true global offsets merge to EXACTLY the
-    one-shot streamed carry (parity ≤ 1e-6) — the additivity the
-    sharded reduce and shard-loss salvage rest on."""
+    """Halves sketched at their true global offsets merge to the one-shot
+    streamed carry — the additivity the sharded reduce and shard-loss
+    salvage rest on."""
     x, y = _realizable(seed=5)
     s = 2 * D
     est = SketchedLeastSquaresEstimator(reg=1e-3, sketch_size=s, seed=7)
@@ -157,7 +157,14 @@ def test_merge_at_global_offsets_matches_oneshot():
     fitted = SketchedLeastSquaresEstimator(
         reg=1e-3, sketch_size=s, seed=7
     ).finish_from_state(merged)
-    assert _rel(np.asarray(fitted.apply_arrays(x)), ref_out) <= 1e-6
+    # Same terms, different order: the one-shot carry adds 8 chunks of 64
+    # rows in sequence, the merge adds two 256-row halves, so every
+    # float32 sketch entry is a sum of up to N terms taken in another
+    # order. Reordering a float32 sum of N terms moves it by about
+    # sqrt(N)·eps (2.7e-6 here; the seed's 1e-6 was a guess the CPU
+    # backend of jax 0.9.0 misses at 1.06e-6).
+    tol = np.sqrt(N) * np.finfo(np.float32).eps
+    assert _rel(np.asarray(fitted.apply_arrays(x)), ref_out) <= tol
 
 
 def test_scaled_state_finishes_to_same_model():
