@@ -6,11 +6,13 @@ grew (flat per-op tracing, serving-local percentiles, the reliability
 ledger's counts):
 
 - :mod:`.spans` — hierarchical spans with trace ids, attributes, events,
-  and cross-thread context handoff; free when no session is active.
+  and cross-thread context handoff; every span is also a ``ks:<name>``
+  annotation in the profiler's trace (the bridge), and nearly free when
+  neither a session nor a profiler trace is active.
 - :mod:`.metrics` — process-wide registry of labeled counters / gauges /
   histograms; :mod:`.names` declares the stable, tested name schema.
 - :mod:`.device` — device/host memory sampling, per-stage peak
-  attribution, optional ``jax.profiler.TraceAnnotation`` wrapping.
+  attribution, the counted host-to-device upload (``h2d``).
 - :mod:`.export` — Chrome trace-event JSON (Perfetto), Prometheus text,
   and a human span-tree report.
 - :mod:`.store` — the persistent profile store: measurements keyed by

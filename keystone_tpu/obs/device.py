@@ -1,5 +1,5 @@
-"""Device/profiling hooks: memory sampling, peak-memory attribution, and
-optional XLA trace annotations.
+"""Device hooks: memory sampling, peak-memory attribution, and the
+counted host-to-device upload.
 
 Memory sampling prefers the accelerator's own accounting
 (``Device.memory_stats()`` — bytes_in_use / peak_bytes_in_use on TPU) and
@@ -8,11 +8,9 @@ test meshes, where XLA allocates out of the process heap anyway. Either
 way the snapshot says which source it used, so a reader never mistakes
 RSS for HBM.
 
-``device_annotation`` wraps a code region in
-``jax.profiler.TraceAnnotation`` so per-node executor work shows up
-inside ``jax.profiler.trace`` captures (TensorBoard/XProf). It is gated —
-default off — because annotations are only useful under an active XLA
-profiler session and cost a host call each.
+``to_device`` is the one place a host batch becomes device arrays on
+the batch-apply path: an ``h2d`` span (in a profiler trace through the
+span layer's bridge, obs/spans.py) and the ``keystone_h2d_*`` counters.
 
 Imports jax lazily; importable before any backend initializes.
 """
@@ -20,46 +18,40 @@ Imports jax lazily; importable before any backend initializes.
 from __future__ import annotations
 
 import os
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 from typing import Any, Dict, Iterator, Optional
 
-from ..envknobs import env_flag
 from . import names, spans
 
 _PAGE_SIZE = os.sysconf("SC_PAGE_SIZE") if hasattr(os, "sysconf") else 4096
 
-# Tri-state like fusion/streaming enablement: None → read the env at CALL
-# time. (This used to be a module-level env read, so flipping
-# KEYSTONE_DEVICE_ANNOTATIONS after import — or monkeypatching it in a
-# test — was silently ignored; keystone-lint KV501 now forbids
-# import-time environment reads, pinned by tests/lint/test_lint_rules.py.)
-_annotations_enabled: "bool | None" = None
+def to_device(data: Any, site: str) -> Any:
+    """``data`` (a pytree) with its host (numpy) leaves uploaded as device
+    arrays; device leaves and everything else pass through untouched.
+    The upload runs under an ``h2d`` span carrying ``bytes`` and is
+    counted in ``keystone_h2d_bytes_total{site}`` /
+    ``keystone_h2d_transfers_total{site}`` (one transfer per leaf)."""
+    import numpy as np
 
+    import jax
 
-def set_device_annotations(enabled: "bool | None") -> None:
-    """Force annotations on/off process-wide; ``None`` restores the env
-    default."""
-    global _annotations_enabled
-    _annotations_enabled = enabled
+    host = [
+        leaf for leaf in jax.tree_util.tree_leaves(data)
+        if isinstance(leaf, np.ndarray)
+    ]
+    if not host:
+        return data
+    import jax.numpy as jnp
 
-
-def annotations_enabled() -> bool:
-    if _annotations_enabled is not None:
-        return _annotations_enabled
-    return env_flag("KEYSTONE_DEVICE_ANNOTATIONS")
-
-
-def device_annotation(name: str):
-    """Context manager: ``jax.profiler.TraceAnnotation(name)`` when
-    enabled and jax is importable, else a no-op."""
-    if not annotations_enabled():
-        return nullcontext()
-    try:
-        import jax.profiler
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return nullcontext()
+    nbytes = sum(leaf.nbytes for leaf in host)
+    with spans.span("h2d", site=site, bytes=nbytes):
+        out = jax.tree_util.tree_map(
+            lambda leaf: jnp.asarray(leaf) if isinstance(leaf, np.ndarray) else leaf,
+            data,
+        )
+    names.metric(names.H2D_BYTES).inc(nbytes, site=site)
+    names.metric(names.H2D_TRANSFERS).inc(len(host), site=site)
+    return out
 
 
 def rss_bytes() -> int:

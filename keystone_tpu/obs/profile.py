@@ -67,11 +67,6 @@ def add_profile_arguments(parser) -> None:
         "--no-serve", action="store_true",
         help="skip the serving phase",
     )
-    parser.add_argument(
-        "--device-annotations", action="store_true",
-        help="wrap node execution in jax.profiler.TraceAnnotation "
-             "(useful under an active XLA profiler capture)",
-    )
 
 
 def profile_from_args(args) -> int:
@@ -82,7 +77,6 @@ def profile_from_args(args) -> int:
         serve_requests=0 if args.no_serve else args.serve_requests,
         out_dir=args.out_dir or args.out or ".",
         autocache=not args.no_autocache,
-        annotations=args.device_annotations,
     )
     # Store round-trip evidence (asserted by scripts/profile_smoke.sh):
     # hits prove a previous run's measurements were read back, writes
@@ -101,7 +95,6 @@ def run_profile(
     serve_requests: int = 32,
     out_dir: str = ".",
     autocache: bool = True,
-    annotations: bool = False,
 ) -> Dict[str, Any]:
     """Fit + apply + serve the synthetic pipeline under instrumentation;
     returns ``{"summary": ..., "session": TraceSession, "report": str}``."""
@@ -115,12 +108,6 @@ def run_profile(
     from . import store as obs_store
 
     names.register_all()
-    # Save the raw override (None = following the env), not the resolved
-    # bool: restoring a resolved False would PIN annotations off
-    # process-wide and re-introduce the stale-env bug device.py fixed.
-    annotations_before = device._annotations_enabled
-    if annotations:
-        device.set_device_annotations(True)
     os.makedirs(out_dir, exist_ok=True)
     # The profile harness is an analysis run: the cost observatory rides
     # along (per-node flop/byte facts + the cost-ledger counter track in
@@ -181,7 +168,6 @@ def run_profile(
                         summary["serve"] = _serve_burst(fitted, serve_requests)
     finally:
         env._optimizer = optimizer_before
-        device.set_device_annotations(annotations_before)
         _cost.set_cost_observatory(cost_override_before)
 
     if store is not None:

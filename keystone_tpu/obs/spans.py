@@ -9,15 +9,30 @@ captures :func:`current_context` at submit time and the worker thread
 re-parents its batch/request spans under it via :func:`attach`, so a
 request's trace id survives submit → batch assembly → apply.
 
+**Profiler bridge.** Every ``span(name)`` also enters
+``jax.profiler.TraceAnnotation("ks:" + name)``, with no switch: the
+annotation records only while somebody is taking a profiler trace (the
+benchmark's ``--trace 1``, ``keystone-tpu profile`` under a capture, an
+operator's xprof session) and is a flag check otherwise. That puts the
+program's spans into the profiler's ``.xplane.pb``, on the device's
+clock. A session is what records spans into memory (ids, parents,
+attributes, exporters); the profiler sees them with or without one.
+Span names are stable — no ids, addresses or counters — so two runs of
+one program give the same set of names.
+
 Design constraints (the serving 5%-overhead budget):
 
-- **Inactive is free.** With no session installed, ``span()`` yields a
-  shared no-op without allocating a record, and ``add_span_event`` is a
-  single global read. Instrumentation can therefore stay in hot paths
-  permanently.
+- **Inactive is nearly free.** With no session installed, ``span()``
+  allocates no record: it returns the bare annotation (about a
+  microsecond with the profiler off), or a shared no-op in a process
+  that has not imported jax; ``add_span_event`` is a single global
+  read. Instrumentation can therefore stay in hot paths permanently.
 - **Stdlib-only at import.** Like ``reliability/``, this module must be
   importable before any jax backend initializes (bench and CLI import it
-  pre-backend).
+  pre-backend). The bridge never imports jax itself: it looks
+  ``jax.profiler`` up once jax is in the process (nobody can be tracing a
+  process that has not imported it), so the jax-free stub workers and
+  the serving supervisor stay jax-free.
 
 Spans use ``time.perf_counter`` timestamps relative to the session start;
 the session records a wall-clock anchor so exporters can emit absolute
@@ -27,6 +42,7 @@ times.
 from __future__ import annotations
 
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -270,8 +286,52 @@ def install_session(
         return _session
 
 
+# ---------------------------------------------------------- profiler bridge
+
+#: Prefix of every program span in a profiler trace (the benchmark's own
+#: spans are ``bench:``; everything else on the host plane is JAX's).
+PROFILER_PREFIX = "ks:"
+
+# jax.profiler.TraceAnnotation once jax is in the process; False when jax
+# is there but has no usable profiler (then never asked again).
+_annotation_cls: Any = None
+
+
+def _find_annotation():
+    """Resolve :data:`_annotation_cls` (called while it is still None)."""
+    global _annotation_cls
+    if "jax" not in sys.modules:
+        return None  # not cached: jax may be imported later
+    try:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    except Exception:
+        _annotation_cls = False
+    return _annotation_cls
+
+
+class _AnnotatedSpan:
+    """``with`` target of a span when no session is open: the profiler's
+    annotation alone, yielding the shared no-op span."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, annotation):
+        self._annotation = annotation
+
+    def __enter__(self) -> "_NoopSpan":
+        self._annotation.__enter__()
+        return NOOP_SPAN
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._annotation.__exit__(exc_type, exc, tb)
+        return False
+
+
 class _NoopSpanContext:
-    """Shared no-op ``with`` target when no session is active."""
+    """Shared no-op ``with`` target when no session is active and jax is
+    not in the process."""
 
     __slots__ = ()
 
@@ -291,12 +351,16 @@ class _SpanContext:
     span, and span() sits on the serving dispatch hot path where the
     fleet-tracing budget is 5% of a ~300µs request."""
 
-    __slots__ = ("_record", "_stack", "_session")
+    __slots__ = ("_record", "_stack", "_session", "_annotation")
 
-    def __init__(self, record: Span, stack: List[Span], session: TraceSession):
+    def __init__(
+        self, record: Span, stack: List[Span], session: TraceSession,
+        annotation=None,
+    ):
         self._record = record
         self._stack = stack
         self._session = session
+        self._annotation = annotation
 
     def __enter__(self) -> Span:
         # Side effects happen HERE, not at span() call time: a
@@ -305,6 +369,8 @@ class _SpanContext:
         # later span's parentage and unbalance __exit__'s pop).
         record = self._record
         self._stack.append(record)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         record.start_s = time.perf_counter()
         return record
 
@@ -316,6 +382,8 @@ class _SpanContext:
                 "exception", type=exc_type.__name__, message=str(exc)[:200]
             )
         record.end_s = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         self._stack.pop()
         self._session.add(record)
         return False  # always re-raise
@@ -335,15 +403,23 @@ def _thread_info() -> Tuple[int, str]:
 
 def span(name: str, parent: Optional[TraceContext] = None, **attributes: Any):
     """Open a child span of the current thread's active span (or of the
-    attached remote context, or a session root). No-op without a session.
+    attached remote context, or a session root), and the profiler's
+    ``ks:<name>`` annotation with it. Without a session only the
+    annotation is entered (module docstring, "Profiler bridge").
 
     ``parent`` hands a REMOTE context in directly — shorthand for
     ``with attach(ctx), span(name)`` on threads with no open span (the
     worker request path), skipping the attach scope. An open span on
     this thread still wins: nesting is local first, like attach."""
+    annotate = _annotation_cls
+    if annotate is None:
+        annotate = _find_annotation()
+    annotation = annotate(PROFILER_PREFIX + name, **attributes) if annotate else None
     session = _session
     if session is None:
-        return _NOOP_SPAN_CM
+        if annotation is None:
+            return _NOOP_SPAN_CM
+        return _AnnotatedSpan(annotation)
     stack = _stack()
     if stack:
         top = stack[-1]
@@ -369,7 +445,7 @@ def span(name: str, parent: Optional[TraceContext] = None, **attributes: Any):
         thread_id=thread_id,
         thread_name=thread_name,
     )
-    return _SpanContext(record, stack, session)
+    return _SpanContext(record, stack, session, annotation)
 
 
 def record_span(

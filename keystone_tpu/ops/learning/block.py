@@ -19,12 +19,14 @@ from typing import Optional
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ...data.dataset import ArrayDataset, Dataset, ObjectDataset
 from ...envknobs import env_disabled
 from ...obs import names as _names
 from ...obs import solver as solver_obs
+from ...obs import spans as _spans
 from ...parallel import linalg
 from ...parallel.mesh import get_mesh
 from ...parallel.partitioner import fit_mesh
@@ -60,14 +62,15 @@ class BlockLinearMapper(BatchTransformer):
         self.feature_mean = None if feature_mean is None else jnp.asarray(feature_mean)
 
     def apply_arrays(self, x):
-        d = x.shape[-1]
-        if self.feature_mean is not None:
-            x = x - self.feature_mean
-        w = self.weights[:d]  # drop padded feature rows
-        out = linalg.mm(x, w)
-        if self.intercept is not None:
-            out = out + self.intercept
-        return out
+        with jax.named_scope("mapper/apply"):
+            d = x.shape[-1]
+            if self.feature_mean is not None:
+                x = x - self.feature_mean
+            w = self.weights[:d]  # drop padded feature rows
+            out = linalg.mm(x, w)
+            if self.intercept is not None:
+                out = out + self.intercept
+            return out
 
     def apply_and_evaluate(self, x, evaluator):
         """Streaming per-block apply: after adding feature block i's
@@ -319,48 +322,64 @@ class BlockLeastSquaresEstimator(GramStreamStateMixin, LabelEstimator):
 
     def _fit_in_core(self, features, targets, mesh, block) -> BlockLinearMapper:
         probe("BlockLeastSquaresEstimator.solve")
-        x = jnp.asarray(features.data, dtype=jnp.float32)
-        y = jnp.asarray(targets.data, dtype=jnp.float32)
-        n = features.num_examples
-        d = x.shape[1]
-        mask = features.mask().reshape(-1, 1)
+        # Host phases of the solve, as spans under `solver:fit` (in a
+        # profiler trace through obs/spans.py's bridge): `solver:prepare`
+        # twice (around the reg floor, which needs the centred rows before
+        # padding), `solver:reg_floor`, `solver:bcd`.
+        with _spans.span("solver:prepare"):
+            x = jnp.asarray(features.data, dtype=jnp.float32)
+            y = jnp.asarray(targets.data, dtype=jnp.float32)
+            n = features.num_examples
+            d = x.shape[1]
+            mask = features.mask().reshape(-1, 1)
 
-        mu_a = jnp.sum(x * mask, axis=0) / n
-        mu_b = jnp.sum(y * mask, axis=0) / n
-        xc = (x - mu_a) * mask
-        yc = (y - mu_b) * mask
+            # (eagerly dispatched operations drop a named scope: this one
+            # names the centring passes wherever the fit is traced whole)
+            with jax.named_scope("solve/centre"):
+                mu_a = jnp.sum(x * mask, axis=0) / n
+                mu_b = jnp.sum(y * mask, axis=0) / n
+                xc = (x - mu_a) * mask
+                yc = (y - mu_b) * mask
 
         # The reg floor must see the REAL data statistics: computed here,
         # before zero-row masking dilution (first n rows only) and before
         # zero-column padding, either of which undershoots E[x²] and with
         # it the intended 1e-6 of the mean Gram diagonal.
-        reg = self.reg if self.reg > 0 else _scale_aware_reg_floor(xc[:n], n)
+        if self.reg > 0:
+            reg = self.reg
+        else:
+            with _spans.span("solver:reg_floor"):  # reads a device scalar back
+                reg = _scale_aware_reg_floor(xc[:n], n)
 
         # Pad the feature dim to a whole number of blocks (zero columns are
         # inert: their Gram rows/cols are zero and λ keeps the solve PD).
         # On a 2-D (data, model) mesh each model group needs a whole number
         # of blocks, so pad to model_axis·block columns.
         m = linalg.model_axis_size(mesh)
-        d_pad = _round_up(d, block * m)
-        if d_pad != d:
-            xc = jnp.pad(xc, ((0, 0), (0, d_pad - d)))
-        if m > 1:
-            xc = linalg.prepare_block_sharded(xc, mesh)
-            yc = linalg.prepare_block_sharded(yc, mesh, fine_rows=True)
-            w = linalg.block_coordinate_descent_2d(
-                xc, yc, reg=reg, num_epochs=self.num_iter, block_size=block, mesh=mesh
-            )
-        else:
-            xc = linalg.prepare_row_sharded(xc, mesh)
-            yc = linalg.prepare_row_sharded(yc, mesh)
-            # xc/yc are private centered copies, dead after the solve —
-            # donate them so the epoch×block scan reuses their HBM for
-            # the carried predictions and per-block Gram workspace
-            # instead of keeping raw + centered copies both resident.
-            w = linalg.block_coordinate_descent(
-                xc, yc, reg=reg, num_epochs=self.num_iter, block_size=block,
-                mesh=mesh, donate_xy=True,
-            )
+        with _spans.span("solver:prepare"):
+            d_pad = _round_up(d, block * m)
+            if d_pad != d:
+                xc = jnp.pad(xc, ((0, 0), (0, d_pad - d)))
+            if m > 1:
+                xc = linalg.prepare_block_sharded(xc, mesh)
+                yc = linalg.prepare_block_sharded(yc, mesh, fine_rows=True)
+            else:
+                xc = linalg.prepare_row_sharded(xc, mesh)
+                yc = linalg.prepare_row_sharded(yc, mesh)
+        with _spans.span("solver:bcd"):
+            if m > 1:
+                w = linalg.block_coordinate_descent_2d(
+                    xc, yc, reg=reg, num_epochs=self.num_iter, block_size=block, mesh=mesh
+                )
+            else:
+                # xc/yc are private centered copies, dead after the solve —
+                # donate them so the epoch×block scan reuses their HBM for
+                # the carried predictions and per-block Gram workspace
+                # instead of keeping raw + centered copies both resident.
+                w = linalg.block_coordinate_descent(
+                    xc, yc, reg=reg, num_epochs=self.num_iter, block_size=block,
+                    mesh=mesh, donate_xy=True,
+                )
         return BlockLinearMapper(
             w, block_size=block, intercept=mu_b, feature_mean=mu_a
         )

@@ -631,6 +631,18 @@ def tsqr_svd(
 # ---------------------------------------------------------------------- BCD
 
 
+# The parts of one BCD block step, as `jax.named_scope`s: the same five
+# names in every variant of the step (in-core, rematerialized, streamed,
+# from-Gram, 2-D), so a device trace splits Gram against Cholesky against
+# the rest whatever numbers XLA gives its fusions. Metadata only.
+BCD_RESIDUAL = "bcd/residual"
+BCD_GRAM = "bcd/gram"
+BCD_CROSS = "bcd/cross"
+BCD_CHOLESKY = "bcd/cholesky"
+BCD_UPDATE = "bcd/update"
+BCD_SCOPES = (BCD_RESIDUAL, BCD_GRAM, BCD_CROSS, BCD_CHOLESKY, BCD_UPDATE)
+
+
 def block_coordinate_descent(
     a: jnp.ndarray,
     y: jnp.ndarray,
@@ -695,13 +707,18 @@ def _bcd_fn(mesh: Mesh, num_epochs: int, block_size: int, donate_xy: bool = Fals
             start = block_idx * block_size
             a_b = lax.dynamic_slice(a_local, (0, start), (a_local.shape[0], block_size))
             w_b = lax.dynamic_slice(w, (start, 0), (block_size, k))
-            r_local = y_local - p_local + mm(a_b, w_b)
-            g = lax.psum(mm(a_b.T, a_b), axes)
-            c = lax.psum(mm(a_b.T, r_local), axes)
-            factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)
-            w_b_new = jax.scipy.linalg.cho_solve(factor, c)
-            p_local = p_local + mm(a_b, w_b_new - w_b)
-            w = lax.dynamic_update_slice(w, w_b_new, (start, 0))
+            with jax.named_scope(BCD_RESIDUAL):
+                r_local = y_local - p_local + mm(a_b, w_b)
+            with jax.named_scope(BCD_GRAM):
+                g = lax.psum(mm(a_b.T, a_b), axes)
+            with jax.named_scope(BCD_CROSS):
+                c = lax.psum(mm(a_b.T, r_local), axes)
+            with jax.named_scope(BCD_CHOLESKY):
+                factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)
+                w_b_new = jax.scipy.linalg.cho_solve(factor, c)
+            with jax.named_scope(BCD_UPDATE):
+                p_local = p_local + mm(a_b, w_b_new - w_b)
+                w = lax.dynamic_update_slice(w, w_b_new, (start, 0))
             return (w, p_local), None
 
         blocks = jnp.tile(jnp.arange(num_blocks), num_epochs)
@@ -752,13 +769,18 @@ def _bcd_remat_fn(mesh: Mesh, num_epochs: int, block_size: int,
             w, p_local = carry
             a_b = block_fn(b, offset, rows)          # (rows, block_size)
             w_b = lax.dynamic_slice(w, (b * block_size, 0), (block_size, k))
-            r_local = y_local - p_local + mm(a_b, w_b)
-            g = lax.psum(mm(a_b.T, a_b), axes)
-            c = lax.psum(mm(a_b.T, r_local), axes)
-            factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)
-            w_b_new = jax.scipy.linalg.cho_solve(factor, c)
-            p_local = p_local + mm(a_b, w_b_new - w_b)
-            w = lax.dynamic_update_slice(w, w_b_new, (b * block_size, 0))
+            with jax.named_scope(BCD_RESIDUAL):
+                r_local = y_local - p_local + mm(a_b, w_b)
+            with jax.named_scope(BCD_GRAM):
+                g = lax.psum(mm(a_b.T, a_b), axes)
+            with jax.named_scope(BCD_CROSS):
+                c = lax.psum(mm(a_b.T, r_local), axes)
+            with jax.named_scope(BCD_CHOLESKY):
+                factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)
+                w_b_new = jax.scipy.linalg.cho_solve(factor, c)
+            with jax.named_scope(BCD_UPDATE):
+                p_local = p_local + mm(a_b, w_b_new - w_b)
+                w = lax.dynamic_update_slice(w, w_b_new, (b * block_size, 0))
             return (w, p_local), None
 
         blocks = jnp.tile(jnp.arange(num_blocks), num_epochs)
@@ -823,12 +845,17 @@ def _bcd_stream_step_fn(mesh: Mesh):
         eye = jnp.eye(bs, dtype=a_b_local.dtype)
         # Center on device (padding rows stay exactly zero via the mask).
         a_b = (a_b_local - mu_block) * mask_local
-        r_local = y_local - p_local + mm(a_b, w_b)
-        g = lax.psum(mm(a_b.T, a_b), axes)
-        c = lax.psum(mm(a_b.T, r_local), axes)
-        factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)
-        w_b_new = jax.scipy.linalg.cho_solve(factor, c)
-        p_local = p_local + mm(a_b, w_b_new - w_b)
+        with jax.named_scope(BCD_RESIDUAL):
+            r_local = y_local - p_local + mm(a_b, w_b)
+        with jax.named_scope(BCD_GRAM):
+            g = lax.psum(mm(a_b.T, a_b), axes)
+        with jax.named_scope(BCD_CROSS):
+            c = lax.psum(mm(a_b.T, r_local), axes)
+        with jax.named_scope(BCD_CHOLESKY):
+            factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)
+            w_b_new = jax.scipy.linalg.cho_solve(factor, c)
+        with jax.named_scope(BCD_UPDATE):
+            p_local = p_local + mm(a_b, w_b_new - w_b)
         return w_b_new, p_local
 
     return jax.jit(
@@ -961,14 +988,15 @@ def gram_stream_step(carry, x, y):
     framework-wide BatchTransformer invariant guarantee it — so no mask
     multiply is needed here."""
     g, c, sa, sb = carry
-    x = x.astype(g.dtype)
-    y = y.astype(g.dtype)
-    return (
-        g + mm(x.T, x),
-        c + mm(x.T, y),
-        sa + jnp.sum(x, axis=0),
-        sb + jnp.sum(y, axis=0),
-    )
+    with jax.named_scope("gram/step"):
+        x = x.astype(g.dtype)
+        y = y.astype(g.dtype)
+        return (
+            g + mm(x.T, x),
+            c + mm(x.T, y),
+            sa + jnp.sum(x, axis=0),
+            sb + jnp.sum(y, axis=0),
+        )
 
 
 def gram_stream_block_step(carry, x, y, block_index):
@@ -1038,16 +1066,21 @@ def _bcd_gram_fn(num_epochs: int, block_size: int):
 
         def block_step(w, block_idx):
             start = block_idx * block_size
-            g_rows = lax.dynamic_slice(gc, (start, 0), (block_size, d))
-            g_bb = lax.dynamic_slice(g_rows, (0, start), (block_size, block_size))
+            with jax.named_scope(BCD_GRAM):  # here a slice of the statistics
+                g_rows = lax.dynamic_slice(gc, (start, 0), (block_size, d))
+                g_bb = lax.dynamic_slice(g_rows, (0, start), (block_size, block_size))
             w_b = lax.dynamic_slice(w, (start, 0), (block_size, k))
             # A_bᵀ(Y − P + A_b W_b) expressed in statistics:
             #   (AᵀY)_b − (AᵀA·W)_b + A_bᵀA_b·W_b
-            c_b = lax.dynamic_slice(cc, (start, 0), (block_size, k))
-            rhs = c_b - mm(g_rows, w) + mm(g_bb, w_b)
-            factor = jax.scipy.linalg.cho_factor(g_bb + reg * eye, lower=True)
-            w_b_new = jax.scipy.linalg.cho_solve(factor, rhs)
-            return lax.dynamic_update_slice(w, w_b_new, (start, 0)), None
+            with jax.named_scope(BCD_CROSS):
+                c_b = lax.dynamic_slice(cc, (start, 0), (block_size, k))
+            with jax.named_scope(BCD_RESIDUAL):
+                rhs = c_b - mm(g_rows, w) + mm(g_bb, w_b)
+            with jax.named_scope(BCD_CHOLESKY):
+                factor = jax.scipy.linalg.cho_factor(g_bb + reg * eye, lower=True)
+                w_b_new = jax.scipy.linalg.cho_solve(factor, rhs)
+            with jax.named_scope(BCD_UPDATE):
+                return lax.dynamic_update_slice(w, w_b_new, (start, 0)), None
 
         blocks = jnp.tile(jnp.arange(num_blocks), num_epochs)
         w, _ = lax.scan(block_step, w0, blocks)
@@ -1195,17 +1228,22 @@ def _bcd2d_fn(mesh: Mesh, num_epochs: int, block_size: int):
                     jnp.where(j == jp, w_b_own, jnp.zeros_like(w_b_own)),
                     MODEL_AXIS,
                 )
-                r = y_fine - p + mm(a_j, w_b_old)
-                g = lax.psum(mm(a_j.T, a_j), all_axes)
-                c = lax.psum(mm(a_j.T, r), all_axes)
-                factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)
-                w_b_new = jax.scipy.linalg.cho_solve(factor, c)
-                p = p + mm(a_j, w_b_new - w_b_old)
-                w_local = jnp.where(
-                    j == jp,
-                    lax.dynamic_update_slice(w_local, w_b_new, (start, 0)),
-                    w_local,
-                )
+                with jax.named_scope(BCD_RESIDUAL):
+                    r = y_fine - p + mm(a_j, w_b_old)
+                with jax.named_scope(BCD_GRAM):
+                    g = lax.psum(mm(a_j.T, a_j), all_axes)
+                with jax.named_scope(BCD_CROSS):
+                    c = lax.psum(mm(a_j.T, r), all_axes)
+                with jax.named_scope(BCD_CHOLESKY):
+                    factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)
+                    w_b_new = jax.scipy.linalg.cho_solve(factor, c)
+                with jax.named_scope(BCD_UPDATE):
+                    p = p + mm(a_j, w_b_new - w_b_old)
+                    w_local = jnp.where(
+                        j == jp,
+                        lax.dynamic_update_slice(w_local, w_b_new, (start, 0)),
+                        w_local,
+                    )
             return (w_local, p), None
 
         blocks = jnp.tile(jnp.arange(num_local_blocks), num_epochs)

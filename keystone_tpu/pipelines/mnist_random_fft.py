@@ -22,6 +22,7 @@ import numpy as np
 from ..data.dataset import ArrayDataset
 from ..data.loaders.csv import LabeledData, load_labeled_csv
 from ..evaluation.multiclass import MulticlassClassifierEvaluator, MulticlassMetrics
+from ..obs import spans
 from ..ops.learning.block import BlockLeastSquaresEstimator
 from ..ops.stats.core import LinearRectifier, PaddedFFT, RandomSignNode
 from ..ops.util.labels import ClassLabelIndicators, MaxClassifier
@@ -55,13 +56,14 @@ def build_featurizer(config: MnistRandomFFTConfig, image_size: int = MNIST_IMAGE
 
 
 def build_pipeline(config: MnistRandomFFTConfig, train: LabeledData) -> Pipeline:
-    labels = ClassLabelIndicators(NUM_CLASSES)(train.labels)
-    featurizer = build_featurizer(config)
-    return featurizer.then_label_estimator(
-        BlockLeastSquaresEstimator(config.block_size, num_iter=1, reg=config.reg or 0.0),
-        train.data,
-        labels,
-    ) >> MaxClassifier()
+    with spans.span("build:pipeline"):
+        labels = ClassLabelIndicators(NUM_CLASSES)(train.labels)
+        featurizer = build_featurizer(config)
+        return featurizer.then_label_estimator(
+            BlockLeastSquaresEstimator(config.block_size, num_iter=1, reg=config.reg or 0.0),
+            train.data,
+            labels,
+        ) >> MaxClassifier()
 
 
 def run(config: MnistRandomFFTConfig) -> dict:

@@ -23,6 +23,7 @@ from ..data.dataset import ArrayDataset
 from ..data.loaders.csv import LabeledData
 from ..data.loaders.timit import NUM_CLASSES, TIMIT_DIMENSION, load_timit
 from ..evaluation.multiclass import MulticlassClassifierEvaluator
+from ..obs import spans
 from ..ops.learning.block import BlockLeastSquaresEstimator
 from ..ops.stats.core import CosineRandomFeatures
 from ..ops.util.labels import ClassLabelIndicators, MaxClassifier
@@ -64,15 +65,18 @@ def build_featurizer(config: TimitConfig, input_dim: int = TIMIT_DIMENSION) -> P
 
 
 def build_pipeline(config: TimitConfig, train: LabeledData, input_dim: int = TIMIT_DIMENSION) -> Pipeline:
-    labels = ClassLabelIndicators(NUM_CLASSES)(train.labels)
-    featurizer = build_featurizer(config, input_dim)
-    return featurizer.then_label_estimator(
-        BlockLeastSquaresEstimator(
-            config.num_cosine_features, num_iter=config.num_epochs, reg=config.reg
-        ),
-        train.data,
-        labels,
-    ) >> MaxClassifier()
+    # A phase of every fit that starts from a configuration, with the
+    # device idle: the random features are drawn on the host, in numpy.
+    with spans.span("build:pipeline"):
+        labels = ClassLabelIndicators(NUM_CLASSES)(train.labels)
+        featurizer = build_featurizer(config, input_dim)
+        return featurizer.then_label_estimator(
+            BlockLeastSquaresEstimator(
+                config.num_cosine_features, num_iter=config.num_epochs, reg=config.reg
+            ),
+            train.data,
+            labels,
+        ) >> MaxClassifier()
 
 
 def run(config: TimitConfig) -> dict:

@@ -49,7 +49,7 @@ from ..obs import cost as _cost
 from ..obs import names as _names
 from .graph import Graph, NodeId, SinkId
 from .operators import TransformerOperator
-from .pipeline import BatchTransformer
+from .pipeline import BatchTransformer, feat_scope
 from .rules import PrefixMap, Rule
 
 logger = logging.getLogger(__name__)
@@ -184,7 +184,8 @@ class FusedTransformerOperator(BatchTransformer):
 
     def _chain(self, x):
         for m in self.members:
-            x = m.apply_arrays(x)
+            with feat_scope(m):
+                x = m.apply_arrays(x)
         return x
 
     def _compiled(self):
@@ -287,7 +288,8 @@ def _shared_chain_jit(members: tuple):
         # on cached executions — the fused-compile counter.
         compiles_c.inc()
         for m in members:
-            x = m.apply_arrays(x)
+            with feat_scope(m):  # each member's operations keep its name
+                x = m.apply_arrays(x)
         return x
 
     jitted = jax.jit(fused_chain)

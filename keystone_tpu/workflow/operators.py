@@ -12,12 +12,40 @@ the analog of the reference's "no Spark job until someone forces .get".
 from __future__ import annotations
 
 import threading
-from typing import Any, Callable, List, Optional, Sequence
+from contextlib import contextmanager
+from typing import Any, Callable, Iterator, List, Optional, Sequence
 
 from ..data.dataset import Dataset
+from ..obs import spans as _spans
 
 
 _UNSET = object()
+
+_timed = threading.local()  # .op: the operator whose span timed_execute holds open
+
+
+def node_span(op: "Operator"):
+    """The ``node:<label>`` span around one node's OWN work: opened
+    inside the thunk, after the dependencies are forced, so the nodes of
+    one operation are siblings in time however the pulls nest. Where
+    ``workflow.tracing.timed_execute`` already holds this operator's span
+    open (a session, the cost observatory) that one stands: a node
+    appears once in a trace."""
+    if getattr(_timed, "op", None) is op:
+        return _spans._NOOP_SPAN_CM
+    return _spans.span(f"node:{op.label}", op=type(op).__name__)
+
+
+@contextmanager
+def holding_node_span(op: "Operator") -> Iterator[None]:
+    """``timed_execute``'s side of :func:`node_span`: while this is open
+    on the thread, ``op``'s thunk opens no span of its own."""
+    before = getattr(_timed, "op", None)
+    _timed.op = op
+    try:
+        yield
+    finally:
+        _timed.op = before
 
 
 class Expression:
@@ -178,12 +206,15 @@ class TransformerOperator(Operator):
                             "in batch execution"
                         )
                     materialized.append(value)
-                return self.batch_transform(materialized)
+                with node_span(self):
+                    return self.batch_transform(materialized)
 
             return DatasetExpression(thunk)
 
         def datum_thunk() -> Any:
-            return self.single_transform([d.get() for d in deps])
+            datums = [d.get() for d in deps]
+            with node_span(self):
+                return self.single_transform(datums)
 
         return DatumExpression(datum_thunk)
 
@@ -226,10 +257,11 @@ class EstimatorOperator(Operator):
             # never leak into solves that were not planned under it.
             mode = getattr(self, "solver_precision", None)
             if mode is None:
-                return self.fit_datasets(datasets)
+                with node_span(self):
+                    return self.fit_datasets(datasets)
             from ..parallel import linalg
 
-            with linalg.solver_mode_scope(mode):
+            with linalg.solver_mode_scope(mode), node_span(self):
                 return self.fit_datasets(datasets)
 
         return TransformerExpression(thunk)
@@ -246,13 +278,16 @@ class DelegatingOperator(Operator):
             def thunk() -> Dataset:
                 transformer: TransformerOperator = transformer_dep.get()
                 datasets = [d.get() for d in data_deps]
-                return transformer.batch_transform(datasets)
+                with node_span(self):
+                    return transformer.batch_transform(datasets)
 
             return DatasetExpression(thunk)
 
         def datum_thunk() -> Any:
             transformer: TransformerOperator = transformer_dep.get()
-            return transformer.single_transform([d.get() for d in data_deps])
+            datums = [d.get() for d in data_deps]
+            with node_span(self):
+                return transformer.single_transform(datums)
 
         return DatumExpression(datum_thunk)
 

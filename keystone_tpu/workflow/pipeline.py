@@ -31,6 +31,8 @@ import threading
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 from ..data.dataset import ArrayDataset, Dataset, ObjectDataset, as_dataset
+from ..obs import spans as _spans
+from ..obs.device import to_device
 from .executor import GraphExecutor, PipelineEnv
 from .graph import Graph, NodeId, NodeOrSourceId, SinkId, SourceId
 from .operators import (
@@ -179,6 +181,18 @@ class Identity(Transformer):
         return dataset
 
 
+def feat_scope(op: Any):
+    """``jax.named_scope("feat/<ClassName>")`` around one transformer's
+    ``apply_arrays``: the stable name its operations carry in a device
+    trace (metadata only), given to every featurizer from one place. It
+    names what is TRACED under it (a fused chain's members, a featurizer's
+    own scan or jitted helper); a primitive dispatched eagerly keeps its
+    bare ``jit(cos)/cos``, whatever scope is open."""
+    import jax
+
+    return jax.named_scope("feat/" + type(op).__name__)
+
+
 class BatchTransformer(Transformer):
     """Transformer whose native form is whole-batch array computation.
 
@@ -256,12 +270,20 @@ class BatchTransformer(Transformer):
             # the descriptors, validity flows through untouched. Safe for
             # the chain between extractor and FisherVector (elementwise
             # maps and PCA matmuls keep zero rows zero).
-            out = self.apply_arrays(dataset.data["desc"])
+            desc = to_device(dataset.data["desc"], site=type(self).__name__)
+            with feat_scope(self):
+                out = self.apply_arrays(desc)
             return ArrayDataset(
                 {"desc": out, "valid": dataset.data["valid"]},
                 dataset.num_examples,
             )
-        out = dataset.map_batched(self.apply_arrays)
+        # The upload of a host-resident batch, made explicit where the
+        # first jnp operation of `apply_arrays` used to make it: once per
+        # application, so a host input that feeds k branches is uploaded k
+        # times (counted by keystone_h2d_*; sharing it is a perf change).
+        data = to_device(dataset.data, site=type(self).__name__)
+        with feat_scope(self):
+            out = ArrayDataset(self.apply_arrays(data), dataset.num_examples)
         if out.physical_rows > out.num_examples:
             real_row = jnp.arange(out.physical_rows) < out.num_examples
 
@@ -390,9 +412,14 @@ class Pipeline(Chainable):
         instead of failing minutes later inside a jit trace."""
         from .verify import verify_and_enforce
 
+        # Top-level phases are siblings, with no root span over the fit:
+        # a trace reader that names a gap by the first span covering it
+        # would otherwise put every gap down to the root (PERF.md 7).
         env = PipelineEnv.get_or_create()
-        graph, prefixes = env.optimizer.execute(self.graph)
-        verify_and_enforce(graph, context="fit")
+        with _spans.span("fit:plan"):
+            graph, prefixes = env.optimizer.execute(self.graph)
+        with _spans.span("fit:verify"):
+            verify_and_enforce(graph, context="fit")
         executor = GraphExecutor(graph, optimize=False)
         executor._prefixes = prefixes
 
@@ -407,19 +434,21 @@ class Pipeline(Chainable):
                 raise TypeError(
                     f"delegating node {node} resolved to {type(fit_transformer).__name__}"
                 )
-            graph = graph.set_operator(node, fit_transformer)
-            graph = graph.set_dependencies(node, data_deps)
-            # keep executor and graph views consistent for later delegating nodes
-            executor._optimized = graph
-            executor._memo.pop(node, None)
+            with _spans.span("fit:splice"):
+                graph = graph.set_operator(node, fit_transformer)
+                graph = graph.set_dependencies(node, data_deps)
+                # keep executor and graph views consistent for later delegating nodes
+                executor._optimized = graph
+                executor._memo.pop(node, None)
 
-        graph, _ = UnusedBranchRemovalRule().apply(graph, {})
-        # The spliced graph is transformer-only: newly-adjacent chains
-        # (fit transformer next to its featurization) fuse into single
-        # compiled dispatches for the apply/serving path. The optimizer's
-        # own fusion batch can't see these chains — they exist only after
-        # delegating nodes collapse.
-        return FittedPipeline(graph, self.source, self.sink).fused()
+        with _spans.span("fit:fuse"):
+            graph, _ = UnusedBranchRemovalRule().apply(graph, {})
+            # The spliced graph is transformer-only: newly-adjacent chains
+            # (fit transformer next to its featurization) fuse into single
+            # compiled dispatches for the apply/serving path. The optimizer's
+            # own fusion batch can't see these chains — they exist only after
+            # delegating nodes collapse.
+            return FittedPipeline(graph, self.source, self.sink).fused()
 
     # ------------------------------------------------------------------ gather
     @staticmethod
@@ -506,21 +535,23 @@ class FittedPipeline(Transformer):
 
     def apply(self, datum: Any) -> Any:
         with self._datum_lock:
-            if self._datum_graph is None:
-                self._datum_op = DatumOperator(datum)
-                graph, node = self.graph.add_node(self._datum_op, [])
-                graph = graph.replace_dependency(self.source, node)
-                self._datum_graph = graph.remove_source(self.source)
-            else:
-                self._datum_op.datum = datum
-            executor = GraphExecutor(self._datum_graph, optimize=False)
+            with _spans.span("apply:bind"):
+                if self._datum_graph is None:
+                    self._datum_op = DatumOperator(datum)
+                    graph, node = self.graph.add_node(self._datum_op, [])
+                    graph = graph.replace_dependency(self.source, node)
+                    self._datum_graph = graph.remove_source(self.source)
+                else:
+                    self._datum_op.datum = datum
+                executor = GraphExecutor(self._datum_graph, optimize=False)
             return executor.execute(self.sink).get()
 
     def apply_batch(self, dataset: Dataset) -> Dataset:
-        graph, node = self.graph.add_node(DatasetOperator(dataset), [])
-        graph = graph.replace_dependency(self.source, node)
-        graph = graph.remove_source(self.source)
-        executor = GraphExecutor(graph, optimize=False)
+        with _spans.span("apply:bind"):
+            graph, node = self.graph.add_node(DatasetOperator(dataset), [])
+            graph = graph.replace_dependency(self.source, node)
+            graph = graph.remove_source(self.source)
+            executor = GraphExecutor(graph, optimize=False)
         return executor.execute(self.sink).get()
 
     def fused(self) -> "FittedPipeline":
@@ -623,13 +654,14 @@ class CompiledApply:
                 )
         fitted = self._fitted
         with self._lock:
-            if self._graph is None:
-                self._op = DatasetOperator(dataset)
-                graph, node = fitted.graph.add_node(self._op, [])
-                graph = graph.replace_dependency(fitted.source, node)
-                self._graph = graph.remove_source(fitted.source)
-            else:
-                self._op.dataset = dataset
-            self.calls += 1
-            executor = GraphExecutor(self._graph, optimize=False)
+            with _spans.span("apply:bind"):
+                if self._graph is None:
+                    self._op = DatasetOperator(dataset)
+                    graph, node = fitted.graph.add_node(self._op, [])
+                    graph = graph.replace_dependency(fitted.source, node)
+                    self._graph = graph.remove_source(fitted.source)
+                else:
+                    self._op.dataset = dataset
+                self.calls += 1
+                executor = GraphExecutor(self._graph, optimize=False)
             return executor.execute(fitted.sink).get()

@@ -23,6 +23,13 @@ CLI) even when no ``trace()`` shim is active; a ``sync_timings=False``
 session — and a metrics-registry-only run with no session at all —
 skips the per-node sync entirely, preserving async dispatch between
 nodes (spans then carry ``synced=False``).
+
+A plain run still has its ``node:<label>`` spans: the thunks of
+``workflow/operators.py`` open one around each node's own work, and the
+span layer's bridge (obs/spans.py) puts it into whatever profiler trace
+is being taken. Where ``timed_execute`` opens the node's span itself (a
+session, the cost observatory) the thunk's stands down, so a node appears
+once in a trace either way.
 """
 
 from __future__ import annotations
@@ -36,7 +43,7 @@ from typing import Any, List, Optional
 from ..obs import cost as _cost
 from ..obs import names as _names
 from ..obs import spans as _spans
-from ..obs.device import device_annotation
+from .operators import holding_node_span
 
 
 @dataclass
@@ -190,7 +197,9 @@ def timed_execute(op, deps):
                 from ..utils.compilation_cache import compile_count
 
                 compiles_before = compile_count()
-            with device_annotation(f"keystone/node/{label}"):
+            # This span is the node's: the thunk's own `node_span` stands
+            # down while it is open (one span a node, session or not).
+            with holding_node_span(op):
                 start = time.perf_counter()
                 value = expression.get()
                 if sync:

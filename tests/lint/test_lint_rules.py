@@ -215,25 +215,6 @@ def test_shipped_tree_is_clean():
 # ------------------------------------------- pinned true-positive fixes
 
 
-def test_device_annotations_env_read_is_call_time(monkeypatch):
-    """KV501 true positive fixed: KEYSTONE_DEVICE_ANNOTATIONS used to be
-    read at import time, so flipping it after import (or monkeypatching
-    in a test, like this one) was silently ignored."""
-    from keystone_tpu.obs import device
-
-    monkeypatch.setattr(device, "_annotations_enabled", None)
-    monkeypatch.delenv("KEYSTONE_DEVICE_ANNOTATIONS", raising=False)
-    assert device.annotations_enabled() is False
-    monkeypatch.setenv("KEYSTONE_DEVICE_ANNOTATIONS", "1")
-    assert device.annotations_enabled() is True
-    device.set_device_annotations(False)
-    try:
-        assert device.annotations_enabled() is False  # override wins
-    finally:
-        device.set_device_annotations(None)
-    assert device.annotations_enabled() is True  # env default restored
-
-
 def test_group_batch_reads_metadata_without_host_sync():
     """KV502 true positive fixed: batch grouping used np.asarray on every
     payload leaf — a synchronous device→host copy per request — just to
