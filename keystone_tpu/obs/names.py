@@ -82,6 +82,7 @@ BLOCKSPARSE_BLOCKS_SKIPPED = "keystone_blocksparse_blocks_skipped_total"
 SOLVER_FIT_SECONDS = "keystone_solver_fit_seconds"
 SOLVER_RUNG_ATTEMPTS = "keystone_solver_rung_attempts_total"
 SOLVER_ITERATIONS = "keystone_solver_iterations_total"
+BCD_FACTOR_REUSE = "keystone_bcd_factor_reuse_total"
 
 # ---------------------------------------------------------------- sketch tier
 SKETCH_FITS = "keystone_sketch_fits_total"
@@ -256,6 +257,7 @@ SCHEMA: Dict[str, Tuple] = {
     SOLVER_FIT_SECONDS: ("histogram", "Solver fit wall time", ("solver",)),
     SOLVER_RUNG_ATTEMPTS: ("counter", "Degradation-ladder rung attempts inside solvers", ("solver",)),
     SOLVER_ITERATIONS: ("counter", "Host-level solver iterations (e.g. L-BFGS steps)", ("solver",)),
+    BCD_FACTOR_REUSE: ("counter", "In-core block_coordinate_descent calls, by program form: reused = each block's Gram and Cholesky factor computed once in a factor pass and reused in every epoch (num_epochs > 1), single_pass = factored inside the one pass (num_epochs == 1)", ("mode",)),
     SKETCH_FITS: ("counter", "Sketched least-squares fits completed, by sketch variant (countsketch/srht)", ("variant",)),
     SKETCH_SIZE: ("gauge", "Sketch rows s chosen for the last sketched fit (knob/tuned/width default)", ()),
     SKETCH_STATE_BYTES: ("gauge", "Bytes of the last sketched fit's O(s·d) carry — the number KV308 compares to the device budget", ()),

@@ -106,7 +106,11 @@ class BlockLeastSquaresEstimator(GramStreamStateMixin, LabelEstimator):
 
     ``num_iter`` full epochs over the feature blocks; λ is applied per
     block. The node is weighted for the auto-cache planner the same way the
-    reference weights it: 3·num_iter + 1 passes over the data.
+    reference weights it: 3·num_iter + 1 passes over the data. The in-core
+    program (``linalg.block_coordinate_descent``) reads each feature block
+    that often too: once in the factor pass (Gram), then three times an
+    epoch (residual, cross term, update); the planner's ``weight`` counts
+    re-reads of the node's INPUT, which the in-core fit uploads once.
     """
 
     #: Chunked-fit protocol (workflow/streaming.py): this estimator can
@@ -366,7 +370,9 @@ class BlockLeastSquaresEstimator(GramStreamStateMixin, LabelEstimator):
             else:
                 xc = linalg.prepare_row_sharded(xc, mesh)
                 yc = linalg.prepare_row_sharded(yc, mesh)
-        with _spans.span("solver:bcd"):
+        # (the 2-D variant factors in every epoch and says nothing)
+        attrs = {} if m > 1 else {"factor_reuse": linalg.bcd_factor_mode(self.num_iter)}
+        with _spans.span("solver:bcd", **attrs):
             if m > 1:
                 w = linalg.block_coordinate_descent_2d(
                     xc, yc, reg=reg, num_epochs=self.num_iter, block_size=block, mesh=mesh

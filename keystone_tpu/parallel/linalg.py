@@ -661,8 +661,21 @@ def block_coordinate_descent(
         (A_bᵀA_b + λI) W_b = A_bᵀ (Y − P + A_b W_b)
 
     where P are current predictions. Per-shard products ride the MXU;
-    cross-shard sums are one psum per block; the whole epoch×block loop is
-    a single compiled ``lax.scan`` — no host round trips inside training.
+    cross-shard sums are one psum per block; the whole solve is a single
+    compiled program — no host round trips inside training.
+
+    Neither ``A_bᵀA_b`` nor its Cholesky factor depends on the iterate, so
+    with ``num_epochs > 1`` the program is two loops: a factor pass over
+    the blocks (Gram at HIGHEST, float32 Cholesky), stacked to
+    ``(num_blocks, block_size, block_size)`` float32, then one scan over
+    epoch×block whose body is residual, cross term, two triangular solves
+    against the stacked factor, update — the same Gauss-Seidel iterates
+    with each Gram computed once, not once per epoch. The stack costs
+    ``d · block_size · 4`` bytes per device (256 MiB at d = 16384, block
+    4096). With ``num_epochs == 1`` nothing would be reused, so the
+    program is the single scan that factors inside each step and holds
+    no stack. ``keystone_bcd_factor_reuse_total{mode}`` counts the calls
+    of each form.
 
     ``a`` is (n, d) row-sharded (rows may be zero-padded), ``y`` is (n, k).
     ``d`` must be a multiple of ``block_size`` (pad features if needed).
@@ -684,15 +697,24 @@ def block_coordinate_descent(
     reg_arr = jnp.asarray(reg, dtype=a.dtype)
     # Cost-observatory attribution (obs/cost.py): avals, not the arrays
     # — a/y may be donated into the solve below.
-    from ..obs import cost as _cost
+    from ..obs import cost as _cost, names as _names
 
+    _names.metric(_names.BCD_FACTOR_REUSE).inc(mode=bcd_factor_mode(num_epochs))
     _cost.note_solver_call("solver_bcd", fn, (a, y, reg_arr))
     return fn(a, y, reg_arr)
+
+
+def bcd_factor_mode(num_epochs: int) -> str:
+    """Which form of the in-core program ``num_epochs`` compiles to: the
+    ``mode`` label of ``keystone_bcd_factor_reuse_total`` and the
+    ``factor_reuse`` attribute of the ``solver:bcd`` span."""
+    return "reused" if num_epochs > 1 else "single_pass"
 
 
 @_mode_cached()
 def _bcd_fn(mesh: Mesh, num_epochs: int, block_size: int, donate_xy: bool = False):
     axes = row_axes(mesh)
+    reuse = bcd_factor_mode(num_epochs) == "reused"
 
     def per_device(a_local, y_local, reg):
         d = a_local.shape[1]
@@ -702,20 +724,41 @@ def _bcd_fn(mesh: Mesh, num_epochs: int, block_size: int, donate_xy: bool = Fals
         w0 = jnp.zeros((d, k), dtype=a_local.dtype)
         p0 = jnp.zeros_like(y_local)
 
+        def block_at(start):
+            return lax.dynamic_slice(a_local, (0, start), (a_local.shape[0], block_size))
+
+        def gram(a_b):
+            with jax.named_scope(BCD_GRAM):
+                return lax.psum(mm(a_b.T, a_b), axes)
+
+        def factorize(g):
+            with jax.named_scope(BCD_CHOLESKY):
+                return jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)[0]
+
+        # The factor pass: each block's Gram and Cholesky factor once per
+        # fit, as neither depends on the iterate.
+        factors = lax.map(
+            lambda block_idx: factorize(gram(block_at(block_idx * block_size))),
+            jnp.arange(num_blocks),
+        ) if reuse else None
+
         def block_step(carry, block_idx):
             w, p_local = carry
             start = block_idx * block_size
-            a_b = lax.dynamic_slice(a_local, (0, start), (a_local.shape[0], block_size))
+            a_b = block_at(start)
             w_b = lax.dynamic_slice(w, (start, 0), (block_size, k))
             with jax.named_scope(BCD_RESIDUAL):
                 r_local = y_local - p_local + mm(a_b, w_b)
-            with jax.named_scope(BCD_GRAM):
-                g = lax.psum(mm(a_b.T, a_b), axes)
+            if not reuse:
+                g = gram(a_b)
             with jax.named_scope(BCD_CROSS):
                 c = lax.psum(mm(a_b.T, r_local), axes)
+            if reuse:
+                factor = lax.dynamic_index_in_dim(factors, block_idx, keepdims=False)
+            else:
+                factor = factorize(g)
             with jax.named_scope(BCD_CHOLESKY):
-                factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)
-                w_b_new = jax.scipy.linalg.cho_solve(factor, c)
+                w_b_new = jax.scipy.linalg.cho_solve((factor, True), c)
             with jax.named_scope(BCD_UPDATE):
                 p_local = p_local + mm(a_b, w_b_new - w_b)
                 w = lax.dynamic_update_slice(w, w_b_new, (start, 0))

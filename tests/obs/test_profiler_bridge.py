@@ -272,6 +272,31 @@ def test_uploads_are_counted_once_per_branch():
     assert counted(names.H2D_TRANSFERS) - transfers0 == 2
 
 
+@pytest.mark.parametrize("num_iter,mode", [(5, "reused"), (1, "single_pass")])
+def test_the_in_core_solve_says_whether_it_reuses_its_factors(num_iter, mode):
+    """`solver:bcd` carries `factor_reuse`, and the counter takes one
+    count a `block_coordinate_descent` call under the same mode."""
+    from keystone_tpu.ops.learning.block import BlockLeastSquaresEstimator
+
+    rng = np.random.default_rng(4)
+    x = ArrayDataset(rng.standard_normal((ROWS, 32)).astype(np.float32))
+    y = ArrayDataset(rng.standard_normal((ROWS, CLASSES)).astype(np.float32))
+    registry = metrics.get_registry()
+
+    def counted(label):
+        metric = registry.get(names.BCD_FACTOR_REUSE)
+        return metric.value(mode=label) if metric else 0.0
+
+    other = "single_pass" if mode == "reused" else "reused"
+    before, before_other = counted(mode), counted(other)
+    with spans.tracing_session("t") as session:
+        BlockLeastSquaresEstimator(16, num_iter=num_iter, reg=0.0).fit(x, y)
+    (bcd,) = session.find("solver:bcd")
+    assert bcd.attributes["factor_reuse"] == mode
+    assert counted(mode) - before == 1
+    assert counted(other) == before_other
+
+
 # ------------------------------------------------- scopes on the kernels
 
 

@@ -1,6 +1,8 @@
 """Distributed linalg vs local numpy golden values, on an 8-device CPU mesh
 (the reference's local-partitions-stand-in-for-cluster strategy)."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -120,6 +122,95 @@ def test_bcd_single_block_equals_exact(mesh):
             block_size=6,
         )
     np.testing.assert_allclose(np.asarray(w), expected, rtol=1e-3, atol=1e-3)
+
+
+def _gauss_seidel(a, y, reg, num_epochs, block_size):
+    """Plain block Gauss-Seidel in float64: the step-by-step form, with
+    the Gram formed and solved inside every step."""
+    a, y = a.astype(np.float64), y.astype(np.float64)
+    w = np.zeros((a.shape[1], y.shape[1]))
+    p = np.zeros_like(y)
+    for _ in range(num_epochs):
+        for start in range(0, a.shape[1], block_size):
+            cols = slice(start, start + block_size)
+            a_b = a[:, cols]
+            r = y - p + a_b @ w[cols]
+            w_b = np.linalg.solve(a_b.T @ a_b + reg * np.eye(block_size), a_b.T @ r)
+            p += a_b @ (w_b - w[cols])
+            w[cols] = w_b
+    return w
+
+
+@pytest.mark.parametrize("rows", [96, 93], ids=["rows_whole", "rows_padded"])
+@pytest.mark.parametrize("reg", [0.0, 1e-3], ids=["reg_floor", "reg_1e-3"])
+@pytest.mark.parametrize("num_blocks", [1, 4])
+@pytest.mark.parametrize("num_epochs", [1, 2, 5])
+def test_bcd_matches_plain_gauss_seidel(mesh, num_epochs, num_blocks, reg, rows):
+    """The factor pass changes how often a block is factored, not the
+    iterates: every (epochs, blocks) form agrees with the plain loop."""
+    from keystone_tpu.ops.learning.block import _scale_aware_reg_floor
+
+    block_size = 8
+    a = rand((rows, num_blocks * block_size), seed=11)
+    y = rand((rows, 3), seed=12)
+    if reg == 0.0:  # what the estimator passes in for reg = 0
+        reg = _scale_aware_reg_floor(a, rows)
+    with use_mesh(mesh):
+        w = linalg.block_coordinate_descent(
+            linalg.prepare_row_sharded(a),
+            linalg.prepare_row_sharded(y),
+            reg=reg,
+            num_epochs=num_epochs,
+            block_size=block_size,
+        )
+    expected = _gauss_seidel(a, y, reg, num_epochs, block_size)
+    np.testing.assert_allclose(np.asarray(w), expected, rtol=1e-4, atol=1e-5)
+
+
+def _lowered_bcd_functions(mesh, num_epochs, num_blocks=4, block_size=8, k=3):
+    """The lowered in-core program as (whole text, {function name: body})."""
+    f32 = np.float32
+    a = jax.ShapeDtypeStruct((64, num_blocks * block_size), f32)
+    y = jax.ShapeDtypeStruct((64, k), f32)
+    reg = jax.ShapeDtypeStruct((), f32)
+    text = linalg._bcd_fn(mesh, num_epochs, block_size, False).lower(a, y, reg).as_text()
+    parts = re.split(r"\n  func\.func ", text)
+    return text, {re.match(r"(?:public |private )?@(\w+)", p).group(1): p for p in parts[1:]}
+
+
+# shapes of _lowered_bcd_functions: the Gram is the one product that is
+# block x block; the factor stack is (num_blocks, block, block)
+_GRAM_DOT = r"dot_general[^\n]*-> tensor<8x8xf32>"
+_FACTOR_STACK = "tensor<4x8x8xf32>"
+
+
+def test_bcd_factors_in_a_factor_pass_ahead_of_the_epoch_scan(mesh):
+    text, functions = _lowered_bcd_functions(mesh, num_epochs=5)
+    assert len(re.findall(r"stablehlo\.while", text)) == 2
+    assert _FACTOR_STACK in text
+    (epoch_body,) = [f for f in functions.values() if "call @_cho_solve" in f]
+    (factor_body,) = [f for f in functions.values() if "call @_cholesky" in f]
+    assert epoch_body is not factor_body
+    assert len(re.findall(_GRAM_DOT, factor_body)) == 1
+    assert not re.search(_GRAM_DOT, epoch_body)
+    assert "dot_general" in epoch_body and _FACTOR_STACK in epoch_body
+    assert len(re.findall(_GRAM_DOT, text)) == 1  # one Gram site in the whole program
+    # the factor pass runs once a block, the epoch scan epochs x blocks times
+    assert re.search(r"constant dense<4> : tensor<i32>[^\n]*\n[^\n]*compare  LT", text)
+    assert re.search(r"constant dense<20> : tensor<i32>[^\n]*\n[^\n]*compare  LT", text)
+
+
+def test_bcd_single_pass_is_one_loop_and_holds_no_factor_stack(mesh):
+    text, functions = _lowered_bcd_functions(mesh, num_epochs=1)
+    assert len(re.findall(r"stablehlo\.while", text)) == 1
+    assert not re.search(r"tensor<\d+x8x8xf32>", text)
+    (step,) = [f for f in functions.values() if "call @_cho_solve" in f]
+    assert "call @_cholesky" in step and re.search(_GRAM_DOT, step)
+
+
+@pytest.mark.parametrize("num_epochs,mode", [(1, "single_pass"), (2, "reused"), (5, "reused")])
+def test_bcd_factor_mode_follows_the_static_epoch_count(num_epochs, mode):
+    assert linalg.bcd_factor_mode(num_epochs) == mode
 
 
 # --------------------------------------------------------- hybrid (DCN) mesh
