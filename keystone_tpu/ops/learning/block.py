@@ -176,7 +176,8 @@ class BlockLeastSquaresEstimator(GramStreamStateMixin, LabelEstimator):
                 carry, n, reg=self.reg, block_size=self.block_size,
                 num_iter=self.num_iter,
             )
-            mapper = self._finish_from_stats(carry, n)
+            with _spans.span("stream:finish", epochs=self.num_iter):
+                mapper = self._finish_from_stats(carry, n)
         _record_solver_observation(
             "block_ls_stream",
             rows=n,

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ...data.dataset import ArrayDataset, Dataset
+from ...obs import spans as _spans
 from ...parallel import linalg
 from ...parallel.mesh import get_mesh
 from ...parallel.partitioner import fit_mesh
@@ -95,7 +96,8 @@ class LinearMapEstimator(GramStreamStateMixin, LabelEstimator):
         carry, info = stream.fold(init, linalg.gram_stream_step)
         n = info["num_examples"] + (state.num_examples if state else 0)
         self._capture_state(carry, n, reg=self.reg)
-        return self._finish_from_stats(carry, n)
+        with _spans.span("stream:finish"):
+            return self._finish_from_stats(carry, n)
 
     def _finish_from_stats(self, carry, n: int) -> LinearMapper:
         """Exact solve from accumulated statistics alone — shared by the

@@ -80,6 +80,23 @@ class CosineRandomFeatures(BatchTransformer):
     ) -> "CosineRandomFeatures":
         """W ~ gamma·dist, b ~ U[0, 2π) (reference: CosineRandomFeatures
         companion object; Cauchy variant for the TIMIT rfType flag)."""
+        return CosineRandomFeatures(
+            *CosineRandomFeatures.draw(
+                num_input_features, num_output_features, gamma, dist, seed
+            )
+        )
+
+    @staticmethod
+    def draw(
+        num_input_features: int,
+        num_output_features: int,
+        gamma: float,
+        dist: str = "gaussian",
+        seed: int = 0,
+    ) -> "tuple[np.ndarray, np.ndarray]":
+        """The host-side (W, b) that :meth:`create` wraps, so that several
+        draws can be stacked into one transformer (pipelines/timit.py)
+        with each row's weights unchanged."""
         rng = np.random.default_rng(seed)
         if dist == "gaussian":
             w = rng.normal(size=(num_output_features, num_input_features))
@@ -88,7 +105,7 @@ class CosineRandomFeatures(BatchTransformer):
         else:
             raise ValueError(f"unknown distribution {dist!r}")
         b = rng.uniform(0.0, 2.0 * np.pi, size=num_output_features)
-        return CosineRandomFeatures(w * gamma, b)
+        return w * gamma, b
 
     def apply_arrays(self, x):
         return jnp.cos(x @ self.w.T + self.b)

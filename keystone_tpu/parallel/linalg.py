@@ -1076,11 +1076,12 @@ def _gram_finish_fn():
     def run(g, c, sa, sb, n):
         # Algebraic centering (Σ(x−μ)(x−μ)ᵀ = G − n·μμᵀ), same identity
         # as the exact solver's fused path — no centered copy exists.
-        mu_a = sa / n
-        mu_b = sb / n
-        gc = g - n * jnp.outer(mu_a, mu_a)
-        cc = c - n * jnp.outer(mu_a, mu_b)
-        return gc, cc, mu_a, mu_b
+        with jax.named_scope("gram/finish"):
+            mu_a = sa / n
+            mu_b = sb / n
+            gc = g - n * jnp.outer(mu_a, mu_a)
+            cc = c - n * jnp.outer(mu_a, mu_b)
+            return gc, cc, mu_a, mu_b
 
     return jax.jit(run)
 

@@ -19,6 +19,7 @@ Axis conventions used throughout the framework:
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 from typing import Optional, Sequence, Tuple
 
@@ -270,11 +271,20 @@ def local_memory_stats() -> list:
     return [s for s in (d.memory_stats() for d in jax.local_devices()) if s]
 
 
+@functools.lru_cache(maxsize=None)
+def _memory_limit_of(devices: tuple) -> Optional[int]:
+    stats = (d.memory_stats() for d in devices)
+    limits = [int(s["bytes_limit"]) for s in stats if s and "bytes_limit" in s]
+    return min(limits) if limits else None
+
+
 def device_memory_limit_bytes() -> Optional[int]:
     """The smallest ``bytes_limit`` over the local devices, or None where
-    the backend reports no memory statistics (CPU test meshes)."""
-    limits = [int(s["bytes_limit"]) for s in local_memory_stats() if "bytes_limit" in s]
-    return min(limits) if limits else None
+    the backend reports no memory statistics (CPU test meshes). Asked of
+    the devices once a process: a device's limit does not change, and
+    ``memory_stats()`` is a call into the runtime that an entry point
+    which sizes every fit by it (pipelines/timit.py) would pay each time."""
+    return _memory_limit_of(tuple(jax.local_devices()))
 
 
 def device_memory_budget_bytes(fraction: float = 0.75) -> int:

@@ -9,6 +9,15 @@ classes.
 Each cosine branch is one whole-batch MXU GEMM + fused cos; the block
 solver's per-block Gram/residual work is sharded over the mesh's data axis
 with psum (the analog of the reference's treeReduce into mlmatrix BCD).
+
+Two forms with one featurizer. Where the feature matrix fits the devices
+(:func:`features_fit_in_core`), :func:`build_pipeline` builds the gather
+of branches and the fit runs in core. Where it does not (TIMIT at its
+published 2.2M frames is 144 GB of features), it builds the same W and b
+stacked into ONE ``CosineRandomFeatures``: a single chain, which the
+streaming plan rule absorbs, so the fit folds row chunks into a Gram
+carry on each device of the data mesh and the features are never held
+(docs/PARTITIONING.md "Fitting TIMIT beyond one chip's memory").
 """
 
 from __future__ import annotations
@@ -28,6 +37,7 @@ from ..ops.learning.block import BlockLeastSquaresEstimator
 from ..ops.stats.core import CosineRandomFeatures
 from ..ops.util.labels import ClassLabelIndicators, MaxClassifier
 from ..ops.util.vectors import VectorCombiner
+from ..parallel.mesh import device_memory_limit_bytes, get_mesh, row_shard_count
 from ..workflow.pipeline import Pipeline
 
 logger = logging.getLogger(__name__)
@@ -64,12 +74,56 @@ def build_featurizer(config: TimitConfig, input_dim: int = TIMIT_DIMENSION) -> P
     return Pipeline.gather(branches) >> VectorCombiner()
 
 
+def build_stacked_featurizer(
+    config: TimitConfig, input_dim: int = TIMIT_DIMENSION
+) -> Pipeline:
+    """:func:`build_featurizer`'s features from one transformer: branch
+    i's W_i and b_i (the same draws, ``seed + i``) stacked in branch
+    order into one ``CosineRandomFeatures`` of ``num_cosines x
+    num_cosine_features`` outputs. Column j of the gather form is column
+    j here, so a fit on either gives weights for the other; and a single
+    chain is what the streaming plan rule takes."""
+    draws = [
+        CosineRandomFeatures.draw(
+            input_dim,
+            config.num_cosine_features,
+            config.gamma,
+            dist=config.rf_type,
+            seed=config.seed + i,
+        )
+        for i in range(config.num_cosines)
+    ]
+    # rounded to float32 a branch at a time, as the gather form's four
+    # constructors round them: the same bits, half the bytes to stack
+    w = np.concatenate([w.astype(np.float32) for w, _ in draws])
+    b = np.concatenate([b.astype(np.float32) for _, b in draws])
+    return CosineRandomFeatures(w, b).to_pipeline()
+
+
+def features_fit_in_core(rows: int, width: int) -> bool:
+    """Whether the in-core fit can hold ``rows x width`` float32 features
+    on this mesh. It keeps the features and their centred copy, row-sharded
+    over the mesh, and its measured peak on the chip is three times the two
+    together (12.2 GiB at 2 x 2 GiB: PERF.md section 5), so they may take a
+    third of the smallest device's memory. A backend that reports no memory
+    (the CPU) is taken to hold them."""
+    limit = device_memory_limit_bytes()
+    if limit is None:
+        return True
+    shards = row_shard_count(get_mesh())
+    return 3 * (2 * 4 * rows * width) <= limit * shards
+
+
 def build_pipeline(config: TimitConfig, train: LabeledData, input_dim: int = TIMIT_DIMENSION) -> Pipeline:
     # A phase of every fit that starts from a configuration, with the
     # device idle: the random features are drawn on the host, in numpy.
     with spans.span("build:pipeline"):
         labels = ClassLabelIndicators(NUM_CLASSES)(train.labels)
-        featurizer = build_featurizer(config, input_dim)
+        width = config.num_cosines * config.num_cosine_features
+        if features_fit_in_core(len(train.data), width):
+            featurizer = build_featurizer(config, input_dim)
+        else:  # the single chain, which streams: the features are never held
+            featurizer = build_stacked_featurizer(config, input_dim)
         return featurizer.then_label_estimator(
             BlockLeastSquaresEstimator(
                 config.num_cosine_features, num_iter=config.num_epochs, reg=config.reg

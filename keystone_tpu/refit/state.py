@@ -41,6 +41,7 @@ plane can import this without paying a backend import.
 from __future__ import annotations
 
 import hashlib
+import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
 
@@ -203,6 +204,46 @@ def load_stream_state(store: Any, name: str) -> Optional[StreamState]:
     return value
 
 
+class _HostFetch:
+    """Device arrays on their way to the host, on a thread of their own.
+    The thread lets the device buffers go as soon as the copy is made, so
+    an estimator that outlives its fit (the prefix table keeps it) holds
+    host memory only. Not a daemon: a process that exits waits for it."""
+
+    def __init__(self, arrays):
+        self._arrays = tuple(arrays)
+        self._host: Optional[Tuple[np.ndarray, ...]] = None
+        self._error: Optional[BaseException] = None
+        self._thread = threading.Thread(target=self._run, name="keystone-state-fetch")
+        self._thread.start()
+
+    def _run(self) -> None:
+        import jax
+
+        try:
+            # Export crosses to host by definition: the envelope must
+            # pickle into the checkpoint store.  # keystone: allow-sync
+            self._host = tuple(np.asarray(jax.device_get(a)) for a in self._arrays)
+        except Exception as e:  # raised again by `result`, where it is asked for
+            self._error = e
+        finally:
+            self._arrays = ()
+
+    def result(self) -> Tuple[np.ndarray, ...]:
+        if self._thread is not None:
+            self._thread.join()
+        if self._error is not None:
+            raise self._error
+        return self._host
+
+    def __getstate__(self):
+        # An estimator that is copied or pickled between its fit and its
+        # export takes the fetched arrays with it, not the thread.
+        if self._thread is not None:
+            self._thread.join()
+        return dict(vars(self), _thread=None)
+
+
 # ------------------------------------------------------------ the Gram mixin
 
 
@@ -221,6 +262,12 @@ class GramStreamStateMixin:
     stream_state_kind = "gram"
 
     def export_stream_state(self) -> Optional[StreamState]:
+        """The envelope of this instance's last streamed fit, its
+        statistics on the host (waits for their fetch, which
+        ``_capture_state`` started, where it has not ended yet)."""
+        fetch = vars(self).pop("_stream_fetch", None)
+        if fetch is not None:
+            self._stream_state.carry = fetch.result()
         return getattr(self, "_stream_state", None)
 
     def merge_stream_state(self, a: StreamState, b: StreamState) -> StreamState:
@@ -294,20 +341,21 @@ class GramStreamStateMixin:
         return jax.block_until_ready(carry)
 
     def _capture_state(self, carry, n_total: int, **meta: Any) -> StreamState:
-        """Device-fetch the post-fold carry into a portable envelope and
-        remember it on the instance for ``export_stream_state``."""
-        import jax
-
-        # Export crosses to host by definition: the envelope must pickle
-        # into the checkpoint store.  # keystone: allow-sync
-        host = tuple(np.asarray(jax.device_get(a)) for a in carry)
+        """Start the post-fold carry's fetch into a portable envelope and
+        remember both on the instance for ``export_stream_state``. The
+        fetch is O(d²) (1.08 GB and a third of a second at TIMIT's
+        width: PERF.md section 6, PR 30) and nothing in the fit reads
+        its result, so it runs beside the finish and whatever the
+        process does next, not between the fold and the finish with the
+        device idle."""
         state = StreamState(
             kind=self.stream_state_kind,
             estimator=f"{type(self).__module__}.{type(self).__qualname__}",
             num_examples=int(n_total),
-            carry=host,
+            carry=(),  # the host arrays, once `export_stream_state` has them
             meta=dict(meta),
         )
+        self._stream_fetch = _HostFetch(carry)
         self._stream_state = state
         return state
 

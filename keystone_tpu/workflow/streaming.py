@@ -46,6 +46,7 @@ in tests/workflow/test_streaming.py).
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import time
@@ -217,6 +218,19 @@ def stream_pipelined(
     return done
 
 
+def _stalls_spanned(queue):
+    """``queue``'s items, each wait for the next one under a
+    ``stream:stall`` span: where the fold's consumer stands still because
+    the host has not prepared the next chunk yet."""
+    while True:
+        with _spans.span("stream:stall"):
+            try:
+                item = next(queue)
+            except StopIteration:
+                return
+        yield item
+
+
 # ------------------------------------------------------------------- reporting
 
 
@@ -314,12 +328,18 @@ def _publish_report(report: StreamReport) -> None:
 
 # ----------------------------------------------------------- fused chunk step
 
-# One jitted (cast → chain → re-zero → estimator step) callable per
-# (member instances, step_fn) pair, shared across folds — same rationale
-# as fusion's _shared_chain_jit: every fit of an unfitted pipeline builds
-# a fresh StreamingFitOperator, and a per-fold jit would retrace the
-# identical program every time (breaking the zero-steady-state-recompile
-# guarantee across repeated fits). Entries keep strong refs to members.
+# One jitted (cast → chain → re-zero → estimator step) callable per chain
+# STRUCTURE, shared across folds and across pipelines. Every fit of an
+# unfitted pipeline builds fresh members and a fresh StreamingFitOperator
+# (``keystone-tpu timit``, each round of the refit daemon), so a cache on
+# member identity retraced and rebuilt the identical program on every fit,
+# with the members' weights baked in as constants, and pinned each retired
+# chain until it aged out. The key is what the trace depends on (member
+# types, static parameters, the shapes and dtypes of their arrays, the
+# step function, the partition); the arrays themselves are ARGUMENTS of
+# the jitted step, placed once per fold. Entries hold templates (members
+# with their arrays taken out), never weights. A member that cannot be
+# split this way is keyed on itself and closed over, as before.
 _STEP_JIT_CACHE = None  # type: ignore
 _STEP_JIT_MAX = 32
 _step_cache_lock = threading.Lock()
@@ -341,9 +361,13 @@ def _apply_chain(members, x, mask):
     import jax
     import jax.numpy as jnp
 
-    x = _cast_tree(x)
+    from .pipeline import feat_scope
+
+    with jax.named_scope("stream/cast"):
+        x = _cast_tree(x)
     for m in members:
-        x = m.apply_arrays(x)
+        with feat_scope(m):  # the name its operations carry in a device trace
+            x = m.apply_arrays(x)
 
     # Re-zero pad rows once at the end of the chain (valid because
     # apply_arrays is row-independent by the BatchTransformer contract)
@@ -356,28 +380,104 @@ def _apply_chain(members, x, mask):
     return jax.tree_util.tree_map(zero_pad, x)
 
 
-def _shared_step_jit(members: tuple, step_fn, partition=None):
-    """jit of (carry, x_raw, y, mask) → (carry', probe), cached on
-    (member ids, step_fn id, partition mesh). Returns
-    (callable, trace_counter_list) — the counter appends at trace time
-    only, making 'exactly one compile per chunk shape' directly
-    observable.
+def _lift_member(m):
+    """``(key, template, arrays)`` of one chain member. ``arrays`` are its
+    attributes that are jax arrays, ``template`` a shallow copy without
+    them, and ``key`` what a trace of ``apply_arrays`` can depend on
+    besides their values: the type, every other attribute, the arrays'
+    shapes and dtypes. A member with an unhashable attribute (or none to
+    read) stays whole: its own key, its own template, nothing lifted."""
+    import copy
+
+    import jax
+
+    try:
+        attrs = vars(m)
+        arrays = {k: v for k, v in attrs.items() if isinstance(v, jax.Array)}
+        static = tuple(sorted(
+            (k, type(v), v) for k, v in attrs.items() if k not in arrays
+        ))
+        hash(static)
+    except TypeError:
+        return _whole_member(m)
+    template = copy.copy(m)
+    vars(template).update(dict.fromkeys(arrays))
+    shapes = tuple((k, v.shape, str(v.dtype)) for k, v in sorted(arrays.items()))
+    return (type(m), static, shapes), template, arrays
+
+
+def _whole_member(m):
+    """``_lift_member``'s result for a member that stays whole: keyed on
+    itself (the cache entry's closure holds it, so its id is not reused
+    meanwhile), its own template, nothing lifted."""
+    return ("whole", id(m)), m, {}
+
+
+def _lift_chain(members, lift: bool = True):
+    """``(key, templates, arrays)`` of a featurize chain: tuples with one
+    entry per member. ``lift=False`` keeps every member whole (a chain
+    whose ``apply_arrays`` reads an array's VALUE while it is traced)."""
+    parts = [(_lift_member if lift else _whole_member)(m) for m in members]
+    return tuple(zip(*parts)) if parts else ((), (), ())
+
+
+def _bind_chain(templates, arrays):
+    """The members to trace: each template with its arrays (tracers,
+    inside the jitted step) put back."""
+    import copy
+
+    bound = []
+    for template, own in zip(templates, arrays):
+        if own:
+            template = copy.copy(template)
+            vars(template).update(own)
+        bound.append(template)
+    return bound
+
+
+class _BoundStep:
+    """The shared jitted step with one fold's member arrays bound:
+    ``step(carry, x_raw, y, mask)``. ``jitted`` takes them as a fifth
+    argument, ``arrays``."""
+
+    def __init__(self, jitted, arrays):
+        self.jitted = jitted
+        self.arrays = arrays
+
+    def __call__(self, carry, x_raw, y, mask):
+        return self.jitted(carry, x_raw, y, mask, self.arrays)
+
+
+def _shared_step_jit(members: tuple, step_fn, partition=None, lift: bool = True):
+    """jit of (carry, x_raw, y, mask, member arrays) → (carry', probe),
+    cached on (chain structure, step_fn, partition). Returns
+    (step, trace_counter_list): ``step(carry, x_raw, y, mask)`` has this
+    chain's arrays bound; the counter appends at trace time only and is
+    shared by every fold that hits the entry, so a fold counts ITS traces
+    from the length it found.
 
     With an eligible ``partition`` decision the fused step runs inside
     ``shard_map`` over the decision's mesh: each device featurizes its
     row slice of the chunk and accumulates into its OWN carry block (the
     carry grows a leading ``(shards,)`` axis sharded over the row axes),
     so no collective runs per chunk — the partial statistics are summed
-    across shards once, at fold finish (docs/PARTITIONING.md)."""
+    across shards once, at fold finish (docs/PARTITIONING.md). The member
+    arrays are replicated over the mesh."""
     global _STEP_JIT_CACHE
     import jax
 
-    key = tuple(id(m) for m in members) + (id(step_fn),)
+    chain_key, templates, arrays = _lift_chain(members, lift)
+    key = (chain_key, step_fn)
     if partition is not None:
         key += (
-            "sharded", id(partition.mesh), partition.shards,
-            getattr(partition, "model_shards", 1),
+            "sharded", partition.mesh, tuple(partition.mesh_axes),
+            tuple(getattr(partition, "carry_axes", partition.mesh_axes)),
+            partition.shards, getattr(partition, "model_shards", 1),
         )
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        everywhere = NamedSharding(partition.mesh, PartitionSpec())
+        arrays = jax.device_put(arrays, everywhere)
     with _step_cache_lock:
         if _STEP_JIT_CACHE is None:
             from collections import OrderedDict
@@ -386,7 +486,7 @@ def _shared_step_jit(members: tuple, step_fn, partition=None):
         hit = _STEP_JIT_CACHE.get(key)
         if hit is not None:
             _STEP_JIT_CACHE.move_to_end(key)
-            return hit[1], hit[2]
+            return _BoundStep(hit[0], arrays), hit[1]
 
     traces: List[tuple] = []
 
@@ -398,9 +498,9 @@ def _shared_step_jit(members: tuple, step_fn, partition=None):
 
     if partition is None:
 
-        def fused(carry, x_raw, y, mask):
+        def fused(carry, x_raw, y, mask, arrays=()):
             traces.append(())  # trace-time side effect: once per new shape
-            x = _apply_chain(members, x_raw, mask)
+            x = _apply_chain(_bind_chain(templates, arrays), x_raw, mask)
             if needs_mask:
                 new_carry = step_fn(carry, x, y, mask)
             else:
@@ -426,16 +526,16 @@ def _shared_step_jit(members: tuple, step_fn, partition=None):
         )
         block_step = getattr(step_fn, "model_block_step", None)
 
-        def fused(carry, x_raw, y, mask):
+        def fused(carry, x_raw, y, mask, arrays=()):
             traces.append(())
 
-            def local(c, x, yb, m):
+            def local(c, x, yb, m, own):
                 # One device's view: carry block (1, …) squeezed, the
                 # chunk's row slice featurized and accumulated locally —
                 # apply_arrays is row-independent (the BatchTransformer
                 # contract), so per-shard application is exact.
                 c0 = jax.tree_util.tree_map(lambda a: a[0], c)
-                feats = _apply_chain(members, x, m)
+                feats = _apply_chain(_bind_chain(templates, own), x, m)
                 # m is this device's row slice of the mask, so an
                 # index-keyed step sees exactly its rows' absolute
                 # indices — per-shard sketch partials stay exact.
@@ -457,9 +557,9 @@ def _shared_step_jit(members: tuple, step_fn, partition=None):
 
             new_carry = _smap(
                 local, mesh=mesh,
-                in_specs=(carry_spec, spec, spec, spec),
+                in_specs=(carry_spec, spec, spec, spec, P()),
                 out_specs=carry_spec,
-            )(carry, x_raw, y, mask)
+            )(carry, x_raw, y, mask, arrays)
             leaf = jax.tree_util.tree_leaves(new_carry)[0]
             probe = leaf.ravel()[:1]
             return new_carry, probe
@@ -469,11 +569,11 @@ def _shared_step_jit(members: tuple, step_fn, partition=None):
     # keystone: owns-donated
     jitted = jax.jit(fused, donate_argnums=(0,))
     with _step_cache_lock:
-        _STEP_JIT_CACHE[key] = ((members, step_fn, partition), jitted, traces)
+        _STEP_JIT_CACHE[key] = (jitted, traces)
         _STEP_JIT_CACHE.move_to_end(key)
         while len(_STEP_JIT_CACHE) > _STEP_JIT_MAX:
             _STEP_JIT_CACHE.popitem(last=False)
-    return jitted, traces
+    return _BoundStep(jitted, arrays), traces
 
 
 # ------------------------------------------------------------------ the stream
@@ -614,6 +714,22 @@ def _merge_blocks(carry, row_shards: int, model_shards: int, layout, np_mod):
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _reduce_fn(row_shards: int, model_shards: int, layout):
+    """The finish-time reduction of a stacked carry as ONE program (its
+    collectives under the scope ``gram/reduce`` in a device trace), per
+    (shard counts, layout); jit's own cache holds one executable per
+    carry shape. The result is replicated over the mesh."""
+    import jax
+    import jax.numpy as jnp
+
+    def reduce(carry):
+        with jax.named_scope("gram/reduce"):
+            return _merge_blocks(carry, row_shards, model_shards, layout, jnp)
+
+    return jax.jit(reduce)
+
+
 def _labels_host(labels: Dataset):
     """Labels as one host (n, k) float-ready matrix. Labels are O(n·k) —
     'the full feature matrix never materializes' is about features; a
@@ -630,7 +746,12 @@ def _labels_host(labels: Dataset):
         y = y[:, None]
     if y.ndim != 2:
         raise StreamingFallback(f"labels must be rank ≤ 2, got {y.shape}")
-    return np.ascontiguousarray(y.astype(transfer_dtype(y.dtype), copy=False))
+    # Not made contiguous here: a label matrix that the executor left on
+    # the device comes back with its rows padded to the device's tiles,
+    # and a contiguous copy of all of it cost 0.67 s of every TIMIT fit
+    # with the device idle (308 MB; PERF.md section 6, PR 30). Each
+    # chunk's rows are made contiguous where the chunk is prepared.
+    return y.astype(transfer_dtype(y.dtype), copy=False)
 
 
 class ChunkStream:
@@ -669,6 +790,10 @@ class ChunkStream:
         self.workers = workers or min(default_ingest_workers(), 4)
         self.num_examples = len(data)
         self._feat_aval = None
+        #: Whether the fused step takes the members' arrays as arguments
+        #: (one compiled step per chain STRUCTURE); `feature_aval` turns
+        #: it off for a chain that does not trace that way.
+        self._lift = True
         # An eligible PartitionDecision (parallel/partitioner.py) runs the
         # sharded chunk plan; the compiled chunk shape must divide evenly
         # across the shards, so round chunk_rows up to a shard multiple.
@@ -701,12 +826,28 @@ class ChunkStream:
 
             x_spec = _chunk_spec(self.data, self.chunk_rows)
             mask_spec = jax.ShapeDtypeStruct((self.chunk_rows, 1), np.float32)
-            try:
-                self._feat_aval = jax.eval_shape(
-                    lambda x, m: _apply_chain(self.members, x, m),
-                    x_spec,
-                    mask_spec,
+
+            def shape_trace():
+                # As the fused step will trace it: the members' arrays
+                # are arguments (abstract here), unless `_lift` is off.
+                _, templates, arrays = _lift_chain(self.members, self._lift)
+                return jax.eval_shape(
+                    lambda own, x, m: _apply_chain(
+                        _bind_chain(templates, own), x, m
+                    ),
+                    arrays, x_spec, mask_spec,
                 )
+
+            try:
+                try:
+                    self._feat_aval = shape_trace()
+                except (
+                    jax.errors.JAXTypeError, jax.errors.UnexpectedTracerError
+                ):
+                    # A member reads an array's VALUE while it is traced
+                    # (concretization): close over this chain instead.
+                    self._lift = False
+                    self._feat_aval = shape_trace()
             except StreamingFallback:
                 raise
             except Exception as e:
@@ -771,7 +912,12 @@ class ChunkStream:
                 carry = _stack_carry(carry, part.shards, sharding)
 
         _quiet_unused_donation_warnings()  # carries are donated each step
-        step, traces = _shared_step_jit(self.members, step_fn, part)
+        step, traces = _shared_step_jit(
+            self.members, step_fn, part, lift=self._lift
+        )
+        # `traces` is shared by every fold over this chain structure: this
+        # fold's compiles are what it appends from here on.
+        trace_base = len(traces)
 
         if not hasattr(type(data), "fetch_rows") or (
             type(data).fetch_rows is Dataset.fetch_rows
@@ -825,7 +971,7 @@ class ChunkStream:
                     lambda a: _pad_narrow(a, padded_rows), x
                 )
                 rows = stop - start
-                y = y_host[start:stop]
+                y = np.ascontiguousarray(y_host[start:stop])
                 if rows < padded_rows:  # tail chunk: pad to compiled shape
                     y = np.concatenate(
                         [y, np.zeros((padded_rows - rows,) + y.shape[1:], y.dtype)]
@@ -990,15 +1136,18 @@ class ChunkStream:
                 # finalize through the jit trace cache (obs/cost.py).
                 avals = jax.tree_util.tree_map(
                     lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                    (carry, x_dev, y_dev, mask_dev),
+                    (carry, x_dev, y_dev, mask_dev, step.arrays),
                 )
-                _cost.note_jit_call("stream_step", step, avals=avals)
+                _cost.note_jit_call("stream_step", step.jitted, avals=avals)
             report.dispatch_t.append(time.perf_counter() - t0)
-            carry, probe_out = step(carry, x_dev, y_dev, mask_dev)
+            with _spans.span(
+                "stream:chunk", index=start_chunk + report.chunks, rows=_rows
+            ):
+                carry, probe_out = step(carry, x_dev, y_dev, mask_dev)
             chunks_c.inc()
             report.chunks += 1
             if report.chunks == 1:
-                report.compiles_first_chunk = len(traces)
+                report.compiles_first_chunk = len(traces) - trace_base
             w = attempt_windows[dispatched]
             folded_log.append(
                 (w[0], w[1], part.shards if part is not None else 1, chunk_rows)
@@ -1029,8 +1178,8 @@ class ChunkStream:
                     )
                     try:
                         stream_pipelined(
-                            queue, stage=stage, compute=compute,
-                            consume=consume, prefetch=1,
+                            _stalls_spanned(queue), stage=stage,
+                            compute=compute, consume=consume, prefetch=1,
                         )
                     except ShardLossError as loss:
                         # Join this attempt's prefetch workers BEFORE
@@ -1041,7 +1190,7 @@ class ChunkStream:
                         queue.close()
                         if report.chunks:
                             prev_base = (
-                                report.compiles_first_chunk
+                                trace_base + report.compiles_first_chunk
                                 if attempt_base is None
                                 else attempt_base
                             )
@@ -1058,7 +1207,8 @@ class ChunkStream:
                         # attempt's first chunk IS the fold's first chunk
                         # — leave the baseline to compiles_first_chunk or
                         # its compiles would double-count as steady-state.
-                        attempt_base = len(traces) if report.chunks else None
+                        trace_base = len(traces)  # the new step's own list
+                        attempt_base = trace_base if report.chunks else None
                         folded_log = []
                         dispatched = 0
                         ckpt_suspended = True
@@ -1084,8 +1234,6 @@ class ChunkStream:
                     # count: the stacked carry must ALWAYS come back to
                     # the estimator's single-device shape (a zero-chunk
                     # fold reduces to the seeded init carry).
-                    import jax.numpy as jnp
-
                     from ..parallel.partitioner import (
                         record_collective_bytes,
                         record_imbalance,
@@ -1095,9 +1243,10 @@ class ChunkStream:
                     layout = (
                         _carry_layout(step_fn, carry) if p_m > 1 else None
                     )
-                    carry = _merge_blocks(
-                        carry, part.shards, p_m, layout, jnp
-                    )
+                    with _spans.span(
+                        "stream:reduce", shards=part.shards, model_shards=p_m
+                    ):
+                        carry = _reduce_fn(part.shards, p_m, layout)(carry)
                     if report.chunks:
                         # Per-axis accounting, plan-pure: with reduced
                         # leaf bytes split into feature (B_f, sharded
@@ -1142,7 +1291,7 @@ class ChunkStream:
             report.stall_s = queue_stall_s
             report.host_buffer_peak_bytes = queue_peak + in_hand_peak
             prev_base = (
-                report.compiles_first_chunk
+                trace_base + report.compiles_first_chunk
                 if attempt_base is None
                 else attempt_base
             )
@@ -1385,7 +1534,9 @@ class ChunkStream:
             new_part, sharding = None, None
             new_chunk_rows = chunk_rows
             carry = jax.tree_util.tree_map(jnp.asarray, surviving)
-        step, traces = _shared_step_jit(self.members, step_fn, new_part)
+        step, traces = _shared_step_jit(
+            self.members, step_fn, new_part, lift=self._lift
+        )
         report.shards = new_part.shards if new_part is not None else 1
         report.model_shards = (
             new_part.model_shards if new_part is not None else 1
