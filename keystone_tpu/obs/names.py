@@ -83,6 +83,7 @@ SOLVER_FIT_SECONDS = "keystone_solver_fit_seconds"
 SOLVER_RUNG_ATTEMPTS = "keystone_solver_rung_attempts_total"
 SOLVER_ITERATIONS = "keystone_solver_iterations_total"
 BCD_FACTOR_REUSE = "keystone_bcd_factor_reuse_total"
+GRAM_SYMMETRIC = "keystone_gram_symmetric_total"
 
 # ---------------------------------------------------------------- sketch tier
 SKETCH_FITS = "keystone_sketch_fits_total"
@@ -258,6 +259,7 @@ SCHEMA: Dict[str, Tuple] = {
     SOLVER_RUNG_ATTEMPTS: ("counter", "Degradation-ladder rung attempts inside solvers", ("solver",)),
     SOLVER_ITERATIONS: ("counter", "Host-level solver iterations (e.g. L-BFGS steps)", ("solver",)),
     BCD_FACTOR_REUSE: ("counter", "In-core block_coordinate_descent calls, by program form: reused = each block's Gram and Cholesky factor computed once in a factor pass and reused in every epoch (num_epochs > 1), single_pass = factored inside the one pass (num_epochs == 1)", ("mode",)),
+    GRAM_SYMMETRIC: ("counter", "Fits whose Gram products come from linalg.gram_sym (one count a streamed Gram fold, one a block_coordinate_descent call), by the column panels its width rule cuts the product into: the upper block triangle is computed and mirrored; 1 = the single full product", ("panels",)),
     SKETCH_FITS: ("counter", "Sketched least-squares fits completed, by sketch variant (countsketch/srht)", ("variant",)),
     SKETCH_SIZE: ("gauge", "Sketch rows s chosen for the last sketched fit (knob/tuned/width default)", ()),
     SKETCH_STATE_BYTES: ("gauge", "Bytes of the last sketched fit's O(s·d) carry — the number KV308 compares to the device budget", ()),

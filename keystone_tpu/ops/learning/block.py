@@ -372,7 +372,10 @@ class BlockLeastSquaresEstimator(GramStreamStateMixin, LabelEstimator):
                 xc = linalg.prepare_row_sharded(xc, mesh)
                 yc = linalg.prepare_row_sharded(yc, mesh)
         # (the 2-D variant factors in every epoch and says nothing)
-        attrs = {} if m > 1 else {"factor_reuse": linalg.bcd_factor_mode(self.num_iter)}
+        attrs = {} if m > 1 else {
+            "factor_reuse": linalg.bcd_factor_mode(self.num_iter),
+            "gram_panels": str(linalg.gram_panels(block)),
+        }
         with _spans.span("solver:bcd", **attrs):
             if m > 1:
                 w = linalg.block_coordinate_descent_2d(

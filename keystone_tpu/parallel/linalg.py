@@ -150,6 +150,64 @@ def mm(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return jnp.matmul(a, b, precision=_solver_precision())
 
 
+# The narrowest diagonal block `gram_sym` halves a width down to. Measured
+# on one v5e chip at the two shapes the benchmark's cells run (PR 31; float32
+# at HIGHEST; ms a product, by this constant):
+#   rows 16,384 x width 16,384 (a streamed chunk's step on one chip): one
+#     full product 283.5; 2048 (8 panels) 165.8; 1024 (16) 167.8; 512 (32) 179.4
+#   rows 32,768 x width 4,096 (an in-core block): one full product 38.9;
+#     2048 (2 panels) 28.5; 1024 (4) 22.5; 512 (8) 21.1
+# 1024 is within 2 ms of the best at the first shape and takes 16.4 of the
+# 17.8 ms the second has to give. Narrower than that the small blocks on
+# and beside the diagonal lose the matmul's efficiency faster than halving
+# again saves work; below 2048 columns nothing is halved at all.
+_GRAM_SYM_PANEL = 1024
+
+
+def gram_panels(d: int) -> int:
+    """How many diagonal blocks (column panels) :func:`gram_sym` halves a
+    width of ``d`` into: the largest power of two that leaves them
+    ``_GRAM_SYM_PANEL`` wide or wider, and 1, the single full product,
+    below twice that. A function of the width alone: the ``panels`` label
+    of ``keystone_gram_symmetric_total`` and the ``gram_panels`` attribute
+    of the ``stream:fold`` and ``solver:bcd`` spans read it too."""
+    return 1 << max(0, (d // _GRAM_SYM_PANEL).bit_length() - 1)
+
+
+def gram_sym(x: jnp.ndarray) -> jnp.ndarray:
+    """``xᵀx`` at the solver precision from its upper block triangle
+    alone: every entry is the dot product over the same rows that
+    ``mm(x.T, x)`` takes, computed once where the full product computes
+    it twice. With m panels the matmuls do (m+1)/(2m) of the full
+    product's work, and the result is symmetric to the bit (the full
+    product on a TPU is not). Narrow widths (``gram_panels`` 1) get the
+    single matmul this replaces."""
+    m = gram_panels(x.shape[1])
+    return _gram_sym_panels(x, m) if m > 1 else mm(x.T, x)
+
+
+def _gram_sym_panels(x: jnp.ndarray, m: int) -> jnp.ndarray:
+    """:func:`gram_sym` at a given power-of-two panel count (tests and the
+    chip measurement force m; callers go through the width rule)."""
+    d = x.shape[1]
+    if m <= 1 or d < 2:
+        # A diagonal block in full, its upper triangle mirrored: whatever
+        # order the matmul sums its passes in, G[r, c] is G[c, r].
+        g = mm(x.T, x)
+        row = lax.broadcasted_iota(jnp.int32, g.shape, 0)
+        col = lax.broadcasted_iota(jnp.int32, g.shape, 1)
+        return jnp.where(row <= col, g, g.T)
+    # [[A, B], [Bᵀ, C]]: one matmul for the block above the diagonal, its
+    # transpose below, and the two diagonal blocks by the same halving.
+    h = -(-d // 2)
+    left, right = x[:, :h], x[:, h:]
+    above = mm(left.T, right)
+    return jnp.block([
+        [_gram_sym_panels(left, m // 2), above],
+        [above.T, _gram_sym_panels(right, m // 2)],
+    ])
+
+
 def mode_jit(fn=None, **jit_kwargs):
     """``jax.jit`` whose compiled-executable cache is ALSO keyed on the
     solver-precision mode: the wrapped function re-traces (and ``mm``
@@ -700,6 +758,7 @@ def block_coordinate_descent(
     from ..obs import cost as _cost, names as _names
 
     _names.metric(_names.BCD_FACTOR_REUSE).inc(mode=bcd_factor_mode(num_epochs))
+    _count_gram_panels(block_size)
     _cost.note_solver_call("solver_bcd", fn, (a, y, reg_arr))
     return fn(a, y, reg_arr)
 
@@ -729,7 +788,7 @@ def _bcd_fn(mesh: Mesh, num_epochs: int, block_size: int, donate_xy: bool = Fals
 
         def gram(a_b):
             with jax.named_scope(BCD_GRAM):
-                return lax.psum(mm(a_b.T, a_b), axes)
+                return lax.psum(gram_sym(a_b), axes)
 
         def factorize(g):
             with jax.named_scope(BCD_CHOLESKY):
@@ -1035,11 +1094,29 @@ def gram_stream_step(carry, x, y):
         x = x.astype(g.dtype)
         y = y.astype(g.dtype)
         return (
-            g + mm(x.T, x),
+            g + gram_sym(x),
             c + mm(x.T, y),
             sa + jnp.sum(x, axis=0),
             sb + jnp.sum(y, axis=0),
         )
+
+
+def _count_gram_panels(width: int) -> str:
+    """One count of ``keystone_gram_symmetric_total`` for a fit whose
+    Gram products are ``width`` wide; returns the ``panels`` label."""
+    from ..obs import names as _names
+
+    panels = str(gram_panels(width))
+    _names.metric(_names.GRAM_SYMMETRIC).inc(panels=panels)
+    return panels
+
+
+def _note_gram_fold(carry) -> dict:
+    """``ChunkStream.fold`` calls this once a fold with the estimator's
+    carry: counts the fold by the panels :func:`gram_stream_step` will
+    cut its Gram into, and returns the same as the ``gram_panels``
+    attribute of ``stream:fold``."""
+    return {"gram_panels": _count_gram_panels(carry[0].shape[0])}
 
 
 def gram_stream_block_step(carry, x, y, block_index):
@@ -1069,6 +1146,7 @@ def gram_stream_block_step(carry, x, y, block_index):
 # shape and accumulated only on model block 0).
 gram_stream_step.model_layout = (0, 0, 0, None)
 gram_stream_step.model_block_step = gram_stream_block_step
+gram_stream_step.note_fold = _note_gram_fold
 
 
 @_mode_cached()

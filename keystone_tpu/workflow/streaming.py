@@ -886,6 +886,13 @@ class ChunkStream:
             # divisibility, and the width floor — and demote to row-only
             # (same mesh, replicated over model) when any fail.
             part = self._validate_model_axis(part, step_fn, carry)
+        # A step may say something about the fold it is about to run (the
+        # Gram step: the panels of its symmetric product), for a counter of
+        # its own and as attributes of `stream:fold`. The model-axis block
+        # step is another function and says nothing.
+        note_fold = getattr(step_fn, "note_fold", None)
+        blocked = part is not None and part.model_shards > 1
+        fold_attrs = note_fold(carry) if note_fold and not blocked else {}
         durable = self.durable
         lease = self.lease
         sharding = None
@@ -1166,7 +1173,7 @@ class ChunkStream:
         try:
             with _spans.span(
                 "stream:fold", chunks=len(windows), chunk_rows=chunk_rows,
-                shards=report.shards,
+                shards=report.shards, **fold_attrs,
             ):
                 while True:
                     queue = PrefetchQueue(

@@ -275,7 +275,9 @@ def test_uploads_are_counted_once_per_branch():
 @pytest.mark.parametrize("num_iter,mode", [(5, "reused"), (1, "single_pass")])
 def test_the_in_core_solve_says_whether_it_reuses_its_factors(num_iter, mode):
     """`solver:bcd` carries `factor_reuse`, and the counter takes one
-    count a `block_coordinate_descent` call under the same mode."""
+    count a `block_coordinate_descent` call under the same mode; beside
+    them `gram_panels` and `keystone_gram_symmetric_total`, "1" at a
+    block this narrow (`linalg.gram_sym`'s single matmul)."""
     from keystone_tpu.ops.learning.block import BlockLeastSquaresEstimator
 
     rng = np.random.default_rng(4)
@@ -287,14 +289,20 @@ def test_the_in_core_solve_says_whether_it_reuses_its_factors(num_iter, mode):
         metric = registry.get(names.BCD_FACTOR_REUSE)
         return metric.value(mode=label) if metric else 0.0
 
+    def panelled():
+        metric = registry.get(names.GRAM_SYMMETRIC)
+        return metric.value(panels="1") if metric else 0.0
+
     other = "single_pass" if mode == "reused" else "reused"
-    before, before_other = counted(mode), counted(other)
+    before, before_other, before_panelled = counted(mode), counted(other), panelled()
     with spans.tracing_session("t") as session:
         BlockLeastSquaresEstimator(16, num_iter=num_iter, reg=0.0).fit(x, y)
     (bcd,) = session.find("solver:bcd")
     assert bcd.attributes["factor_reuse"] == mode
+    assert bcd.attributes["gram_panels"] == "1"
     assert counted(mode) - before == 1
     assert counted(other) == before_other
+    assert panelled() - before_panelled == 1
 
 
 # ------------------------------------------------- scopes on the kernels

@@ -213,6 +213,108 @@ def test_bcd_factor_mode_follows_the_static_epoch_count(num_epochs, mode):
     assert linalg.bcd_factor_mode(num_epochs) == mode
 
 
+# ------------------------------------------------- symmetric Gram product
+
+
+@pytest.mark.parametrize(
+    "rows,width,panels",
+    [
+        (48, 32, 2), (48, 32, 4), (48, 32, 8),  # the forced panel counts
+        (48, 30, 4),  # a width the panels do not divide: 8, 7, 8, 7
+        (48, 17, 8),  # 3, 2, 2, 2, 2, 2, 2, 2
+        (48, 3, 8),  # fewer columns than panels: 1, 1, 1
+        (1, 32, 4),  # one row
+        (48, 32, 1),  # one block: the full product, mirrored
+    ],
+)
+@pytest.mark.parametrize("pad_rows", [0, 16])
+def test_gram_sym_is_the_full_product_from_its_upper_block_triangle(rows, width, panels, pad_rows):
+    x = np.concatenate([rand((rows, width), seed=rows + width), np.zeros((pad_rows, width), np.float32)])
+    got = np.asarray(linalg._gram_sym_panels(x, panels))
+    assert got.dtype == np.float32 and got.shape == (width, width)
+    assert np.array_equal(got, got.T)  # to the bit
+    full = np.asarray(linalg.mm(x.T, x))  # HIGHEST, float32
+    exact = x.astype(np.float64).T @ x.astype(np.float64)
+    if panels == 1:
+        assert np.array_equal(np.triu(got), np.triu(full))
+    # each entry is the same dot product over the same rows: as close to
+    # the exact product as the full product is, and within rounding of it
+    tol = 8 * np.finfo(np.float32).eps * np.sqrt(rows) * np.abs(exact).max()
+    assert np.abs(got - exact).max() <= tol
+    assert np.abs(got - full).max() <= tol
+
+
+def test_gram_sym_takes_its_panels_from_the_width_alone():
+    assert linalg.gram_panels(64) == 1  # the tests' widths, MNIST-sized fits
+    x = rand((8, 64), seed=9)
+    assert np.array_equal(np.asarray(linalg.gram_sym(x)), np.asarray(linalg.mm(x.T, x)))
+    assert linalg.gram_panels(16384) > 1  # the streamed cell's width
+    wide = rand((4, 2 * linalg._GRAM_SYM_PANEL), seed=10)
+    got = np.asarray(linalg.gram_sym(wide))
+    assert np.array_equal(got, got.T)
+    np.testing.assert_allclose(got, wide.T @ wide, rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("num_epochs", [1, 5], ids=["single_pass", "factor_pass"])
+def test_bcd_with_a_panelled_gram_matches_plain_gauss_seidel(mesh, monkeypatch, num_epochs):
+    """The in-core program with `gram_sym` engaged (inside `shard_map`,
+    under the factor pass's `lax.map`, ahead of the `psum`)."""
+    # a block size no other test has: `_bcd_fn` keeps what it traced under this rule
+    block_size = 20
+    monkeypatch.setattr(linalg, "_GRAM_SYM_PANEL", 5)
+    assert linalg.gram_panels(block_size) == 4
+    a = rand((93, 3 * block_size), seed=13)
+    y = rand((93, 3), seed=14)
+    with use_mesh(mesh):
+        w = linalg.block_coordinate_descent(
+            linalg.prepare_row_sharded(a),
+            linalg.prepare_row_sharded(y),
+            reg=1e-3,
+            num_epochs=num_epochs,
+            block_size=block_size,
+        )
+    expected = _gauss_seidel(a, y, 1e-3, num_epochs, block_size)
+    np.testing.assert_allclose(np.asarray(w), expected, rtol=1e-4, atol=1e-5)
+
+
+def _dot_flops(jaxpr):
+    """2 x multiply-adds over every `dot_general` of a jaxpr, nested ones too."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            (contract, _), _ = eqn.params["dimension_numbers"]
+            lhs = eqn.invars[0].aval.shape
+            k = int(np.prod([lhs[i] for i in contract]))
+            total += 2 * k * int(np.prod(eqn.outvars[0].aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            total += _dot_flops(sub)
+    return total
+
+
+@pytest.mark.parametrize(
+    "rows,width",
+    [(16384, 16384), (32768, 4096)],
+    ids=["streamed_chunk_a_chip", "in_core_block"],
+)
+def test_the_gram_at_the_cells_shapes_is_not_the_full_product(rows, width):
+    """Trace only, no compute: the guard against the full product coming
+    back quietly. The width rule engages at both shapes, and the matmuls
+    of `gram_stream_step` do at most 0.65 of 2nd² (+ the cross product)."""
+    classes = 147
+    f32 = np.float32
+    carry = tuple(
+        jax.ShapeDtypeStruct(shape, f32)
+        for shape in [(width, width), (width, classes), (width,), (classes,)]
+    )
+    x = jax.ShapeDtypeStruct((rows, width), f32)
+    y = jax.ShapeDtypeStruct((rows, classes), f32)
+    flops = _dot_flops(jax.make_jaxpr(linalg.gram_stream_step)(carry, x, y).jaxpr)
+    full, cross = 2 * rows * width * width, 2 * rows * width * classes
+    m = linalg.gram_panels(width)
+    assert m > 1 and flops == cross + full * (m + 1) // (2 * m)
+    assert flops <= 0.65 * full + cross
+
+
 # --------------------------------------------------------- hybrid (DCN) mesh
 
 
