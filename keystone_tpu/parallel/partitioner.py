@@ -310,6 +310,29 @@ def record_collective_bytes(nbytes: int, axis: str = "data") -> None:
     _names.metric(_names.PARTITION_COLLECTIVE_BYTES).inc(int(nbytes), axis=axis)
 
 
+def reduction_collective_bytes(
+    leaf_nbytes: Sequence[int],
+    layout: Sequence[Optional[int]],
+    shards: int,
+    model_shards: int,
+) -> Tuple[int, int]:
+    """``(data, model)`` payload bytes of the finish-time reduction of a
+    stacked streaming carry, plan-pure: from the REDUCED leaves' byte
+    counts, their layout (the axis a leaf's feature blocks split along,
+    None for a leaf every block holds whole) and the shard counts. With
+    the bytes split into feature (B_f, sharded over model) and remainder
+    (B_r, replicated), each device block holds B_f/p_m + B_r. The
+    data-axis sum moves one block per non-root row shard per model
+    column; the model-axis reassembly moves one block per non-root model
+    column. At p_m = 1 the data term is the historical
+    bytes × (shards − 1)."""
+    b_f = sum(n for n, ax in zip(leaf_nbytes, layout) if ax is not None)
+    b_r = sum(leaf_nbytes) - b_f
+    data = (b_f + model_shards * b_r) * (shards - 1)
+    model = (b_f // model_shards + b_r) * (model_shards - 1)
+    return data, model
+
+
 def record_imbalance(kind: str, logical_rows: int, padded_rows: int) -> None:
     """Per-device imbalance: the fraction of sharded rows that are pad
     (devices holding pad rows do the same FLOPs for no useful output)."""

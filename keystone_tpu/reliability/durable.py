@@ -267,6 +267,39 @@ class DurableFold:
     start_chunk: int = 0            # chunks to skip (resumed fold)
     resume_rows: int = 0            # rows those skipped chunks held
     seed_rows: int = 0              # non-dataset rows in the seed state
+    suspended: bool = False         # no more commits this fold (shard loss)
+    _committed_at: int = field(default=-1, repr=False)
+
+    def suspend(self) -> None:
+        """The fold lost a shard: recovery windows break the canonical
+        chunk-prefix ordering a cursor describes, so checkpoints suspend
+        for the remainder of the fold."""
+        self.suspended = True
+
+    def at_boundary(self, dispatched: int, snapshot, force: bool = False) -> bool:
+        """The chunk-boundary decision, and the commit where it says so:
+        the fold is about to dispatch chunk ``dispatched`` of its attempt
+        (``start_chunk + dispatched`` of the fit). Commits every
+        ``ckpt_every`` chunks, or at any boundary when ``force`` (the
+        lease is yielding here); never before the first chunk, twice at
+        one index, or while suspended. ``snapshot()`` is called only for
+        a commit and returns :meth:`commit`'s other arguments — it holds
+        the commit-before-continue barrier. True when a cursor was
+        written."""
+        due = force or (
+            self.ckpt_every > 0 and dispatched % self.ckpt_every == 0
+        )
+        if (
+            not due
+            or self.suspended
+            or dispatched <= 0
+            or dispatched == self._committed_at
+        ):
+            return False
+        self._committed_at = dispatched
+        return self.commit(
+            chunk_index=self.start_chunk + dispatched, **snapshot()
+        )
 
     def cursor(
         self,
@@ -298,11 +331,11 @@ class DurableFold:
         model_shards: int = 1,
     ) -> bool:
         """Persist one mid-fit snapshot (atomic tmp+rename underneath).
-        Called by the fold with the carry ALREADY host-fetched and
-        shard-merged — the commit-before-continue barrier is the fold's
-        job; this is just the write. Best-effort: a failed write is
-        ledgered and the fit continues (durability must never fail a
-        fit that would have succeeded)."""
+        Called (by :meth:`at_boundary`) with the carry ALREADY
+        host-fetched and shard-merged — the commit-before-continue barrier
+        is the fold's snapshot; this is just the write. Best-effort: a
+        failed write is ledgered and the fit continues (durability must
+        never fail a fit that would have succeeded)."""
         state = StreamState(
             kind=self.kind,
             estimator=self.estimator,

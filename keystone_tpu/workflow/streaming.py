@@ -66,6 +66,7 @@ from ..obs import cost as _cost
 from ..obs import names as _names
 from ..obs import spans as _spans
 from ..obs import store as _store
+from ..reliability.durable import ShardLossError, shard_loss_index
 from ..reliability.faultinject import probe
 from .graph import Graph, NodeId, SourceId
 from .operators import DatasetOperator, EstimatorOperator, TransformerOperator
@@ -282,15 +283,21 @@ class StreamReport:
     dispatch_t: List[float] = field(default_factory=list)
     compute_done_t: List[float] = field(default_factory=list)
 
+    @property
+    def measured_steady_state(self) -> bool:
+        """Not resumed, not preempted, no shard loss: the fold's wall is a
+        steady-state measurement of all its rows, not one of recovery or
+        of a prefix."""
+        return (
+            self.resumed_from_chunk is None
+            and self.preempted_at_chunk is None
+            and not self.shard_losses
+        )
+
     def overlap_ok(self) -> bool:
         """True when the upload of chunk i+1 was issued before compute
         of chunk i was observed complete — the double-buffer invariant."""
-        if self.chunks < 2:
-            return True
-        return all(
-            self.upload_issued_t[i + 1] <= self.compute_done_t[i]
-            for i in range(self.chunks - 1)
-        )
+        return self.overlap_efficiency() == 1.0
 
     def overlap_efficiency(self) -> float:
         """Fraction of chunk boundaries where the next upload was in
@@ -496,18 +503,21 @@ def _shared_step_jit(members: tuple, step_fn, partition=None, lift: bool = True)
     # signature untouched.
     needs_mask = bool(getattr(step_fn, "needs_mask", False))
 
+    def accumulate(step, carry, x, y, mask, *block):
+        if needs_mask:
+            return step(carry, x, y, mask, *block)
+        return step(carry, x, y, *block)
+
+    def probed(new_carry):
+        leaf = jax.tree_util.tree_leaves(new_carry)[0]
+        return new_carry, leaf.ravel()[:1]  # tiny, NOT donated: safe to block on
+
     if partition is None:
 
         def fused(carry, x_raw, y, mask, arrays=()):
             traces.append(())  # trace-time side effect: once per new shape
             x = _apply_chain(_bind_chain(templates, arrays), x_raw, mask)
-            if needs_mask:
-                new_carry = step_fn(carry, x, y, mask)
-            else:
-                new_carry = step_fn(carry, x, y)
-            leaf = jax.tree_util.tree_leaves(new_carry)[0]
-            probe = leaf.ravel()[:1]  # tiny, NOT donated: safe to block on
-            return new_carry, probe
+            return probed(accumulate(step_fn, carry, x, y, mask))
 
     else:
         from jax.sharding import PartitionSpec as P
@@ -545,24 +555,16 @@ def _shared_step_jit(members: tuple, step_fn, partition=None, lift: bool = True)
                     # the (traced) model-axis position and slices its own
                     # columns out of the full-width featurized chunk.
                     j = jax.lax.axis_index(MODEL_AXIS)
-                    if needs_mask:
-                        c1 = block_step(c0, feats, yb, m, j)
-                    else:
-                        c1 = block_step(c0, feats, yb, j)
-                elif needs_mask:
-                    c1 = step_fn(c0, feats, yb, m)
+                    c1 = accumulate(block_step, c0, feats, yb, m, j)
                 else:
-                    c1 = step_fn(c0, feats, yb)
+                    c1 = accumulate(step_fn, c0, feats, yb, m)
                 return jax.tree_util.tree_map(lambda a: a[None], c1)
 
-            new_carry = _smap(
+            return probed(_smap(
                 local, mesh=mesh,
                 in_specs=(carry_spec, spec, spec, spec, P()),
                 out_specs=carry_spec,
-            )(carry, x_raw, y, mask, arrays)
-            leaf = jax.tree_util.tree_leaves(new_carry)[0]
-            probe = leaf.ravel()[:1]
-            return new_carry, probe
+            )(carry, x_raw, y, mask, arrays))
 
     # carry is owned by the fold loop: created by gram_stream_init (or a
     # refit state seed) and threaded only through this step.
@@ -613,29 +615,6 @@ def _stacked_from_blocks(shape, dtype, sharding, seed_block):
     return jax.make_array_from_single_device_arrays(shape, sharding, blocks)
 
 
-def _stack_seeded(a, blocks: int, sharding):
-    """``blocks`` leading blocks shaped like ``a``: block 0 is ``a``, the
-    rest zeros."""
-    return _stacked_from_blocks(
-        (blocks,) + tuple(a.shape), a.dtype, sharding,
-        lambda i: a if i == 0 else None,
-    )
-
-
-def _stack_carry(carry, shards: int, sharding):
-    """Per-device carry blocks: a leading ``(shards,)`` axis sharded over
-    the row axes. Shard 0 seeds the estimator's initial carry (or a
-    salvaged shard-loss merge), the rest start zero — exact for the
-    additive accumulation the fit_stream protocol is (final carry =
-    seed + Σ partials, summed once at finish)."""
-    import jax
-    import jax.numpy as jnp
-
-    return jax.tree_util.tree_map(
-        lambda a: _stack_seeded(jnp.asarray(a), shards, sharding), carry
-    )
-
-
 def _carry_layout(step_fn, carry) -> Optional[Tuple[Optional[int], ...]]:
     """The blocked-carry protocol's per-leaf feature axes, validated
     against the actual carry structure — ``None`` when the step doesn't
@@ -651,49 +630,90 @@ def _carry_layout(step_fn, carry) -> Optional[Tuple[Optional[int], ...]]:
     return tuple(layout)
 
 
-def _stack_carry_2d(carry, row_shards: int, model_shards: int, layout, sharding):
-    """2-D per-device carry blocks: leading axis ``row_shards ×
-    model_shards`` sharded over ``(row axes, model)`` — flat block index
-    ``data_idx·model_shards + model_idx``, row-major. Feature leaves
-    (``layout`` axis int) split into model blocks; the SEED therefore
-    lands spread over blocks 0..model_shards−1 (data row 0). Feature-free
-    leaves (``layout`` None) keep full shape per block and seed only
-    block 0 — the finish reduce SUMS them across both axes, so the
-    additive contract holds leaf-wise."""
+@dataclass(frozen=True)
+class _FoldLayout:
+    """Where one attempt of a fold keeps its carry and sends its chunks:
+    built in one place (``ChunkStream._layout``) from a partition
+    decision, the step and the estimator's carry. ``part`` None is the
+    single-device value: one shard, no shardings, nothing stacked."""
+
+    part: Any  # eligible PartitionDecision, or None
+    shards: int  # row shards the chunk rows split across
+    model_shards: int  # feature blocks of a 2-D layout (1 = row-only)
+    mesh_shape: Tuple[int, ...]
+    chunk_sharding: Any  # rows over the row axes
+    carry_sharding: Any  # leading block axis over (row axes[, model])
+    #: Per carry leaf, the axis its feature blocks split along, or None
+    #: for a leaf every block holds whole (all of them in a 1-D layout).
+    carry_layout: Tuple[Optional[int], ...]
+    step: _BoundStep
+    #: The step's trace list, shared by every fold over this chain
+    #: structure: a fold's compiles are what it appends from here on.
+    traces: List[tuple]
+    chunk_rows: int
+
+    @property
+    def sharded(self) -> bool:
+        return self.part is not None
+
+    @property
+    def total_shards(self) -> int:
+        return self.shards * self.model_shards
+
+
+def _stack_carry(carry, layout: _FoldLayout):
+    """``carry`` as device arrays, one block per device where the layout
+    is sharded: a leading ``row_shards × model_shards`` axis over ``(row
+    axes[, model])`` — flat block index ``data_idx·model_shards +
+    model_idx``, row-major. Feature leaves (``carry_layout`` axis int)
+    split into model blocks; the SEED therefore lands spread over blocks
+    0..model_shards−1 (data row 0). Feature-free leaves (``carry_layout``
+    None) keep full shape per block and seed only block 0, the rest start
+    zero — exact for the additive accumulation the fit_stream protocol is
+    (final carry = seed + Σ partials, summed once at finish, leaf-wise)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
-    total = row_shards * model_shards
+    if not layout.sharded:
+        return jax.tree_util.tree_map(jnp.asarray, carry)
+    total, p_m = layout.total_shards, layout.model_shards
     leaves, treedef = jax.tree_util.tree_flatten(carry)
 
     def stack(a, ax):
         a = jnp.asarray(a)
         if ax is None:
-            return _stack_seeded(a, total, sharding)
-        b = a.shape[ax] // model_shards
-        block_shape = a.shape[:ax] + (b,) + a.shape[ax + 1:]
+            return _stacked_from_blocks(
+                (total,) + a.shape, a.dtype, layout.carry_sharding,
+                lambda i: a if i == 0 else None,
+            )
+        b = a.shape[ax] // p_m
         return _stacked_from_blocks(
-            (total,) + block_shape, a.dtype, sharding,
+            (total,) + a.shape[:ax] + (b,) + a.shape[ax + 1:],
+            a.dtype, layout.carry_sharding,
             lambda i: (
                 lax.slice_in_dim(a, i * b, (i + 1) * b, axis=ax)
-                if i < model_shards
+                if i < p_m
                 else None
             ),
         )
 
     return jax.tree_util.tree_unflatten(
-        treedef, [stack(a, ax) for a, ax in zip(leaves, layout)]
+        treedef, [stack(a, ax) for a, ax in zip(leaves, layout.carry_layout)]
     )
 
 
-def _merge_blocks(carry, row_shards: int, model_shards: int, layout, np_mod):
-    """Reduce a stacked ``(row_shards·model_shards, …)`` carry back to the
-    estimator's single-device shape: partials SUM across the data axis;
-    feature leaves then CONCATENATE their model blocks along the layout
-    axis, feature-free leaves sum (only model block 0 accumulated them).
-    ``np_mod`` is numpy for host merges (checkpoints, salvage) or
-    jax.numpy for the on-device finish reduce."""
+def _merge_blocks(
+    carry, row_shards: int, model_shards: int, layout, np_mod, drop_row=None
+):
+    """THE additive contract of a stacked ``(row_shards·model_shards, …)``
+    carry, back in the estimator's single-device shape: partials SUM
+    across the data axis; feature leaves then CONCATENATE their model
+    blocks along the layout axis, feature-free leaves sum (only model
+    block 0 accumulated them). ``np_mod`` is numpy for host merges
+    (checkpoints, salvage) or jax.numpy for the on-device finish reduce.
+    ``drop_row`` leaves one data row-group out of the sum: what survives
+    a device lost from that group (all of it dropped gives zeros)."""
     import jax
 
     leaves, treedef = jax.tree_util.tree_flatten(carry)
@@ -702,7 +722,10 @@ def _merge_blocks(carry, row_shards: int, model_shards: int, layout, np_mod):
 
     def merge(a, ax):
         a = np_mod.asarray(a)
-        a = a.reshape((row_shards, model_shards) + a.shape[1:]).sum(axis=0)
+        a = a.reshape((row_shards, model_shards) + a.shape[1:])
+        if drop_row is not None:
+            a = np_mod.delete(a, drop_row, axis=0)
+        a = a.sum(axis=0)
         if ax is None or model_shards == 1:
             return a.sum(axis=0) if ax is None else a[0]
         return np_mod.concatenate(
@@ -752,6 +775,212 @@ def _labels_host(labels: Dataset):
     # with the device idle (308 MB; PERF.md section 6, PR 30). Each
     # chunk's rows are made contiguous where the chunk is prepared.
     return y.astype(transfer_dtype(y.dtype), copy=False)
+
+
+def _chunk_boundary(durable, lease, dispatched: int, snapshot, report) -> None:
+    """The one call a fold with a durability plan or a lease makes before
+    it dispatches a chunk, ``dispatched`` chunks into its attempt. The
+    lease says whether the fold yields here (sched/scheduler.py), the plan
+    whether this boundary commits ``snapshot()`` (reliability/durable.py;
+    a yield asks it to). The order is the preemption contract: commit the
+    durable cursor FIRST — a deferred fold must resume from here, not
+    restart — then mark the lease, then unwind. The prefix carry stays
+    valid statistics; the caller reads ``report.preempted_at_chunk`` and
+    re-leases later."""
+    yielding = lease is not None and dispatched > 0 and lease.should_yield()
+    if durable is not None and durable.at_boundary(
+        dispatched, snapshot, force=yielding
+    ):
+        report.checkpoints += 1
+    if yielding:
+        at = dispatched + (durable.start_chunk if durable is not None else 0)
+        report.preempted_at_chunk = at
+        lease.mark_preempted(at)
+        raise FoldPreempted(at)
+
+
+class _FoldRun:
+    """What one fold threads through its chunks. ``stage`` / ``compute``
+    / ``consume`` are ``stream_pipelined``'s three callbacks and
+    ``prepare`` the prefetch workers' job; ``begin`` starts an attempt —
+    the fold's first, or the one salvage made of a shard loss, which
+    replaces layout, carry and windows together."""
+
+    def __init__(self, stream, report, y_host, start_chunk, rows_folded):
+        self.stream, self.report, self.y_host = stream, report, y_host
+        self.guarded = stream.durable is not None or stream.lease is not None
+        #: Absolute index of the fold's first window (a resumed fold
+        #: starts at its cursor's).
+        self.start_chunk = start_chunk
+        #: ABSOLUTE logical rows fully dispatched (a resumed fold starts
+        #: at the cursor's count) — what a committed cursor records.
+        self.rows_folded = rows_folded
+        self.in_hand_peak = 0
+        self.chunks_c = _names.metric(_names.STREAM_CHUNKS)
+        self.bytes_c = _names.metric(_names.STREAM_BYTES)
+
+    def begin(self, layout: _FoldLayout, carry, windows) -> None:
+        report = self.report
+        self.layout, self.windows = layout, windows
+        report.shards, report.model_shards = layout.shards, layout.model_shards
+        report.mesh_shape = layout.mesh_shape
+        # Shard-loss recovery must be able to re-add the attempt's seed
+        # when the device holding carry block 0 dies: keep the PRE-STACK
+        # carry alive (stacking copies, nothing donates it) — on the
+        # device where it is there — and fetch it to host only if that
+        # loss actually happens.
+        self.seed = carry if layout.sharded else None
+        self.carry = _stack_carry(carry, layout)
+        #: Chunks of ``windows`` dispatched, in order.
+        self.dispatched = 0
+        # A fold's compiles are what its attempts append to their steps'
+        # trace lists, past the fold's first chunk.
+        self._trace_base = len(layout.traces)
+
+    def new_traces(self) -> int:
+        """Traces of the attempt's step since the last call (or since the
+        attempt began)."""
+        base, self._trace_base = self._trace_base, len(self.layout.traces)
+        return self._trace_base - base
+
+    def prepare(self, window):
+        import jax
+        import numpy as np
+
+        start, stop = window
+        padded_rows = self.layout.chunk_rows
+        # fetch_rows runs inside the prefetch workers — this is the
+        # decode/stack work being overlapped with device compute.
+        x = self.stream.data.fetch_rows(start, stop)
+        x = jax.tree_util.tree_map(lambda a: _pad_narrow(a, padded_rows), x)
+        # The labels' rows are made contiguous here, not all at once
+        # (`_labels_host`), and the tail chunk padded to the compiled shape.
+        y = _pad_narrow(self.y_host[start:stop], padded_rows)
+        rows = stop - start
+        # The pad-mask lane carries each row's ABSOLUTE dataset index + 1
+        # (0 = pad). The chain only tests m > 0, so this is
+        # backward-compatible; index-keyed folds (the sketch tier) read
+        # the value itself, which stays exact in float32 up to 2^24 rows
+        # (sketch/core.py refuses longer streams).
+        mask = np.zeros((padded_rows, 1), np.float32)
+        mask[:rows, 0] = np.arange(start + 1, stop + 1, dtype=np.float32)
+        return x, y, mask, rows
+
+    def stage(self, chunk):
+        import jax
+
+        x, y, mask, rows = chunk
+        report, sharding = self.report, self.layout.chunk_sharding
+        nbytes = _tree_nbytes(x) + y.nbytes + mask.nbytes
+        self.in_hand_peak = max(self.in_hand_peak, nbytes)
+        report.upload_issued_t.append(time.perf_counter() - report.t0_s)
+
+        # Async uploads at transfer (narrow) width; cast happens on device
+        # inside the fused step. Under a partition decision every leaf
+        # lands row-sharded over the mesh — each device receives only its
+        # slice of the chunk.
+        def put(a):
+            return jax.device_put(a, sharding)
+
+        dev = (jax.tree_util.tree_map(put, x), put(y), put(mask), rows)
+        report.bytes_transferred += nbytes
+        self.bytes_c.inc(nbytes)
+        return dev
+
+    def snapshot(self):
+        """``DurableFold.commit``'s arguments for the carry as it stands:
+        a mesh-INDEPENDENT host snapshot and the cursor's geometry."""
+        import jax
+        import numpy as np
+
+        lay = self.layout
+        # Commit-before-continue barrier: the carry is host-fetched
+        # (device_get blocks until the last dispatch retired) and the
+        # atomic store write completes BEFORE the next chunk's dispatch
+        # donates the buffer — a persisted carry is never stale.
+        # keystone: allow-sync
+        host = jax.device_get(self.carry)
+        if lay.sharded:
+            # Per-shard partials merge via the additive contract (rows
+            # summed, feature blocks reassembled): resume may re-plan on
+            # any mesh shape, 1-D or 2-D. Operates on the already-fetched
+            # HOST tree, never a device array.  # keystone: allow-sync
+            host = _merge_blocks(
+                host, lay.shards, lay.model_shards, lay.carry_layout, np
+            )
+        return dict(
+            host_carry=tuple(
+                np.asarray(a)  # host leaves  # keystone: allow-sync
+                for a in jax.tree_util.tree_leaves(host)
+            ),
+            rows_consumed=self.rows_folded,
+            chunk_rows=lay.chunk_rows,
+            mesh_shape=lay.mesh_shape,
+            shards=lay.shards,
+            model_shards=lay.model_shards,
+        )
+
+    def compute(self, staged_chunk, _chunk):
+        import jax
+
+        x_dev, y_dev, mask_dev, rows = staged_chunk
+        lay, report = self.layout, self.report
+        if self.guarded:
+            _chunk_boundary(
+                self.stream.durable, self.stream.lease, self.dispatched,
+                self.snapshot, report,
+            )
+        if lay.sharded:
+            try:
+                probe("parallel.shard_loss")
+            except Exception as exc:
+                # Any injected fault at this site models the runtime
+                # observing a device gone from the mesh before this chunk
+                # could dispatch — the fold's elastic recovery owns it.
+                # Indexed over ALL carry blocks (row × model shards, flat
+                # row-major) so a seeded fault can land on either axis of
+                # a 2-D layout.
+                raise ShardLossError(
+                    shard_loss_index(lay.total_shards),
+                    self.start_chunk + self.dispatched,
+                    lay.total_shards,
+                ) from exc
+        probe("streaming.chunk")
+        if not report.chunks and _cost.current_frame() is not None:
+            # Cost-observatory note, once per fold: avals (not the arrays
+            # — the carry is donated into the step) so the per-chunk
+            # program's flop/byte facts harvest at node finalize through
+            # the jit trace cache (obs/cost.py).
+            avals = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                (self.carry, x_dev, y_dev, mask_dev, lay.step.arrays),
+            )
+            _cost.note_jit_call("stream_step", lay.step.jitted, avals=avals)
+        report.dispatch_t.append(time.perf_counter() - report.t0_s)
+        with _spans.span(
+            "stream:chunk", index=self.start_chunk + report.chunks, rows=rows
+        ):
+            self.carry, probe_out = lay.step(self.carry, x_dev, y_dev, mask_dev)
+        self.chunks_c.inc()
+        report.chunks += 1
+        if report.chunks == 1:
+            report.compiles_first_chunk = self.new_traces()
+        self.dispatched += 1
+        self.rows_folded += rows
+        return probe_out
+
+    def consume(self, probe_out, _chunk):
+        # The overlap engine's completion barrier for chunk i — a
+        # one-element un-donated probe leaf, waited on so chunk timings
+        # and backpressure are real: consume() drains one item behind the
+        # dispatch frontier, so the upload of chunk i+1 (stage) is always
+        # issued before the loop blocks on chunk i — the double-buffer
+        # invariant the smoke script asserts via the event log.
+        # keystone: allow-sync
+        probe_out.block_until_ready()
+        self.report.compute_done_t.append(
+            time.perf_counter() - self.report.t0_s
+        )
 
 
 class ChunkStream:
@@ -859,12 +1088,15 @@ class ChunkStream:
     # ---------------------------------------------------------------- fold
     def fold(self, init_fn, step_fn):
         import jax
-        import numpy as np
 
+        from ..data.ingest import PrefetchQueue
         from ..parallel.linalg import _quiet_unused_donation_warnings
-
-        data, chunk_rows = self.data, self.chunk_rows
-        n = self.num_examples
+        from ..parallel.partitioner import (
+            record_collective_bytes,
+            record_imbalance,
+            reduction_collective_bytes,
+        )
+        data, chunk_rows, n = self.data, self.chunk_rows, self.num_examples
         if self.labels is None:
             raise StreamingFallback("no labels bound for a supervised fit")
         y_host = _labels_host(self.labels)
@@ -872,86 +1104,42 @@ class ChunkStream:
             raise StreamingFallback(
                 f"labels rows {y_host.shape[0]} < data rows {n}"
             )
+        if getattr(type(data), "fetch_rows", None) in (None, Dataset.fetch_rows):
+            raise StreamingFallback(f"{type(data).__name__} is not chunkable")
 
         # Shape-only pass: featurized aval without touching data.
         feat_aval = self.feature_aval()
         y_spec = jax.ShapeDtypeStruct((chunk_rows, y_host.shape[1]), y_host.dtype)
         carry = init_fn(feat_aval, y_spec)
-
-        part = self.partition
-        if part is not None and getattr(part, "model_shards", 1) > 1:
-            # The plan granted the model axis optimistically (raw-width
-            # proxy); re-validate against the REAL carry the estimator
-            # built — the step's blocked protocol, the featurized width's
-            # divisibility, and the width floor — and demote to row-only
-            # (same mesh, replicated over model) when any fail.
-            part = self._validate_model_axis(part, step_fn, carry)
+        _quiet_unused_donation_warnings()  # carries are donated each step
+        layout = self._layout(self.partition, step_fn, carry, chunk_rows)
         # A step may say something about the fold it is about to run (the
         # Gram step: the panels of its symmetric product), for a counter of
         # its own and as attributes of `stream:fold`. The model-axis block
         # step is another function and says nothing.
         note_fold = getattr(step_fn, "note_fold", None)
-        blocked = part is not None and part.model_shards > 1
-        fold_attrs = note_fold(carry) if note_fold and not blocked else {}
-        durable = self.durable
-        lease = self.lease
-        sharding = None
-        # Shard-loss recovery must be able to re-add the fold's seed when
-        # the device holding carry block 0 dies: keep the PRE-STACK device
-        # carry alive (stack() copies, nothing donates it) and fetch it to
-        # host only if that loss actually happens.
-        seed_carry_dev = carry if part is not None else None
-        attempt_seed_host = None
-
-        if part is not None:
-            from ..parallel.partitioner import NamedShardingCache
-
-            sharding = NamedShardingCache.get(part.mesh, part.mesh_axes)
-            if part.model_shards > 1:
-                carry_sharding = NamedShardingCache.get(
-                    part.mesh, part.carry_axes
-                )
-                carry = _stack_carry_2d(
-                    carry, part.shards, part.model_shards,
-                    _carry_layout(step_fn, carry), carry_sharding,
-                )
-            else:
-                carry = _stack_carry(carry, part.shards, sharding)
-
-        _quiet_unused_donation_warnings()  # carries are donated each step
-        step, traces = _shared_step_jit(
-            self.members, step_fn, part, lift=self._lift
+        fold_attrs = (
+            note_fold(carry) if note_fold and layout.model_shards == 1 else {}
         )
-        # `traces` is shared by every fold over this chain structure: this
-        # fold's compiles are what it appends from here on.
-        trace_base = len(traces)
 
-        if not hasattr(type(data), "fetch_rows") or (
-            type(data).fetch_rows is Dataset.fetch_rows
-        ):
-            raise StreamingFallback(f"{type(data).__name__} is not chunkable")
+        durable = self.durable
         windows = [
             (s, min(s + chunk_rows, n)) for s in range(0, n, chunk_rows)
         ]
-        start_chunk = (
-            min(durable.start_chunk, len(windows)) if durable is not None else 0
-        )
-
+        start_chunk = resume_rows = 0
+        if durable is not None:
+            start_chunk = min(durable.start_chunk, len(windows))
+            resume_rows = durable.resume_rows
         report = StreamReport(
-            chunk_rows=chunk_rows,
-            num_examples=n,
-            prefetch_depth=self.prefetch,
-            shards=part.shards if part is not None else 1,
-            model_shards=part.model_shards if part is not None else 1,
-            mesh_shape=tuple(part.mesh_shape) if part is not None else (),
-            # The acceptance number for 2-D layouts: bytes of streamed
-            # solver state each device actually holds — shrinks with
-            # model shards while the row-only plan replicates it.
-            state_bytes_per_device=(
-                _tree_nbytes(carry) // part.total_shards
-                if part is not None
-                else _tree_nbytes(carry)
-            ),
+            chunk_rows=chunk_rows, num_examples=n, prefetch_depth=self.prefetch
+        )
+        run = _FoldRun(self, report, y_host, start_chunk, resume_rows)
+        run.begin(layout, carry, windows[start_chunk:])
+        # The acceptance number for 2-D layouts: bytes of streamed solver
+        # state each device actually holds — shrinks with model shards
+        # while the row-only plan replicates it.
+        report.state_bytes_per_device = (
+            _tree_nbytes(run.carry) // layout.total_shards
         )
         if start_chunk:
             # Crash-resume: chunks before the cursor live in the seeded
@@ -961,215 +1149,8 @@ class ChunkStream:
             _names.metric(_names.DURABLE_REINGESTED_CHUNKS).inc(
                 report.reingested_chunks
             )
-        data_shape = _store.dataset_shape_class(data)
-        chunks_c = _names.metric(_names.STREAM_CHUNKS)
-        bytes_c = _names.metric(_names.STREAM_BYTES)
-        from ..data.ingest import PrefetchQueue
-        from ..reliability.durable import ShardLossError, shard_loss_index
-
-        def make_prepare(padded_rows):
-            def prepare(window):
-                start, stop = window
-                # fetch_rows runs inside the prefetch workers — this is
-                # the decode/stack work being overlapped with device
-                # compute.
-                x = data.fetch_rows(start, stop)
-                x = jax.tree_util.tree_map(
-                    lambda a: _pad_narrow(a, padded_rows), x
-                )
-                rows = stop - start
-                y = np.ascontiguousarray(y_host[start:stop])
-                if rows < padded_rows:  # tail chunk: pad to compiled shape
-                    y = np.concatenate(
-                        [y, np.zeros((padded_rows - rows,) + y.shape[1:], y.dtype)]
-                    )
-                # The pad-mask lane carries each row's ABSOLUTE dataset
-                # index + 1 (0 = pad). The chain only tests m > 0, so
-                # this is backward-compatible; index-keyed folds (the
-                # sketch tier) read the value itself, which stays exact
-                # in float32 up to 2^24 rows (sketch/core.py refuses
-                # longer streams).
-                mask = np.zeros((padded_rows, 1), np.float32)
-                mask[:rows, 0] = np.arange(start + 1, stop + 1, dtype=np.float32)
-                return x, y, mask, rows
-
-            return prepare
-
-        in_hand_peak = 0
-        queue_stall_s = 0.0
+        report.t0_s = time.perf_counter()
         queue_peak = 0
-        t0 = time.perf_counter()
-        report.t0_s = t0
-
-        # ---- durable/elastic bookkeeping --------------------------------
-        # rows_folded: ABSOLUTE logical rows fully dispatched (a resumed
-        # fold starts at the cursor's count) — what a committed cursor
-        # records. dispatched indexes attempt_windows (the ordered
-        # PrefetchQueue guarantees windows dispatch in source order);
-        # folded_log keeps each window's fold-time geometry so shard-loss
-        # salvage can slice exactly the lost device's rows back out.
-        rows_folded = durable.resume_rows if durable is not None else 0
-        dispatched = 0
-        last_committed = -1
-        # Recovery windows break the canonical chunk-prefix ordering a
-        # cursor describes, so after a shard loss mid-fit checkpoints
-        # suspend for the remainder of the fold (docs/RELIABILITY.md).
-        ckpt_suspended = False
-        folded_log: List[Tuple[int, int, int, int]] = []
-        attempt_windows: List[Tuple[int, int]] = windows[start_chunk:]
-        steady_accum = 0
-        attempt_base: Optional[int] = None
-
-        # The loop below IS stream_pipelined — the same engine that runs
-        # the flagship's per-bucket encode — with the carry threaded and
-        # the report timestamps recorded through the three callbacks.
-        # consume() drains one item behind the dispatch frontier, so the
-        # upload of chunk i+1 (stage) is always issued before the loop
-        # blocks on chunk i — the double-buffer invariant the smoke
-        # script asserts via the event log.
-        def stage(chunk):
-            nonlocal in_hand_peak
-            x, y, mask, rows = chunk
-            nbytes = _tree_nbytes(x) + y.nbytes + mask.nbytes
-            in_hand_peak = max(in_hand_peak, nbytes)
-            report.upload_issued_t.append(time.perf_counter() - t0)
-            # Async uploads at transfer (narrow) width; cast happens on
-            # device inside the fused step. Under a partition decision
-            # every leaf lands row-sharded over the mesh — each device
-            # receives only its slice of the chunk.
-            put = (
-                jax.device_put if sharding is None
-                else (lambda a: jax.device_put(a, sharding))
-            )
-            dev = (
-                jax.tree_util.tree_map(put, x),
-                put(y),
-                put(mask),
-                rows,
-            )
-            report.bytes_transferred += nbytes
-            bytes_c.inc(nbytes)
-            return dev
-
-        def commit_checkpoint():
-            # Commit-before-continue barrier: the carry is host-fetched
-            # (device_get blocks until the last dispatch retired) and the
-            # atomic store write completes BEFORE the next chunk's
-            # dispatch donates the buffer — a persisted carry is never
-            # stale.  # keystone: allow-sync
-            host = jax.device_get(carry)
-            if part is not None:
-                # Per-shard partials merge via the additive contract into
-                # a mesh-INDEPENDENT snapshot (rows summed, feature
-                # blocks reassembled): resume may re-plan on any mesh
-                # shape, 1-D or 2-D. Operates on the already-fetched HOST
-                # tree, never a device array.  # keystone: allow-sync
-                host = _merge_blocks(
-                    host, part.shards, part.model_shards,
-                    _carry_layout(step_fn, host)
-                    if part.model_shards > 1
-                    else None,
-                    np,
-                )
-            ok = durable.commit(
-                tuple(
-                    np.asarray(a)  # host leaves  # keystone: allow-sync
-                    for a in jax.tree_util.tree_leaves(host)
-                ),
-                chunk_index=start_chunk + dispatched,
-                rows_consumed=rows_folded,
-                chunk_rows=chunk_rows,
-                mesh_shape=tuple(part.mesh_shape) if part is not None else (),
-                shards=part.shards if part is not None else 1,
-                model_shards=part.model_shards if part is not None else 1,
-            )
-            if ok:
-                report.checkpoints += 1
-
-        def compute(staged_chunk, _chunk):
-            nonlocal carry, dispatched, rows_folded, last_committed
-            x_dev, y_dev, mask_dev, _rows = staged_chunk
-            if (
-                lease is not None
-                and dispatched > 0
-                and lease.should_yield()
-            ):
-                # Preempt-at-chunk-boundary: commit the durable cursor
-                # FIRST (the preemption contract — a deferred fold must
-                # resume from here, not restart), then unwind. The
-                # prefix carry stays valid statistics; the caller reads
-                # report.preempted_at_chunk and re-leases later.
-                if (
-                    durable is not None
-                    and not ckpt_suspended
-                    and dispatched != last_committed
-                ):
-                    last_committed = dispatched
-                    commit_checkpoint()
-                report.preempted_at_chunk = start_chunk + dispatched
-                lease.mark_preempted(start_chunk + dispatched)
-                raise FoldPreempted(start_chunk + dispatched)
-            if (
-                durable is not None
-                and durable.ckpt_every > 0
-                and not ckpt_suspended
-                and dispatched > 0
-                and dispatched % durable.ckpt_every == 0
-                and dispatched != last_committed
-            ):
-                last_committed = dispatched
-                commit_checkpoint()
-            if part is not None:
-                try:
-                    probe("parallel.shard_loss")
-                except Exception as exc:
-                    # Any injected fault at this site models the runtime
-                    # observing a device gone from the mesh before this
-                    # chunk could dispatch — the elastic recovery below
-                    # owns it.
-                    # Indexed over ALL carry blocks (row × model shards,
-                    # flat row-major) so a seeded fault can land on
-                    # either axis of a 2-D layout.
-                    raise ShardLossError(
-                        shard_loss_index(part.total_shards),
-                        start_chunk + dispatched,
-                        part.total_shards,
-                    ) from exc
-            probe("streaming.chunk")
-            if not report.chunks and _cost.current_frame() is not None:
-                # Cost-observatory note, once per fold: avals (not the
-                # arrays — the carry is donated into the step) so the
-                # per-chunk program's flop/byte facts harvest at node
-                # finalize through the jit trace cache (obs/cost.py).
-                avals = jax.tree_util.tree_map(
-                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                    (carry, x_dev, y_dev, mask_dev, step.arrays),
-                )
-                _cost.note_jit_call("stream_step", step.jitted, avals=avals)
-            report.dispatch_t.append(time.perf_counter() - t0)
-            with _spans.span(
-                "stream:chunk", index=start_chunk + report.chunks, rows=_rows
-            ):
-                carry, probe_out = step(carry, x_dev, y_dev, mask_dev)
-            chunks_c.inc()
-            report.chunks += 1
-            if report.chunks == 1:
-                report.compiles_first_chunk = len(traces) - trace_base
-            w = attempt_windows[dispatched]
-            folded_log.append(
-                (w[0], w[1], part.shards if part is not None else 1, chunk_rows)
-            )
-            dispatched += 1
-            rows_folded += _rows
-            return probe_out
-
-        def consume(probe_out, _chunk):
-            # The overlap engine's completion barrier for chunk i — a
-            # one-element un-donated probe leaf, waited on so chunk
-            # timings and backpressure are real.  # keystone: allow-sync
-            probe_out.block_until_ready()
-            report.compute_done_t.append(time.perf_counter() - t0)
-
         try:
             with _spans.span(
                 "stream:fold", chunks=len(windows), chunk_rows=chunk_rows,
@@ -1177,134 +1158,74 @@ class ChunkStream:
             ):
                 while True:
                     queue = PrefetchQueue(
-                        iter(attempt_windows),
-                        make_prepare(chunk_rows),
+                        iter(run.windows),
+                        run.prepare,
                         depth=self.prefetch,
                         workers=min(self.workers, self.prefetch),
                         size_of=lambda c: _tree_nbytes(c[0]) + c[1].nbytes,
                     )
                     try:
+                        # The attempt IS stream_pipelined — the same engine
+                        # that runs the flagship's per-bucket encode — with
+                        # the carry threaded and the report timestamps
+                        # recorded through the run's three callbacks.
                         stream_pipelined(
-                            _stalls_spanned(queue), stage=stage,
-                            compute=compute, consume=consume, prefetch=1,
+                            _stalls_spanned(queue), stage=run.stage,
+                            compute=run.compute, consume=run.consume,
+                            prefetch=1,
                         )
-                    except ShardLossError as loss:
-                        # Join this attempt's prefetch workers BEFORE
-                        # salvage (and before ANY exception leaves the
-                        # fold — the finally below covers the abort
-                        # paths): an abandoned fold must never leak
-                        # decode threads.
-                        queue.close()
-                        if report.chunks:
-                            prev_base = (
-                                trace_base + report.compiles_first_chunk
-                                if attempt_base is None
-                                else attempt_base
-                            )
-                            steady_accum += len(traces) - prev_base
-                        (
-                            part, sharding, carry, step, traces,
-                            attempt_windows, chunk_rows, attempt_seed_host,
-                        ) = self._salvage_shard_loss(
-                            loss, carry, part, step_fn, seed_carry_dev,
-                            attempt_seed_host, folded_log, attempt_windows,
-                            dispatched, chunk_rows, report,
-                        )
-                        # A loss before ANY chunk folded means the next
-                        # attempt's first chunk IS the fold's first chunk
-                        # — leave the baseline to compiles_first_chunk or
-                        # its compiles would double-count as steady-state.
-                        trace_base = len(traces)  # the new step's own list
-                        attempt_base = trace_base if report.chunks else None
-                        folded_log = []
-                        dispatched = 0
-                        ckpt_suspended = True
-                        continue
+                        break
                     except FoldPreempted:
-                        # Graceful yield: fall through to the finish
-                        # merge with the prefix carry — the cursor is
-                        # already committed, the report already marked.
-                        pass
+                        # Graceful yield: on to the finish merge with the
+                        # prefix carry — the cursor is already committed,
+                        # the report already marked.
+                        break
+                    except ShardLossError as lost:
+                        loss = lost
                     finally:
+                        # Joins this attempt's prefetch workers BEFORE
+                        # salvage, and before ANY exception leaves the
+                        # fold: an abandoned fold must never leak decode
+                        # threads.
                         queue.close()
-                        queue_stall_s += queue.stall_s
+                        report.stall_s += queue.stall_s
                         queue_peak = max(queue_peak, queue.peak_live_bytes)
-                    break
-                if part is not None:
+                        report.compiles_steady_state += run.new_traces()
+                    if durable is not None:
+                        durable.suspend()
+                    run.begin(*self._salvage_shard_loss(loss, run, step_fn))
+                lay, carry = run.layout, run.carry
+                if lay.sharded:
                     # THE cross-shard reduction of the whole fit, once at
                     # finish — O(d²) payload independent of how many
-                    # chunks streamed (docs/PARTITIONING.md): partials
-                    # SUM across the data axis; a 2-D layout then
-                    # reassembles the feature blocks across the model
-                    # axis (concat for feature leaves, sum for the
-                    # feature-free remainder). Unconditional on chunk
-                    # count: the stacked carry must ALWAYS come back to
-                    # the estimator's single-device shape (a zero-chunk
-                    # fold reduces to the seeded init carry).
-                    from ..parallel.partitioner import (
-                        record_collective_bytes,
-                        record_imbalance,
-                    )
-
-                    p_m = part.model_shards
-                    layout = (
-                        _carry_layout(step_fn, carry) if p_m > 1 else None
-                    )
+                    # chunks streamed (docs/PARTITIONING.md): the additive
+                    # contract of `_merge_blocks`, on the device.
+                    # Unconditional on chunk count: the stacked carry must
+                    # ALWAYS come back to the estimator's single-device
+                    # shape (a zero-chunk fold reduces to the seeded init
+                    # carry).
                     with _spans.span(
-                        "stream:reduce", shards=part.shards, model_shards=p_m
+                        "stream:reduce", shards=lay.shards,
+                        model_shards=lay.model_shards,
                     ):
-                        carry = _reduce_fn(part.shards, p_m, layout)(carry)
+                        carry = _reduce_fn(
+                            lay.shards, lay.model_shards, lay.carry_layout
+                        )(carry)
                     if report.chunks:
-                        # Per-axis accounting, plan-pure: with reduced
-                        # leaf bytes split into feature (B_f, sharded
-                        # over model) and remainder (B_r, replicated),
-                        # each device block holds B_f/p_m + B_r. The
-                        # data-axis sum moves one block per non-root row
-                        # shard per model column; the model-axis
-                        # reassembly moves one block per non-root model
-                        # column. At p_m = 1 the data term reduces to
-                        # the historical bytes × (shards − 1).
-                        reduced = _tree_nbytes(carry)
-                        if layout is not None:
-                            leaves = jax.tree_util.tree_leaves(carry)
-                            b_f = sum(
-                                leaf.nbytes
-                                for leaf, ax in zip(leaves, layout)
-                                if ax is not None
-                            )
-                        else:
-                            b_f = reduced
-                        b_r = reduced - b_f
-                        report.collective_bytes_data = (
-                            b_f + p_m * b_r
-                        ) * (part.shards - 1)
-                        report.collective_bytes_model = (
-                            b_f // p_m + b_r
-                        ) * (p_m - 1)
-                        report.collective_bytes = (
-                            report.collective_bytes_data
-                            + report.collective_bytes_model
+                        data_b, model_b = reduction_collective_bytes(
+                            [a.nbytes for a in jax.tree_util.tree_leaves(carry)],
+                            lay.carry_layout, lay.shards, lay.model_shards,
                         )
-                        record_collective_bytes(
-                            report.collective_bytes_data, axis="data"
-                        )
-                        record_collective_bytes(
-                            report.collective_bytes_model, axis="model"
-                        )
+                        report.collective_bytes_data = data_b
+                        report.collective_bytes_model = model_b
+                        report.collective_bytes = data_b + model_b
+                        record_collective_bytes(data_b, axis="data")
+                        record_collective_bytes(model_b, axis="model")
                         record_imbalance(
-                            "fit_stream", n, len(windows) * chunk_rows
+                            "fit_stream", n, len(windows) * lay.chunk_rows
                         )
         finally:
-            report.stall_s = queue_stall_s
-            report.host_buffer_peak_bytes = queue_peak + in_hand_peak
-            prev_base = (
-                trace_base + report.compiles_first_chunk
-                if attempt_base is None
-                else attempt_base
-            )
-            report.compiles_steady_state = (
-                steady_accum + len(traces) - prev_base
-            )
+            report.host_buffer_peak_bytes = queue_peak + run.in_hand_peak
             _publish_report(report)
 
         if durable is not None and report.preempted_at_chunk is None:
@@ -1313,45 +1234,33 @@ class ChunkStream:
             # — its cursor IS the resume point the next lease needs.
             durable.complete()
 
-        # A COMPLETED fold is a knob observation: remember what this
-        # chunk size achieved on this data shape, so MeasuredKnobRule can
-        # prefer the best recorded chunk_rows next plan (a failed fold
-        # recorded nothing — its throughput would be a lie; a resumed or
-        # shard-loss-recovered fold measured recovery, not steady state).
-        if (
-            report.chunks == len(windows)
-            and report.resumed_from_chunk is None
-            and report.preempted_at_chunk is None
-            and not report.shard_losses
-        ):
-            self._record_observation(report, data_shape)
-        if (
-            report.compute_done_t
-            and report.resumed_from_chunk is None
-            and report.preempted_at_chunk is None
-            and not report.shard_losses
-        ):
+        # A failed fold recorded nothing — its throughput would be a lie;
+        # a resumed or shard-loss-recovered fold measured recovery, and a
+        # preempted one a partial wall against full num_examples: either
+        # would inflate rows/s (the PR-15 suffix-wall guard, extended to
+        # deferrals).
+        if report.measured_steady_state and report.compute_done_t:
+            if report.chunks == len(windows):
+                # A COMPLETED fold is a knob observation: remember what
+                # this chunk size achieved on this data shape, so
+                # MeasuredKnobRule can prefer the best recorded chunk_rows
+                # next plan.
+                self._record_observation(
+                    report, _store.dataset_shape_class(data)
+                )
             # Achieved throughput to the enclosing harvest frame: a
             # rows/s-denominated prediction (the measured-knob chunk
             # winner) is drift-scored in its own unit (obs/cost.py).
-            # Resumed/recovered folds measured recovery, not steady
-            # state — feeding suffix-only walls against full-dataset
-            # rows would inflate rows/s and mis-score the drift
-            # sentinel (same guard as _record_observation). A
-            # scheduler-PREEMPTED fold is the mirror image — a partial
-            # wall against full num_examples would inflate the same way
-            # (the PR-15 suffix-wall guard extended to deferrals).
             wall = max(report.compute_done_t[-1], 1e-9)
             _cost.note_stream_result(report.num_examples / wall, n)
 
-        resume_rows = durable.resume_rows if durable is not None else 0
         info = {
             # Rows THIS fold absorbed: a resumed fold re-ingests only the
             # suffix past the cursor — the cursor's rows already live in
             # the seeding state, and estimators add state.num_examples.
             # A preempted fold absorbed only the dispatched prefix.
             "num_examples": (
-                rows_folded - resume_rows
+                run.rows_folded - resume_rows
                 if report.preempted_at_chunk is not None
                 else n - resume_rows
             ),
@@ -1359,6 +1268,37 @@ class ChunkStream:
             "report": report,
         }
         return carry, info
+
+    def _layout(self, part, step_fn, carry, chunk_rows: int) -> _FoldLayout:
+        """The layout of a fold of ``carry`` under ``part`` (an eligible
+        partition decision, or None), with the fused step bound for it."""
+        import jax
+
+        if part is not None and part.model_shards > 1:
+            # The plan granted the model axis optimistically (raw-width
+            # proxy); re-validate against the REAL carry the estimator
+            # built — the step's blocked protocol, the featurized width's
+            # divisibility, and the width floor — and demote to row-only
+            # (same mesh, replicated over model) when any fail.
+            part = self._validate_model_axis(part, step_fn, carry)
+        step, traces = _shared_step_jit(
+            self.members, step_fn, part, lift=self._lift
+        )
+        whole = (None,) * len(jax.tree_util.tree_leaves(carry))
+        if part is None:
+            return _FoldLayout(
+                None, 1, 1, (), None, None, whole, step, traces, chunk_rows
+            )
+        from ..parallel.partitioner import NamedShardingCache
+
+        blocked = part.model_shards > 1
+        return _FoldLayout(
+            part, part.shards, part.model_shards, tuple(part.mesh_shape),
+            NamedShardingCache.get(part.mesh, part.mesh_axes),
+            NamedShardingCache.get(part.mesh, part.carry_axes),
+            _carry_layout(step_fn, carry) if blocked else whole,
+            step, traces, chunk_rows,
+        )
 
     def _validate_model_axis(self, part, step_fn, carry):
         """Fold-time re-validation of an optimistically-granted model
@@ -1377,200 +1317,137 @@ class ChunkStream:
             partition_min_width_per_shard,
         )
 
-        p_m = part.model_shards
-        layout = _carry_layout(step_fn, carry)
-        reason = detail = ""
-        if layout is None or all(ax is None for ax in layout):
+        p_m, floor = part.model_shards, partition_min_width_per_shard()
+        widths = {
+            leaf.shape[ax]
+            for leaf, ax in zip(
+                jax.tree_util.tree_leaves(carry),
+                _carry_layout(step_fn, carry) or (),
+            )
+            if ax is not None
+        }
+        if not widths:
             reason = R_MODEL_INDIVISIBLE
             detail = (
                 f"step {getattr(step_fn, '__name__', type(step_fn).__name__)}"
                 " declares no blocked-carry protocol"
             )
+        elif any(w % p_m for w in widths):
+            reason = R_MODEL_INDIVISIBLE
+            detail = (
+                f"featurized width {sorted(widths)} not divisible by "
+                f"{p_m} model shards"
+            )
+        elif max(widths) < p_m * floor:
+            reason = R_BELOW_WIDTH_FLOOR
+            detail = (
+                f"featurized width {max(widths)} < {p_m} shards × "
+                f"{floor} min cols/shard"
+            )
         else:
-            leaves = jax.tree_util.tree_leaves(carry)
-            widths = {
-                leaf.shape[ax]
-                for leaf, ax in zip(leaves, layout)
-                if ax is not None
-            }
-            width = max(widths)
-            if any(w % p_m for w in widths):
-                reason = R_MODEL_INDIVISIBLE
-                detail = (
-                    f"featurized width {sorted(widths)} not divisible by "
-                    f"{p_m} model shards"
-                )
-            elif width < p_m * partition_min_width_per_shard():
-                reason = R_BELOW_WIDTH_FLOOR
-                detail = (
-                    f"featurized width {width} < {p_m} shards × "
-                    f"{partition_min_width_per_shard()} min cols/shard"
-                )
-        if not reason:
             return part
         demoted = demote_model_axis(part, reason, detail)
         return demoted if demoted.eligible else None
 
-    def _salvage_shard_loss(
-        self,
-        loss,
-        carry,
-        part,
-        step_fn,
-        seed_carry_dev,
-        attempt_seed_host,
-        folded_log,
-        attempt_windows,
-        dispatched,
-        chunk_rows,
-        report,
-    ):
-        """Absorb a mid-stream device loss and hand back the context for
-        the next fold attempt.
+    def _salvage_shard_loss(self, loss, run: "_FoldRun", step_fn):
+        """Absorb a mid-stream device loss: the layout, the (unstacked)
+        carry and the windows of the next fold attempt.
 
         The lost device's carry block is gone; everything else survives:
         the other shards' partials merge via the additive state contract
         into one host carry, and — when the dead shard was block 0, which
-        carries the fold's SEED (the estimator's init or a resume state)
-        — the host-side seed copy is added back. The rows only the lost
-        shard had folded (its row slice of every chunk dispatched this
-        attempt, per ``folded_log``'s geometry) become recovery windows,
-        re-ingested ahead of the untouched remainder. The Partitioner is
-        re-consulted on the shrunken mesh; an ineligible decision (down
-        to one device) continues single-device — elasticity is never an
-        error (docs/RELIABILITY.md "Durable fits").
+        carries the attempt's SEED (the estimator's init, a resume state
+        or an earlier salvage) — the seed is added back. The rows only the
+        lost shard had folded (its row slice of every chunk dispatched
+        this attempt) become recovery windows, re-ingested ahead of the
+        untouched remainder. The Partitioner is re-consulted on the
+        shrunken mesh; an ineligible decision (down to one device)
+        continues single-device — elasticity is never an error
+        (docs/RELIABILITY.md "Durable fits").
         """
         import jax
         import numpy as np
 
         from ..parallel.mesh import mesh_without
-        from ..parallel.partitioner import (
-            NamedShardingCache,
-            Partitioner,
-            record_decision,
-        )
+        from ..parallel.partitioner import Partitioner, record_decision
         from ..reliability.recovery import get_recovery_log
 
+        lay, report = run.layout, run.report
         label = f"fit_stream[{len(self.members)}ops]"
-        lost, old_rows, p_m = loss.lost_shard, part.shards, part.model_shards
         # The flat block index is row-major over (data, model): a loss on
         # EITHER axis maps to one data row-group, and the whole group is
         # dropped — with feature-sharded blocks no single column holds a
         # complete partial, so group-mates of a lost device contribute
         # nothing usable on their own. Their rows are re-ingested below.
-        lost_row = lost // p_m
+        lost_row = loss.lost_shard // lay.model_shards
         get_recovery_log().record(
             "shard_loss",
             label,
-            lost_shard=lost,
-            shards=part.total_shards,
+            lost_shard=loss.lost_shard,
+            shards=lay.total_shards,
             chunk_index=loss.chunk_index,
         )
         _names.metric(_names.DURABLE_SHARD_LOSSES).inc()
         report.shard_losses += 1
 
         # Surviving per-shard partials, merged once on host (O(d²) — the
-        # same additive algebra the finish-time reduce runs): sum the
-        # surviving data row-groups, then reassemble feature blocks
-        # across the model axis.
+        # same additive algebra the finish-time reduce runs).
         # keystone: allow-sync
-        host_blocks = jax.device_get(carry)
-        layout = _carry_layout(step_fn, host_blocks) if p_m > 1 else None
-        leaves, treedef = jax.tree_util.tree_flatten(host_blocks)
-        if layout is None:
-            layout = (None,) * len(leaves)
-
-        def merge(a, ax):
-            # Already device_get above — host data.  # keystone: allow-sync
-            a = np.asarray(a)
-            a = a.reshape((old_rows, p_m) + a.shape[1:])
-            keep = [i for i in range(old_rows) if i != lost_row]
-            summed = (
-                a[keep].sum(axis=0) if keep else np.zeros_like(a[0])
-            )  # (p_m, …)
-            if ax is None:
-                return summed.sum(axis=0)
-            if p_m == 1:
-                return summed[0]
-            return np.concatenate([summed[j] for j in range(p_m)], axis=ax)
-
-        surviving = jax.tree_util.tree_unflatten(
-            treedef, [merge(a, ax) for a, ax in zip(leaves, layout)]
+        surviving = _merge_blocks(
+            jax.device_get(run.carry), lay.shards, lay.model_shards,
+            lay.carry_layout, np, drop_row=lost_row,
         )
         if lost_row == 0:
-            # Data row-group 0 carried the fold's seed (spread over its
+            # Data row-group 0 carried the attempt's seed (spread over its
             # feature blocks in a 2-D layout) and the whole group was
-            # dropped; the seed survives on the host.
-            if attempt_seed_host is None:
-                # keystone: allow-sync
-                attempt_seed_host = jax.device_get(seed_carry_dev)
+            # dropped; the seed itself was kept.  # keystone: allow-sync
             surviving = jax.tree_util.tree_map(
                 lambda s, a: np.asarray(s) + np.asarray(a),
                 surviving,
-                attempt_seed_host,
+                jax.device_get(run.seed),
             )
 
         # Rows only the lost row-group had absorbed: group i held padded
         # rows [i·rps, (i+1)·rps) of each chunk, so the lost LOGICAL rows
         # of a window (s, e) are the contiguous
-        # [s+lost_row·rps, min(s+(lost_row+1)·rps, e)).
+        # [s+lost_row·rps, min(s+(lost_row+1)·rps, e)). The ordered
+        # PrefetchQueue dispatched the attempt's windows in source order.
+        rps = lay.chunk_rows // lay.shards
         recovery: List[Tuple[int, int]] = []
-        for (s, e, shards_f, cr_f) in folded_log:
-            rps = cr_f // shards_f
+        for s, e in run.windows[:run.dispatched]:
             lo = s + lost_row * rps
             hi = min(s + (lost_row + 1) * rps, e)
             if lo < hi:
                 recovery.append((lo, hi))
-        remaining = list(attempt_windows[dispatched:])
+        remaining = list(run.windows[run.dispatched:])
 
-        decision = Partitioner(mesh=mesh_without(part.mesh, lost)).decide_stream(
-            label, chunk_rows, rows=self.num_examples, record=False
+        decision = Partitioner(
+            mesh=mesh_without(lay.part.mesh, loss.lost_shard)
+        ).decide_stream(
+            label, lay.chunk_rows, rows=self.num_examples, record=False
         )
         # Metrics yes, plan report no: the report is documented as "the
         # last PLAN's decisions" and a mid-fold re-decision is runtime.
         record_decision(decision, to_report=False)
-
+        chunk_rows = lay.chunk_rows
         if decision.eligible:
-            new_part = decision
-            new_chunk_rows = decision.chunk_rows or chunk_rows
-            sharding = NamedShardingCache.get(new_part.mesh, new_part.mesh_axes)
-            carry = _stack_carry(surviving, new_part.shards, sharding)
-        else:
-            import jax.numpy as jnp
+            chunk_rows = decision.chunk_rows or chunk_rows
+        layout = self._layout(
+            decision if decision.eligible else None, step_fn, surviving,
+            chunk_rows,
+        )
 
-            new_part, sharding = None, None
-            new_chunk_rows = chunk_rows
-            carry = jax.tree_util.tree_map(jnp.asarray, surviving)
-        step, traces = _shared_step_jit(
-            self.members, step_fn, new_part, lift=self._lift
-        )
-        report.shards = new_part.shards if new_part is not None else 1
-        report.model_shards = (
-            new_part.model_shards if new_part is not None else 1
-        )
-        report.mesh_shape = (
-            tuple(new_part.mesh_shape) if new_part is not None else ()
-        )
         report.reingested_chunks += len(recovery)
         _names.metric(_names.DURABLE_REINGESTED_CHUNKS).inc(len(recovery))
         _names.metric(_names.DURABLE_RESUMES).inc(kind="shard")
         get_recovery_log().record(
             "shard_resume",
             label,
-            shards=report.shards,
+            shards=layout.shards,
             recovery_chunks=len(recovery),
             remaining_chunks=len(remaining),
         )
-        return (
-            new_part,
-            sharding,
-            carry,
-            step,
-            traces,
-            recovery + remaining,
-            new_chunk_rows,
-            surviving,
-        )
+        return layout, surviving, recovery + remaining
 
     def _record_observation(self, report: StreamReport, data_shape: str) -> None:
         store = _store.get_store()

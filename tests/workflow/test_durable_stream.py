@@ -87,6 +87,105 @@ def _crash_at(store_dir, call, spec_kind="transient"):
             build().fit()
 
 
+# ---------------------------------------------------- the chunk boundary
+
+
+class _FakeStore:
+    def __init__(self, events):
+        self.events = events
+
+    def save(self, node, entry, digest):
+        self.events.append(("commit", entry.cursor.chunk_index))
+        return True
+
+
+class _FakeLease:
+    """Yields at the boundaries named; every consultation is recorded."""
+
+    def __init__(self, events, yield_at):
+        self.events, self.yield_at, self.at = events, yield_at, None
+
+    def should_yield(self):
+        self.events.append(("asked", self.at))
+        return self.at in self.yield_at
+
+    def mark_preempted(self, chunk_index):
+        self.events.append(("marked", chunk_index))
+
+
+@pytest.mark.parametrize(
+    "every,boundaries,suspend_before,yield_at,want",
+    [
+        # every 2 chunks, never before the first chunk
+        (2, [0, 1, 2, 3, 4, 5, 6], None, None,
+         [("commit", 12), ("commit", 14), ("commit", 16)]),
+        # a boundary asked twice commits once
+        (1, [0, 1, 1, 2, 2], None, None, [("commit", 11), ("commit", 12)]),
+        # none after a shard loss suspended them, the restart at 0 included
+        (1, [0, 1, 2, 0, 1, 2], 3, None, [("commit", 11), ("commit", 12)]),
+        # checkpoints off: nothing, and no snapshot is built
+        (0, [0, 1, 2, 3], None, None, []),
+        # a yield commits whatever the cadence, BEFORE the lease is marked;
+        # the lease is not asked before the first chunk
+        (0, [0, 1, 2, 3], None, {0, 2},
+         [("asked", 1), ("asked", 2), ("commit", 12), ("marked", 12), ("raised", 12)]),
+        # a yield on a cadence boundary commits once
+        (2, [0, 1, 2], None, {2},
+         [("asked", 1), ("asked", 2), ("commit", 12), ("marked", 12), ("raised", 12)]),
+        # a suspended plan still yields, without a cursor
+        (1, [0, 1], 0, {1}, [("asked", 1), ("marked", 11), ("raised", 11)]),
+        # a lease and no plan: the chunk index is the attempt's own
+        (None, [0, 1], None, {1}, [("asked", 1), ("marked", 1), ("raised", 1)]),
+    ],
+)
+def test_chunk_boundary_decision_table(every, boundaries, suspend_before, yield_at, want):
+    """The one chunk-boundary call, on a fake store and a fake lease: who
+    commits when (`DurableFold.at_boundary`), and the order commit, mark,
+    raise (`streaming._chunk_boundary`)."""
+    from keystone_tpu.reliability.durable import DurableFold
+    from keystone_tpu.workflow.streaming import (
+        FoldPreempted,
+        StreamReport,
+        _chunk_boundary,
+    )
+
+    events, snapshots = [], []
+    durable = None
+    if every is not None:
+        durable = DurableFold(
+            store=_FakeStore(events), key="k" * 40, kind="gram", estimator="e",
+            ckpt_every=every, start_chunk=10,
+            fingerprints=dict(
+                dataset_digest="x", labels_digest="y", chain_digest="c",
+                feature_width=D, feature_dtype="float32",
+            ),
+        )
+    lease = _FakeLease(events, yield_at) if yield_at is not None else None
+    report = StreamReport()
+
+    def snapshot():
+        snapshots.append(1)
+        return dict(
+            host_carry=(np.zeros(1, np.float32),), rows_consumed=0, chunk_rows=CHUNK
+        )
+
+    for step, dispatched in enumerate(boundaries):
+        if step == suspend_before:
+            durable.suspend()
+        if lease is not None:
+            lease.at = dispatched
+        try:
+            _chunk_boundary(durable, lease, dispatched, snapshot, report)
+        except FoldPreempted as preempted:
+            events.append(("raised", preempted.chunk_index))
+            break
+    assert events == want
+    commits = [e for e in events if e[0] == "commit"]
+    assert report.checkpoints == len(snapshots) == len(commits)
+    raised = [at for kind, at in events if kind == "raised"]
+    assert report.preempted_at_chunk == (raised[0] if raised else None)
+
+
 # ----------------------------------------------------------- checkpoints
 
 

@@ -157,6 +157,93 @@ def test_every_reason_key_reaches_the_docs_matrix():
     assert len(ALL_REASON_KEYS) == len(set(ALL_REASON_KEYS))
 
 
+# ------------------------------------------- the carry's additive contract
+
+
+@pytest.mark.parametrize("np_mod", ["numpy", "jax.numpy"])
+@pytest.mark.parametrize(
+    "row_shards,model_shards,drop_row",
+    [(4, 1, None), (4, 1, 3), (4, 1, 0), (2, 4, None), (2, 4, 1), (4, 2, 0), (1, 8, 0)],
+)
+def test_merge_blocks_is_the_sum_of_the_kept_row_groups(
+    np_mod, row_shards, model_shards, drop_row
+):
+    """`_merge_blocks` against the merge done by hand, block by block: the
+    kept row groups summed, feature leaves reassembled along their axis,
+    the feature-free leaf summed over every block; a 1-D layout keeps all
+    leaves whole. Dropping the only row group leaves zeros."""
+    import importlib
+
+    from keystone_tpu.workflow.streaming import _merge_blocks
+
+    blocked = model_shards > 1
+    layout = (1, 0, None) if blocked else (None, None, None)
+    whole = [(D, D), (D, K), (K,)]
+    r = np.random.default_rng(row_shards * 10 + model_shards)
+
+    def block_shape(shape, ax):
+        if ax is None:
+            return shape
+        return shape[:ax] + (shape[ax] // model_shards,) + shape[ax + 1:]
+
+    # blocks[leaf][i][j]: row group i, model column j (flat index i·p_m + j)
+    blocks = [
+        [
+            [r.normal(size=block_shape(shape, ax)).astype(np.float32)
+             for _ in range(model_shards)]
+            for _ in range(row_shards)
+        ]
+        for shape, ax in zip(whole, layout)
+    ]
+    stacked = tuple(
+        np.stack([b for group in leaf for b in group]) for leaf in blocks
+    )
+    kept = [i for i in range(row_shards) if i != drop_row]
+    by_hand = []
+    for leaf, shape, ax in zip(blocks, whole, layout):
+        columns = [
+            sum((leaf[i][j] for i in kept), np.zeros(block_shape(shape, ax), np.float32))
+            for j in range(model_shards)
+        ]
+        by_hand.append(
+            sum(columns) if ax is None else np.concatenate(columns, axis=ax)
+        )
+    merged = _merge_blocks(
+        stacked, row_shards, model_shards, layout,
+        importlib.import_module(np_mod), drop_row=drop_row,
+    )
+    for got, want, shape in zip(merged, by_hand, whole):
+        assert got.shape == shape
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
+    if not kept:
+        assert all(not np.asarray(got).any() for got in merged)
+
+
+@pytest.mark.parametrize("shards,model_shards", [(8, 1), (2, 1), (2, 4), (4, 2), (1, 8)])
+def test_reduction_collective_bytes_is_a_function_of_the_plan(shards, model_shards):
+    """The numbers the fold stores on its report, from the plan alone:
+    `bytes × (shards − 1)` on the data axis of a row-only layout (what
+    tests/workflow/test_partition.py expects of the report), the per-axis
+    split of the 2-D cases below."""
+    from keystone_tpu.parallel import linalg
+    from keystone_tpu.parallel.partitioner import reduction_collective_bytes
+
+    carry = linalg.gram_stream_init(D, K)
+    nbytes = [a.nbytes for a in carry]
+    b_f, b_r = 4 * (D * D + D * K + D), 4 * K
+    assert sum(nbytes) == b_f + b_r
+    if model_shards == 1:
+        layout = (None,) * len(carry)  # a row-only fold keeps every leaf whole
+        want = ((b_f + b_r) * (shards - 1), 0)
+    else:
+        layout = linalg.gram_stream_step.model_layout
+        want = (
+            (b_f + model_shards * b_r) * (shards - 1),
+            (b_f // model_shards + b_r) * (model_shards - 1),
+        )
+    assert reduction_collective_bytes(nbytes, layout, shards, model_shards) == want
+
+
 # ----------------------------------------------------- streamed execution
 
 
