@@ -11,6 +11,8 @@ RSS for HBM.
 ``to_device`` is the one place a host batch becomes device arrays on
 the batch-apply path: an ``h2d`` span (in a profiler trace through the
 span layer's bridge, obs/spans.py) and the ``keystone_h2d_*`` counters.
+Its callers are ``BatchTransformer.apply_batch`` (a transformer's own
+input) and the graph executor (a node's output shared by several).
 
 Imports jax lazily; importable before any backend initializes.
 """
@@ -25,10 +27,14 @@ from . import names, spans
 
 _PAGE_SIZE = os.sysconf("SC_PAGE_SIZE") if hasattr(os, "sysconf") else 4096
 
-def to_device(data: Any, site: str) -> Any:
+def to_device(data: Any, site: str, consumers: int = 1) -> Any:
     """``data`` (a pytree) with its host (numpy) leaves uploaded as device
-    arrays; device leaves and everything else pass through untouched.
-    The upload runs under an ``h2d`` span carrying ``bytes`` and is
+    arrays; device leaves and everything else pass through untouched
+    (with no host leaf, ``data`` itself comes back). The upload is
+    enqueued, never waited for, under an ``h2d`` span carrying ``site``,
+    ``bytes`` and ``consumers`` (how many batch transformers will compute
+    on this one copy: 1 where a transformer uploads its own input, k
+    where the executor shares a node's output between k of them), and is
     counted in ``keystone_h2d_bytes_total{site}`` /
     ``keystone_h2d_transfers_total{site}`` (one transfer per leaf)."""
     import numpy as np
@@ -44,7 +50,7 @@ def to_device(data: Any, site: str) -> Any:
     import jax.numpy as jnp
 
     nbytes = sum(leaf.nbytes for leaf in host)
-    with spans.span("h2d", site=site, bytes=nbytes):
+    with spans.span("h2d", site=site, bytes=nbytes, consumers=consumers):
         out = jax.tree_util.tree_map(
             lambda leaf: jnp.asarray(leaf) if isinstance(leaf, np.ndarray) else leaf,
             data,

@@ -45,6 +45,7 @@ STREAM_HOST_BUFFER_PEAK = "keystone_stream_host_buffer_peak_bytes"
 # ------------------------------------------------------------ host-to-device
 H2D_BYTES = "keystone_h2d_bytes_total"
 H2D_TRANSFERS = "keystone_h2d_transfers_total"
+H2D_REUSES = "keystone_h2d_reuses_total"
 
 # ---------------------------------------------------------------- partitioning
 PARTITION_DECISIONS = "keystone_partition_decisions_total"
@@ -231,8 +232,9 @@ SCHEMA: Dict[str, Tuple] = {
     STREAM_STALL_SECONDS: ("counter", "Seconds the streaming dispatch loop spent waiting on the host prefetch pipeline", ()),
     STREAM_PREFETCH_DEPTH: ("gauge", "Chunks currently buffered in the host prefetch queue", ()),
     STREAM_HOST_BUFFER_PEAK: ("gauge", "Peak bytes of host chunk buffers concurrently live in the last streaming fit", ()),
-    H2D_BYTES: ("counter", "Bytes of host batches uploaded by the batch-apply path (obs.device.to_device), by the transformer class that uploaded them", ("site",)),
+    H2D_BYTES: ("counter", "Bytes of host batches uploaded by the batch-apply path (obs.device.to_device), by the class that made the upload: the consuming transformer, or for an upload shared by several consumers the operator whose output was uploaded", ("site",)),
     H2D_TRANSFERS: ("counter", "Host arrays uploaded by the batch-apply path, one per leaf, beside keystone_h2d_bytes_total", ("site",)),
+    H2D_REUSES: ("counter", "Batch transformers handed device arrays that were uploaded for another consumer of the same node (k - 1 per upload shared by k), by the site of that upload", ("site",)),
     PARTITION_DECISIONS: ("counter", "Partitioner decisions recorded into plans, split by kind and eligibility", ("kind", "eligible")),
     PARTITION_SHARDS: ("gauge", "Shards chosen by the last eligible partition decision, per kind and mesh axis (data = rows, model = feature blocks)", ("kind", "axis")),
     PARTITION_FALLBACKS: ("counter", "Partition decisions that fell back (whole decision or just the model axis), by reason key", ("reason",)),

@@ -17,14 +17,16 @@ consults per node — see keystone_tpu/reliability/ and docs/RELIABILITY.md.
 from __future__ import annotations
 
 import threading
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
+from ..data.dataset import ArrayDataset, Dataset
 from ..obs import names as _names
 from ..obs import spans as _spans
+from ..obs.device import to_device
 from ..reliability import faultinject
 from ..reliability.recovery import reset_recovery_log
 from .graph import Graph, GraphId, NodeId, SinkId, SourceId
-from .operators import EstimatorOperator, Expression
+from .operators import DatasetExpression, EstimatorOperator, Expression
 from .prefix import Prefix, find_prefix
 from .tracing import timed_execute
 
@@ -45,6 +47,72 @@ def _is_cacher(op) -> bool:
     from ..ops.util.misc import CacherOperator
 
     return isinstance(op, CacherOperator)
+
+
+def _uploads_its_input(op) -> bool:
+    """Whether ``op`` applies a batch through ``BatchTransformer.apply_batch``
+    as it stands: the wrapper whose first act on a host batch is to upload
+    it whole. A subclass with an ``apply_batch`` of its own may want the
+    host's copy (native extractors, patchers), and so does every operator
+    that is no ``BatchTransformer``."""
+    from .pipeline import BatchTransformer
+
+    return (
+        isinstance(op, BatchTransformer)
+        and type(op).apply_batch is BatchTransformer.apply_batch
+    )
+
+
+class _SharedUpload:
+    """One node's host-resident output, uploaded once for the
+    ``consumers`` batch transformers that read it directly.
+
+    Left alone, each of them uploads the whole batch for itself
+    (``BatchTransformer.apply_batch``): the same rows k times over the
+    bus, on the blocking path of a request. Here the first one forced
+    makes the upload (``to_device``: enqueued, not waited for) under the
+    producing operator's class as ``site``, and every one is handed the
+    same device arrays, so its own ``to_device`` finds nothing to do.
+    What such a consumer would not upload whole passes through as it
+    was produced: arrays already on a device, the masked-descriptor
+    dictionary (``desc`` alone goes up, in the consumer), a
+    ``BucketedDataset``, an ``ObjectDataset``.
+
+    The copy belongs to one executor and so to one execution, and is let
+    go of when the last consumer has it. Nothing is kept on the dataset,
+    on the numpy array or across calls: the next execution uploads
+    again. The executor's pull forces consumers one after another, so
+    there is no lock."""
+
+    def __init__(self, produced: Expression, site: str, consumers: int):
+        self._produced = produced
+        self._site = site
+        self._consumers = consumers
+        self._handed = 0
+        self._device: Optional[ArrayDataset] = None
+
+    def hand(self) -> Dataset:
+        """The dataset one consumer computes on (each calls this once)."""
+        dataset = self._produced.get()
+        self._handed += 1
+        if self._handed == 1:
+            self._device = self._upload(dataset)
+        elif self._device is not None:
+            _names.metric(_names.H2D_REUSES).inc(site=self._site)
+        device = self._device
+        if self._handed >= self._consumers:
+            self._device = None
+        return dataset if device is None else device
+
+    def _upload(self, dataset: Dataset) -> Optional[ArrayDataset]:
+        from .pipeline import is_masked_descriptors
+
+        if not isinstance(dataset, ArrayDataset) or is_masked_descriptors(dataset.data):
+            return None
+        data = to_device(dataset.data, site=self._site, consumers=self._consumers)
+        if data is dataset.data:  # no host leaf: nothing was uploaded
+            return None
+        return ArrayDataset(data, dataset.num_examples)
 
 
 class PipelineEnv:
@@ -103,6 +171,11 @@ class GraphExecutor:
         self._prefixes: Dict[NodeId, Prefix] = {}
         self._memo: Dict[GraphId, Expression] = {}
         self._counters = None  # resolved lazily, once per executor
+        # Host outputs uploaded once for several batch transformers, and
+        # the reverse edges of the graph they were counted on.
+        self._uploads: Dict[NodeId, _SharedUpload] = {}
+        self._dependents: Optional[Dict[NodeId, List[GraphId]]] = None
+        self._dependents_of: Optional[Graph] = None
         #: Partition decisions the planner recorded for THIS plan
         #: (parallel/partitioner.py), captured at optimize time — a
         #: stable per-executor snapshot for programmatic consumers that
@@ -166,8 +239,8 @@ class GraphExecutor:
             self._memo[graph_id] = result
             return result
 
-        deps = [self.execute(d) for d in graph.get_dependencies(graph_id)]
         op = graph.get_operator(graph_id)
+        deps = [self._input(op, d) for d in graph.get_dependencies(graph_id)]
         nodes_c.inc()
         if _is_cacher(op):
             cache_miss_c.inc()
@@ -183,6 +256,34 @@ class GraphExecutor:
 
         self._memo[graph_id] = expression
         return expression
+
+    def _input(self, op, dep: GraphId) -> Expression:
+        """``dep``'s result as ``op`` reads it: the memoized expression,
+        or, for a batch transformer that is one of several on ``dep``, a
+        hand-out of their shared upload (:class:`_SharedUpload`). Decided
+        from the graph this executor runs, the optimized or fused one,
+        and from nothing else."""
+        produced = self.execute(dep)
+        if not (
+            isinstance(dep, NodeId)
+            and isinstance(produced, DatasetExpression)
+            and _uploads_its_input(op)
+        ):
+            return produced
+        shared = self._uploads.get(dep)
+        if shared is None:
+            graph = self.graph
+            if self._dependents_of is not graph:  # Pipeline.fit splices as it goes
+                self._dependents, self._dependents_of = graph.dependents(), graph
+            consumers = sum(
+                isinstance(c, NodeId) and _uploads_its_input(graph.get_operator(c))
+                for c in set(self._dependents[dep])
+            )
+            if consumers < 2:
+                return produced
+            site = type(graph.get_operator(dep)).__name__
+            shared = self._uploads[dep] = _SharedUpload(produced, site, consumers)
+        return DatasetExpression(shared.hand)
 
 
 def _wrap_reliability(

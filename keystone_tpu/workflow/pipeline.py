@@ -193,6 +193,13 @@ def feat_scope(op: Any):
     return jax.named_scope("feat/" + type(op).__name__)
 
 
+def is_masked_descriptors(data: Any) -> bool:
+    """The masked descriptor convention ({"desc": (N, n_pad, d), "valid":
+    (N, n_pad)} from ops.images.native): batch transformers act on the
+    descriptors and validity flows through untouched."""
+    return isinstance(data, dict) and "desc" in data and "valid" in data
+
+
 class BatchTransformer(Transformer):
     """Transformer whose native form is whole-batch array computation.
 
@@ -260,16 +267,9 @@ class BatchTransformer(Transformer):
         if isinstance(dataset, ObjectDataset):
             dataset = dataset.to_arrays()
         assert isinstance(dataset, ArrayDataset)
-        if (
-            isinstance(dataset.data, dict)
-            and "desc" in dataset.data
-            and "valid" in dataset.data
-        ):
-            # Masked descriptor convention ({"desc": (N, n_pad, d),
-            # "valid": (N, n_pad)} from ops.images.native): the op acts on
-            # the descriptors, validity flows through untouched. Safe for
-            # the chain between extractor and FisherVector (elementwise
-            # maps and PCA matmuls keep zero rows zero).
+        if is_masked_descriptors(dataset.data):
+            # Safe for the chain between extractor and FisherVector
+            # (elementwise maps and PCA matmuls keep zero rows zero).
             desc = to_device(dataset.data["desc"], site=type(self).__name__)
             with feat_scope(self):
                 out = self.apply_arrays(desc)
@@ -278,9 +278,10 @@ class BatchTransformer(Transformer):
                 dataset.num_examples,
             )
         # The upload of a host-resident batch, made explicit where the
-        # first jnp operation of `apply_arrays` used to make it: once per
-        # application, so a host input that feeds k branches is uploaded k
-        # times (counted by keystone_h2d_*; sharing it is a perf change).
+        # first jnp operation of `apply_arrays` used to make it. Where
+        # this transformer is one of several on the same node's output,
+        # the executor has uploaded it once for all of them
+        # (executor._SharedUpload) and there is no host leaf left here.
         data = to_device(dataset.data, site=type(self).__name__)
         with feat_scope(self):
             out = ArrayDataset(self.apply_arrays(data), dataset.num_examples)

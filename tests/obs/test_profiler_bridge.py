@@ -133,7 +133,14 @@ def test_phases_nest_as_documented(traced):
     assert within(one("ks:optimize:rules"), one("ks:fit:plan"))
     assert within(one("ks:solver:fit"), one("ks:node:BlockLeastSquaresEstimator"))
     assert within(one("ks:solver:bcd"), one("ks:solver:fit"))
-    assert within(one("ks:h2d"), one("ks:node:CosineRandomFeatures"))
+    # x feeds both cosine branches and goes up once, when the first of
+    # them forces its input: ahead of that node's span and inside no
+    # node's; the labels' upload is their one consumer's own, inside it
+    nodes = [e for e in events if e[0].startswith("ks:node:")]
+    shared, own = [e for e in events if e[0] == "ks:h2d"]
+    assert shared[2] <= one("ks:node:CosineRandomFeatures")[1]
+    assert not any(within(shared, node) for node in nodes)
+    assert any(within(own, node) for node in nodes)
     # top-level phases are siblings: nothing spans the whole fit
     assert not any(within(one("ks:fit:plan"), e) for e in events if e[0] != "ks:fit:plan"
                    and not e[0].startswith("ks:optimize"))
@@ -252,24 +259,29 @@ def test_with_a_session_the_profiler_sees_each_node_once(tmp_path):
     ])
 
 
-def test_uploads_are_counted_once_per_branch():
-    """One host input, two cosine branches: two uploads today (sharing
-    one is a performance change, and this count is how it will show)."""
+def test_one_host_input_of_two_branches_is_uploaded_once():
+    """One host input, two cosine branches: one upload, counted under the
+    operator whose output it was, and one branch handed the other's copy
+    (two uploads under `CosineRandomFeatures` until PR 33)."""
     pipeline, x = _pipeline(3)
     fitted = pipeline.fit()
-    registry = metrics.get_registry()
 
-    def counted(name):
-        metric = registry.get(name)
-        return metric.value(site="CosineRandomFeatures") if metric else 0.0
+    def counted(site):
+        return tuple(
+            names.metric(name).value(site=site)
+            for name in (names.H2D_BYTES, names.H2D_TRANSFERS, names.H2D_REUSES)
+        )
 
-    bytes0, transfers0 = counted(names.H2D_BYTES), counted(names.H2D_TRANSFERS)
+    def gained(site, since):
+        return tuple(now - was for now, was in zip(counted(site), since))
+
+    source0, cosine0 = counted("DatasetOperator"), counted("CosineRandomFeatures")
     fitted.apply_batch(ArrayDataset(x))
-    assert counted(names.H2D_TRANSFERS) - transfers0 == 2
-    assert counted(names.H2D_BYTES) - bytes0 == 2 * x.nbytes
+    assert gained("DatasetOperator", source0) == (x.nbytes, 1, 1)
+    assert gained("CosineRandomFeatures", cosine0) == (0, 0, 0)
     device_input = ArrayDataset(jnp.asarray(x))
     fitted.apply_batch(device_input)  # already on the device: nothing to upload
-    assert counted(names.H2D_TRANSFERS) - transfers0 == 2
+    assert gained("DatasetOperator", source0) == (x.nbytes, 1, 1)
 
 
 @pytest.mark.parametrize("num_iter,mode", [(5, "reused"), (1, "single_pass")])
