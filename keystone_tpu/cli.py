@@ -287,6 +287,10 @@ WORKLOADS: Dict[str, Tuple[str, str, str, Dict[str, Any], str]] = {
         "timit", "TimitConfig", "run", {},
         "TIMIT cosine random features + block solve",
     ),
+    "timit-kernel": (
+        "timit", "TimitConfig", "run", {"solver": "kernel"},
+        "TIMIT exact Gaussian kernel ridge regression (dual block Gauss-Seidel)",
+    ),
     "voc-sift-fisher": (
         "voc", "SIFTFisherConfig", "run", {},
         "VOC 2007 SIFT + Fisher Vector + block least squares",

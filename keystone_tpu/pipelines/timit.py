@@ -18,13 +18,22 @@ stacked into ONE ``CosineRandomFeatures``: a single chain, which the
 streaming plan rule absorbs, so the fit folds row chunks into a Gram
 carry on each device of the data mesh and the features are never held
 (docs/PARTITIONING.md "Fitting TIMIT beyond one chip's memory").
+
+A third form, ``solver="kernel"`` (``keystone-tpu timit-kernel``): no
+featurizer at all. The cosine branches with W = gamma N(0, 1) are the
+random-feature estimate of k(x, y) = exp(-gamma^2 |x - y|^2 / 2); the
+kernel form fits that kernel itself, on the raw frames, by kernel ridge
+regression (dual block Gauss-Seidel, ``ops/learning/kernel.py``; Tu et
+al., arXiv:1602.05310, whose TIMIT experiment compares the two). The
+n x n kernel is never held: the live object is one n x block panel.
 """
 
 from __future__ import annotations
 
 import logging
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
+from typing import Optional
 
 import numpy as np
 
@@ -34,6 +43,7 @@ from ..data.loaders.timit import NUM_CLASSES, TIMIT_DIMENSION, load_timit
 from ..evaluation.multiclass import MulticlassClassifierEvaluator
 from ..obs import spans
 from ..ops.learning.block import BlockLeastSquaresEstimator
+from ..ops.learning.kernel import GaussianKernelGenerator, KernelRidgeRegression
 from ..ops.stats.core import CosineRandomFeatures
 from ..ops.util.labels import ClassLabelIndicators, MaxClassifier
 from ..ops.util.vectors import VectorCombiner
@@ -58,6 +68,23 @@ class TimitConfig:
     num_epochs: int = 5
     num_cosine_features: int = NUM_COSINE_FEATURES
     seed: int = 123
+    # "block": cosine random features into the primal block solver.
+    # "kernel": the exact Gaussian kernel on the raw frames, by kernel
+    # ridge regression (`reg` is its lambda, as K_bb + lambda I; `seed`
+    # permutes its blocks in every epoch).
+    solver: str = "block"
+    kernel_gamma: Optional[float] = None  # exp(-g |x - y|^2); None: gamma ** 2 / 2, the kernel the features estimate
+    kernel_block_size: int = 4096
+    kernel_num_epochs: int = 1
+
+
+def kernel_gamma(config: TimitConfig) -> float:
+    """The Gaussian kernel generator's parameter for the kernel form: as
+    given, or the kernel that the cosine features of ``config.gamma``
+    estimate (W = gamma N(0, 1) gives exp(-gamma^2 |x - y|^2 / 2))."""
+    if config.kernel_gamma is not None:
+        return config.kernel_gamma
+    return config.gamma ** 2 / 2.0
 
 
 def build_featurizer(config: TimitConfig, input_dim: int = TIMIT_DIMENSION) -> Pipeline:
@@ -119,6 +146,16 @@ def build_pipeline(config: TimitConfig, train: LabeledData, input_dim: int = TIM
     # device idle: the random features are drawn on the host, in numpy.
     with spans.span("build:pipeline"):
         labels = ClassLabelIndicators(NUM_CLASSES)(train.labels)
+        if config.solver == "kernel":
+            return KernelRidgeRegression(
+                GaussianKernelGenerator(kernel_gamma(config)),
+                config.reg,
+                config.kernel_block_size,
+                config.kernel_num_epochs,
+                block_permuter=config.seed,
+            ).with_data(train.data, labels) >> MaxClassifier()
+        if config.solver != "block":
+            raise ValueError(f"unknown solver {config.solver!r}")
         width = config.num_cosines * config.num_cosine_features
         if features_fit_in_core(len(train.data), width):
             featurizer = build_featurizer(config, input_dim)
@@ -133,7 +170,11 @@ def build_pipeline(config: TimitConfig, train: LabeledData, input_dim: int = TIM
         ) >> MaxClassifier()
 
 
-def run(config: TimitConfig) -> dict:
+def run(config: TimitConfig, solver: Optional[str] = None) -> dict:
+    """Fit and evaluate; ``solver`` (the CLI's ``timit-kernel`` variant)
+    overrides the configuration's."""
+    if solver is not None:
+        config = replace(config, solver=solver)
     start = time.time()
     if config.train_data_location:
         data = load_timit(

@@ -41,7 +41,6 @@ plane can import this without paying a backend import.
 from __future__ import annotations
 
 import hashlib
-import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
 
@@ -205,43 +204,32 @@ def load_stream_state(store: Any, name: str) -> Optional[StreamState]:
 
 
 class _HostFetch:
-    """Device arrays on their way to the host, on a thread of their own.
-    The thread lets the device buffers go as soon as the copy is made, so
-    an estimator that outlives its fit (the prefix table keeps it) holds
-    host memory only. Not a daemon: a process that exits waits for it."""
+    """Device arrays that cross to the host when somebody asks for them,
+    once. Until then they stay where the fold left them: an estimator
+    lives as long as the pipeline that holds it (the prefix table lets a
+    fit go with its pipeline: workflow/prefix.py), so a fit whose state
+    nobody exports pays for no copy, and one whose pipeline is kept holds
+    the carry on the device until it is asked for or dropped."""
 
     def __init__(self, arrays):
         self._arrays = tuple(arrays)
         self._host: Optional[Tuple[np.ndarray, ...]] = None
-        self._error: Optional[BaseException] = None
-        self._thread = threading.Thread(target=self._run, name="keystone-state-fetch")
-        self._thread.start()
 
-    def _run(self) -> None:
-        import jax
+    def result(self) -> Tuple[np.ndarray, ...]:
+        if self._host is None:
+            import jax
 
-        try:
             # Export crosses to host by definition: the envelope must
             # pickle into the checkpoint store.  # keystone: allow-sync
             self._host = tuple(np.asarray(jax.device_get(a)) for a in self._arrays)
-        except Exception as e:  # raised again by `result`, where it is asked for
-            self._error = e
-        finally:
-            self._arrays = ()
-
-    def result(self) -> Tuple[np.ndarray, ...]:
-        if self._thread is not None:
-            self._thread.join()
-        if self._error is not None:
-            raise self._error
+            self._arrays = ()  # the device buffers go with the copy
         return self._host
 
     def __getstate__(self):
         # An estimator that is copied or pickled between its fit and its
-        # export takes the fetched arrays with it, not the thread.
-        if self._thread is not None:
-            self._thread.join()
-        return dict(vars(self), _thread=None)
+        # export takes the fetched arrays with it, not the device's.
+        self.result()
+        return dict(vars(self))
 
 
 # ------------------------------------------------------------ the Gram mixin
@@ -263,8 +251,8 @@ class GramStreamStateMixin:
 
     def export_stream_state(self) -> Optional[StreamState]:
         """The envelope of this instance's last streamed fit, its
-        statistics on the host (waits for their fetch, which
-        ``_capture_state`` started, where it has not ended yet)."""
+        statistics on the host (fetched here, the first time it is
+        asked for: ``_capture_state`` left them on the device)."""
         fetch = vars(self).pop("_stream_fetch", None)
         if fetch is not None:
             self._stream_state.carry = fetch.result()
@@ -341,13 +329,13 @@ class GramStreamStateMixin:
         return jax.block_until_ready(carry)
 
     def _capture_state(self, carry, n_total: int, **meta: Any) -> StreamState:
-        """Start the post-fold carry's fetch into a portable envelope and
-        remember both on the instance for ``export_stream_state``. The
+        """Remember the post-fold carry and its portable envelope on the
+        instance for ``export_stream_state``, which fetches it. The
         fetch is O(d²) (1.08 GB and a third of a second at TIMIT's
         width: PERF.md section 6, PR 30) and nothing in the fit reads
-        its result, so it runs beside the finish and whatever the
-        process does next, not between the fold and the finish with the
-        device idle."""
+        its result, so no fit pays for it: whoever exports does (on a
+        thread until PR 34, for every fit, with 1.08 GB of host memory
+        to allocate and free each time: PERF.md section 6, PR 34)."""
         state = StreamState(
             kind=self.stream_state_kind,
             estimator=f"{type(self).__module__}.{type(self).__qualname__}",

@@ -27,7 +27,7 @@ from ..reliability import faultinject
 from ..reliability.recovery import reset_recovery_log
 from .graph import Graph, GraphId, NodeId, SinkId, SourceId
 from .operators import DatasetExpression, EstimatorOperator, Expression
-from .prefix import Prefix, find_prefix
+from .prefix import Prefix, PrefixTable, find_prefix
 from .tracing import timed_execute
 
 
@@ -122,7 +122,7 @@ class PipelineEnv:
     _lock = threading.Lock()
 
     def __init__(self):
-        self.state: Dict[Prefix, Expression] = {}
+        self.state = PrefixTable()  # prefix -> Expression, while it can be asked for
         self._optimizer = None
         # Reliability hooks — both default OFF (zero per-node overhead).
         # retry_policy: a reliability.RetryPolicy applied to every node

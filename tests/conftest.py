@@ -123,12 +123,33 @@ _OUTGROWN_BY_THE_MANIFEST = {
     + "test_the_entries_resolve_to_their_files_in_the_tiny_cells_too[cifar-tiny.fit-incore-phases1]",
 }
 
+# tests/benchmark/test_bench_stream_cell.py (PR 30) in turn holds the
+# manifest to "the streamed configuration, its cell and its three metrics
+# are the last of their lists, and each fit metric's list ends in that
+# cell". PR 34 appended a configuration, a cell and host_idle_ms.kernel.fit
+# after them, the only place they may go. The same rule, the same marks:
+# tests/benchmark/test_bench_krr_cell.py holds what these four held, one
+# place earlier.
+_STREAM_CELL_WAS_LAST = "tests/benchmark/test_bench_stream_cell.py::"
+_OUTGROWN_BY_THE_MANIFEST |= {
+    _STREAM_CELL_WAS_LAST + "test_the_manifest_gained_the_configuration_and_its_one_four_chip_cell",
+    _STREAM_CELL_WAS_LAST
+    + "test_the_eleven_host_idle_entries_stand_as_they_were_and_the_new_ones_came_after",
+    _STREAM_CELL_WAS_LAST
+    + "test_the_host_idle_entries_resolve_in_the_tiny_fit_cells_and_the_stream_phase_in_its_own"
+    "[timit-tiny.fit-incore]",
+    _STREAM_CELL_WAS_LAST
+    + "test_the_host_idle_entries_resolve_in_the_tiny_fit_cells_and_the_stream_phase_in_its_own"
+    "[cifar-tiny.fit-incore]",
+}
+
 
 def pytest_collection_modifyitems(items):
     for item in items:
         if item.nodeid in _OUTGROWN_BY_THE_MANIFEST:
             item.add_marker(pytest.mark.xfail(
-                reason="BENCHMARK.json gained per-layer metrics after these eleven (PR 30); "
-                "the file needs a benchmark PR; see test_bench_stream_cell.py",
+                reason="BENCHMARK.json gained entries after the ones this case expects last "
+                "(PRs 30 and 34); the file needs a benchmark PR; see test_bench_stream_cell.py "
+                "and test_bench_krr_cell.py",
                 strict=False,
             ))

@@ -172,10 +172,10 @@ def test_the_device_scopes_are_in_the_lowered_step():
 # --------------------------------------- what else stood in a big fit's way
 
 
-def test_the_fold_state_crosses_to_the_host_on_a_thread_of_its_own():
-    """`_capture_state` starts the O(d²) fetch and returns; the envelope
-    has host arrays when it is asked for, and the estimator (which the
-    prefix table keeps) holds no device buffer afterwards."""
+def test_the_fold_state_crosses_to_the_host_when_it_is_asked_for():
+    """`_capture_state` keeps the carry where the fold left it and
+    returns; the O(d²) fetch is `export_stream_state`'s, made once, and
+    the estimator holds no device buffer afterwards."""
     import jax
     import jax.numpy as jnp
 
@@ -185,6 +185,7 @@ def test_the_fold_state_crosses_to_the_host_on_a_thread_of_its_own():
     carry = tuple(a + i for i, a in enumerate(linalg.gram_stream_init(8, 2), start=1))
     estimator._capture_state(carry, 5, reg=1e-3)
     fetch = estimator._stream_fetch
+    assert fetch._host is None and len(fetch._arrays) == len(carry)  # nothing copied for a fit nobody exports
     state = estimator.export_stream_state()
     assert state.num_examples == 5 and state.meta == {"reg": 1e-3}
     assert all(isinstance(a, np.ndarray) for a in state.carry)
