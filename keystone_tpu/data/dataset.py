@@ -40,10 +40,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 def default_ingest_workers() -> int:
     """Host-side worker count shared by every ingest-adjacent pool:
-    ``ObjectDataset.map``, the archive decode pool, and the streaming
-    engine's prefetch pipeline. ``KEYSTONE_INGEST_WORKERS`` overrides;
-    the default derives from the host's core count (capped — tar decode
-    pools past ~32 threads just fight the GIL/page cache)."""
+    ``ObjectDataset.map``, the archive decode pool, the streaming
+    engine's prefetch pipeline, and the draw of a bank of random-feature
+    branches (``CosineRandomFeatures.draw_branches``).
+    ``KEYSTONE_INGEST_WORKERS`` overrides; the default derives from the
+    host's core count (capped — tar decode pools past ~32 threads just
+    fight the GIL/page cache)."""
     raw = env_str("KEYSTONE_INGEST_WORKERS").strip()
     if raw:
         return max(1, int(raw))

@@ -87,6 +87,7 @@ BCD_FACTOR_REUSE = "keystone_bcd_factor_reuse_total"
 GRAM_SYMMETRIC = "keystone_gram_symmetric_total"
 KERNEL_PANELS = "keystone_kernel_panels_total"
 KERNEL_PANEL_BYTES = "keystone_kernel_panel_bytes"
+FEATURE_DRAWS = "keystone_feature_draws_total"
 
 # ---------------------------------------------------------------- sketch tier
 SKETCH_FITS = "keystone_sketch_fits_total"
@@ -265,6 +266,7 @@ SCHEMA: Dict[str, Tuple] = {
     BCD_FACTOR_REUSE: ("counter", "In-core block_coordinate_descent calls, by program form: reused = each block's Gram and Cholesky factor computed once in a factor pass and reused in every epoch (num_epochs > 1), single_pass = factored inside the one pass (num_epochs == 1)", ("mode",)),
     KERNEL_PANELS: ("counter", "Kernel column panels computed by the exact kernel suite (ops/learning/kernel.py): blocks x epochs a KernelRidgeRegression fit, train blocks a KernelBlockLinearMapper request", ("site",)),
     KERNEL_PANEL_BYTES: ("gauge", "Bytes of the kernel panel that is live on one device during the last fit (a shard's rows x block) or request (a shard's test rows x block)", ("site",)),
+    FEATURE_DRAWS: ("counter", "Banks of random-feature branches drawn on the host (CosineRandomFeatures.draw_branches: one count a bank, whatever its branches), by the host threads that drew them side by side; 1 = inline on the caller, no pool", ("workers",)),
     GRAM_SYMMETRIC: ("counter", "Fits whose Gram products come from linalg.gram_sym (one count a streamed Gram fold, one a block_coordinate_descent call), by the column panels its width rule cuts the product into: the upper block triangle is computed and mirrored; 1 = the single full product", ("panels",)),
     SKETCH_FITS: ("counter", "Sketched least-squares fits completed, by sketch variant (countsketch/srht)", ("variant",)),
     SKETCH_SIZE: ("gauge", "Sketch rows s chosen for the last sketched fit (knob/tuned/width default)", ()),

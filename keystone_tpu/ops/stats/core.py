@@ -15,13 +15,16 @@ per-vector Breeze loop:
 
 from __future__ import annotations
 
-from typing import Optional
+from concurrent.futures import ThreadPoolExecutor
+from typing import Optional, Sequence
 
 import numpy as np
 
 import jax.numpy as jnp
 
-from ...data.dataset import ArrayDataset, Dataset
+from ...data.dataset import ArrayDataset, Dataset, default_ingest_workers
+from ...obs import names as _names
+from ...obs import spans
 from ...workflow.pipeline import BatchTransformer, Estimator, Transformer
 
 
@@ -106,6 +109,41 @@ class CosineRandomFeatures(BatchTransformer):
             raise ValueError(f"unknown distribution {dist!r}")
         b = rng.uniform(0.0, 2.0 * np.pi, size=num_output_features)
         return w * gamma, b
+
+    @staticmethod
+    def draw_branches(
+        num_input_features: int,
+        num_output_features: int,
+        gamma: float,
+        dist: str,
+        seeds: Sequence[int],
+    ) -> "list[tuple[np.ndarray, np.ndarray]]":
+        """:meth:`draw` for each seed of a bank of branches, in the order
+        of ``seeds``, each (W, b) rounded to float32 as the constructor
+        rounds it: the same bits as one :meth:`create` a seed.
+
+        The branches have a generator each and numpy fills under
+        ``nogil``, so they are drawn side by side on host threads, as
+        many as the package's other host pools use
+        (``default_ingest_workers``), and joined before this returns; one
+        branch or one worker draws inline, with no pool. A worker rounds
+        its own draw, so no more float64 copies than workers are alive.
+        The span ``build:draw`` is the caller's; a worker opens none."""
+        seeds = list(seeds)
+        workers = max(1, min(len(seeds), default_ingest_workers()))
+
+        def one(seed: int):
+            w, b = CosineRandomFeatures.draw(
+                num_input_features, num_output_features, gamma, dist, seed
+            )
+            return w.astype(np.float32), b.astype(np.float32)
+
+        with spans.span("build:draw", branches=len(seeds), workers=workers):
+            _names.metric(_names.FEATURE_DRAWS).inc(workers=str(workers))
+            if workers == 1:
+                return [one(seed) for seed in seeds]
+            with ThreadPoolExecutor(workers, thread_name_prefix="keystone-draw") as pool:
+                return list(pool.map(one, seeds))
 
     def apply_arrays(self, x):
         return jnp.cos(x @ self.w.T + self.b)

@@ -14,7 +14,9 @@ Two forms with one featurizer. Where the feature matrix fits the devices
 (:func:`features_fit_in_core`), :func:`build_pipeline` builds the gather
 of branches and the fit runs in core. Where it does not (TIMIT at its
 published 2.2M frames is 144 GB of features), it builds the same W and b
-stacked into ONE ``CosineRandomFeatures``: a single chain, which the
+(one draw, :func:`_draw_branches`: the branches side by side on host
+threads, each by its own generator) stacked into ONE
+``CosineRandomFeatures``: a single chain, which the
 streaming plan rule absorbs, so the fit folds row chunks into a Gram
 carry on each device of the data mesh and the features are never held
 (docs/PARTITIONING.md "Fitting TIMIT beyond one chip's memory").
@@ -87,17 +89,20 @@ def kernel_gamma(config: TimitConfig) -> float:
     return config.gamma ** 2 / 2.0
 
 
+def _draw_branches(config: TimitConfig, input_dim: int) -> "list[tuple[np.ndarray, np.ndarray]]":
+    """Branch i's float32 (W_i, b_i) (generator ``seed + i``), in branch
+    order: the one draw both forms of the featurizer are made of."""
+    return CosineRandomFeatures.draw_branches(
+        input_dim,
+        config.num_cosine_features,
+        config.gamma,
+        config.rf_type,
+        [config.seed + i for i in range(config.num_cosines)],
+    )
+
+
 def build_featurizer(config: TimitConfig, input_dim: int = TIMIT_DIMENSION) -> Pipeline:
-    branches = [
-        CosineRandomFeatures.create(
-            input_dim,
-            config.num_cosine_features,
-            config.gamma,
-            dist=config.rf_type,
-            seed=config.seed + i,
-        )
-        for i in range(config.num_cosines)
-    ]
+    branches = [CosineRandomFeatures(w, b) for w, b in _draw_branches(config, input_dim)]
     return Pipeline.gather(branches) >> VectorCombiner()
 
 
@@ -110,20 +115,9 @@ def build_stacked_featurizer(
     num_cosine_features`` outputs. Column j of the gather form is column
     j here, so a fit on either gives weights for the other; and a single
     chain is what the streaming plan rule takes."""
-    draws = [
-        CosineRandomFeatures.draw(
-            input_dim,
-            config.num_cosine_features,
-            config.gamma,
-            dist=config.rf_type,
-            seed=config.seed + i,
-        )
-        for i in range(config.num_cosines)
-    ]
-    # rounded to float32 a branch at a time, as the gather form's four
-    # constructors round them: the same bits, half the bytes to stack
-    w = np.concatenate([w.astype(np.float32) for w, _ in draws])
-    b = np.concatenate([b.astype(np.float32) for _, b in draws])
+    draws = _draw_branches(config, input_dim)
+    w = np.concatenate([w for w, _ in draws])
+    b = np.concatenate([b for _, b in draws])
     return CosineRandomFeatures(w, b).to_pipeline()
 
 
@@ -143,7 +137,10 @@ def features_fit_in_core(rows: int, width: int) -> bool:
 
 def build_pipeline(config: TimitConfig, train: LabeledData, input_dim: int = TIMIT_DIMENSION) -> Pipeline:
     # A phase of every fit that starts from a configuration, with the
-    # device idle: the random features are drawn on the host, in numpy.
+    # device idle: the random features are drawn on the host, in numpy,
+    # the branches side by side on host threads (`build:draw`), and are
+    # concrete device arrays when this returns. The label indicators are
+    # a lazy node of the graph, not work done here.
     with spans.span("build:pipeline"):
         labels = ClassLabelIndicators(NUM_CLASSES)(train.labels)
         if config.solver == "kernel":

@@ -200,18 +200,36 @@ def test_obs_imports_and_spans_work_in_a_process_without_jax(jax_is):
 
 
 def test_the_shipped_timit_builder_opens_the_build_phase():
-    """Drawing the random features on the host is 120 ms of each TIMIT
-    fit at the benchmark's width, before `Pipeline.fit` is called at all
-    (PERF.md section 5): the entry point's builder has a span of its own."""
+    """Drawing the random features on the host is a phase of each TIMIT
+    fit before `Pipeline.fit` is called at all (PERF.md section 5): the
+    entry point's builder has a span of its own, and the draw of the bank
+    one inside it, on the calling thread (`host_idle_ms.build.fit` books
+    idle time to the innermost `ks:build:` span: a worker opens none),
+    with the bank's `branches` and `workers`; the counter takes one count
+    a bank."""
+    import threading
+
     from keystone_tpu.data.loaders.csv import LabeledData
     from keystone_tpu.pipelines import timit
 
     config = timit.TimitConfig(num_cosines=2, num_cosine_features=16, num_epochs=1)
     x = np.zeros((32, 440), np.float32)
     train = LabeledData(ArrayDataset(np.zeros(32, np.int32)), ArrayDataset(x))
+    registry = metrics.get_registry()
+
+    def counted():
+        metric = registry.get(names.FEATURE_DRAWS)
+        return metric.value(workers="2") if metric else 0.0
+
+    before = counted()
     with spans.tracing_session("t") as session:
         timit.build_pipeline(config, train)
-    assert [s.name for s in session.spans()] == ["build:pipeline"]
+    draw, build = session.spans()
+    assert (draw.name, build.name) == ("build:draw", "build:pipeline")
+    assert draw.parent_id == build.span_id
+    assert draw.thread_id == build.thread_id == threading.get_ident()
+    assert draw.attributes == {"branches": 2, "workers": 2}
+    assert counted() - before == 1
 
 
 def _names_under_session(sync_timings):

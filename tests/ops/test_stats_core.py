@@ -1,9 +1,13 @@
 """Stats ops vs numpy golden values."""
 
+import threading
+
 import numpy as np
 import pytest
 
 from keystone_tpu.data.dataset import ArrayDataset
+from keystone_tpu.obs import spans
+from keystone_tpu.ops.stats import core as stats_core
 from keystone_tpu.ops.stats.core import (
     CosineRandomFeatures,
     LinearRectifier,
@@ -123,3 +127,74 @@ def test_cosine_random_features_create_shapes_and_dists():
 def test_cosine_random_features_mismatched_b():
     with pytest.raises(ValueError):
         CosineRandomFeatures(np.ones((4, 3)), np.ones(5))
+
+
+# ------------------------------------------- a bank of branches, side by side
+
+def _draw_threads():
+    return [t for t in threading.enumerate() if t.name.startswith("keystone-draw")]
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+@pytest.mark.parametrize("dist", ["gaussian", "cauchy"])
+def test_draw_branches_is_draw_a_seed_rounded_in_branch_order(monkeypatch, dist, workers):
+    monkeypatch.setattr(stats_core, "default_ingest_workers", lambda: workers)
+    seeds = [2_900_000_011 + i for i in range(5)]
+    pairs = CosineRandomFeatures.draw_branches(6, 33, 0.25, dist, seeds)
+    assert len(pairs) == len(seeds)
+    for seed, (w, b) in zip(seeds, pairs):
+        w64, b64 = CosineRandomFeatures.draw(6, 33, 0.25, dist, seed)
+        assert w.dtype == b.dtype == np.float32
+        assert np.array_equal(w, w64.astype(np.float32))
+        assert np.array_equal(b, b64.astype(np.float32))
+        made = CosineRandomFeatures.create(6, 33, 0.25, dist, seed)
+        assert np.array_equal(np.asarray(made.w), w) and np.array_equal(np.asarray(made.b), b)
+
+
+def test_draw_branches_draws_four_at_once(monkeypatch):
+    """Without a clock: every `draw` waits at a barrier of four, which
+    opens only if four are inside `draw` together."""
+    barrier = threading.Barrier(4)
+    real = CosineRandomFeatures.draw
+
+    def waits(*args):
+        barrier.wait(timeout=30)
+        return real(*args)
+
+    monkeypatch.setattr(stats_core, "default_ingest_workers", lambda: 8)
+    monkeypatch.setattr(CosineRandomFeatures, "draw", staticmethod(waits))
+    pairs = CosineRandomFeatures.draw_branches(3, 5, 1.0, "gaussian", [7, 8, 9, 10])
+    assert [w.shape for w, _ in pairs] == [(5, 3)] * 4
+    assert not barrier.broken
+    assert not _draw_threads()  # the pool died with the call
+
+
+@pytest.mark.parametrize("seeds,host_workers", [([3], 8), ([3, 4, 5], 1)], ids=["one-branch", "one-worker"])
+def test_one_branch_or_one_worker_draws_inline(monkeypatch, seeds, host_workers):
+    def no_pool(*args, **kwargs):
+        raise AssertionError("a pool was made")
+
+    seen = []
+    real = CosineRandomFeatures.draw
+
+    def records(*args):
+        seen.append(threading.get_ident())
+        return real(*args)
+
+    monkeypatch.setattr(stats_core, "default_ingest_workers", lambda: host_workers)
+    monkeypatch.setattr(stats_core, "ThreadPoolExecutor", no_pool)
+    monkeypatch.setattr(CosineRandomFeatures, "draw", staticmethod(records))
+    with spans.tracing_session("t") as session:
+        pairs = CosineRandomFeatures.draw_branches(3, 5, 1.0, "gaussian", seeds)
+    assert len(pairs) == len(seeds)
+    assert seen == [threading.get_ident()] * len(seeds)
+    (span,) = session.spans()
+    assert span.attributes == {"branches": len(seeds), "workers": 1}
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+def test_a_workers_error_reaches_the_caller_as_it_is(monkeypatch, workers):
+    monkeypatch.setattr(stats_core, "default_ingest_workers", lambda: workers)
+    with pytest.raises(ValueError, match="unknown distribution 'laplace'"):
+        CosineRandomFeatures.draw_branches(3, 5, 1.0, "laplace", [1, 2, 3, 4])
+    assert not _draw_threads()

@@ -2,12 +2,15 @@
 gather form's featurizer as ONE chain, which `Pipeline.fit` streams over
 the data mesh; its weights are the in-core fit's."""
 
+import threading
+
 import jax
 import numpy as np
 import pytest
 
 from keystone_tpu.data.dataset import ArrayDataset
 from keystone_tpu.ops.learning.block import BlockLinearMapper
+from keystone_tpu.ops.stats import core as stats_core
 from keystone_tpu.ops.stats.core import CosineRandomFeatures
 from keystone_tpu.parallel.mesh import make_mesh, use_mesh
 from keystone_tpu.pipelines import timit as t
@@ -50,7 +53,10 @@ def does_not_fit(monkeypatch):
     monkeypatch.setenv("KEYSTONE_STREAM_CHUNK_ROWS", str(CHUNK))
 
 
-def test_the_stacked_featurizer_is_the_gather_forms_to_the_bit():
+@pytest.mark.parametrize("workers", [1, 4], ids=["inline", "pool"])
+def test_the_stacked_featurizer_is_the_gather_forms_to_the_bit(monkeypatch, workers):
+    # both forms are made of one draw (`_draw_branches`), inline or on the pool
+    monkeypatch.setattr(stats_core, "default_ingest_workers", lambda: workers)
     config = _config(num_cosines=3)
     branches = [
         CosineRandomFeatures.create(t.TIMIT_DIMENSION, 256, config.gamma, seed=config.seed + i)
@@ -78,6 +84,36 @@ def test_the_entry_point_streams_only_what_does_not_fit(monkeypatch, limit, stre
     monkeypatch.setattr(t, "device_memory_limit_bytes", lambda: limit)
     pipeline = t.build_pipeline(_config(), t.synthetic_timit(ROWS, seed=0))
     assert len(_members(pipeline)) == (1 if streams else 2)
+
+
+@pytest.mark.parametrize("rf_type", ["gaussian", "cauchy"])
+@pytest.mark.parametrize("limit", [None, 1 << 20], ids=["gather", "stacked"])
+def test_the_built_pipeline_holds_todays_weights_and_nothing_of_the_draw(monkeypatch, limit, rf_type):
+    """What `build_pipeline` returns is what it returned when the branches
+    were drawn one after another: concrete float32 device arrays equal to
+    one `create` a branch; no thread of the pool is alive."""
+    monkeypatch.setattr(t, "device_memory_limit_bytes", lambda: limit)
+    monkeypatch.setattr(stats_core, "default_ingest_workers", lambda: 4)
+    config = _config(num_cosines=4, num_cosine_features=64, rf_type=rf_type)
+    pipeline = t.build_pipeline(config, t.synthetic_timit(ROWS, seed=0))
+    assert not [th for th in threading.enumerate() if th.name.startswith("keystone-draw")]
+    members = _members(pipeline)
+    assert all(isinstance(m.w, jax.Array) and m.w.dtype == np.float32 for m in members)
+    made = [
+        CosineRandomFeatures.create(t.TIMIT_DIMENSION, 64, config.gamma, dist=rf_type, seed=config.seed + i)
+        for i in range(4)
+    ]
+    if limit is None:  # a graph's operators are in no order: find each branch by its bits
+        assert len(members) == 4
+        for m in made:
+            assert sum(
+                np.array_equal(np.asarray(m.w), np.asarray(g.w)) and np.array_equal(np.asarray(m.b), np.asarray(g.b))
+                for g in members
+            ) == 1
+    else:
+        (stacked,) = members
+        assert np.array_equal(np.concatenate([np.asarray(m.w) for m in made]), np.asarray(stacked.w))
+        assert np.array_equal(np.concatenate([np.asarray(m.b) for m in made]), np.asarray(stacked.b))
 
 
 def test_fit_in_core_counts_every_shard_of_the_mesh(monkeypatch):
