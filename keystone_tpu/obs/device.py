@@ -52,12 +52,39 @@ def to_device(data: Any, site: str, consumers: int = 1) -> Any:
     nbytes = sum(leaf.nbytes for leaf in host)
     with spans.span("h2d", site=site, bytes=nbytes, consumers=consumers):
         out = jax.tree_util.tree_map(
-            lambda leaf: jnp.asarray(leaf) if isinstance(leaf, np.ndarray) else leaf,
+            lambda leaf: _upload(leaf) if isinstance(leaf, np.ndarray) else leaf,
             data,
         )
     names.metric(names.H2D_BYTES).inc(nbytes, site=site)
     names.metric(names.H2D_TRANSFERS).inc(len(host), site=site)
     return out
+
+
+#: A host array whose last dimension is narrower than this (an image
+#: batch's 3 channels) goes up as (rows, everything else) and is given its
+#: shape on the device. 128 is the v5e's lane width, below which the
+#: device's layout puts another dimension innermost; measured at ONE
+#: width only, 3 (my chip runs, PR 36: 1.2 million host transposes and
+#: 160 ms a 201 MB request handed up as it is, none and 33 ms flat). The
+#: TIMIT cells' 440-wide rows are past it and go up as they did.
+_NARROW = 128
+
+
+def _upload(leaf):
+    """``jnp.asarray(leaf)``. A batch of three or more dimensions with a
+    narrow last one is uploaded flat and reshaped on the device: the
+    device keeps such an array with a wider dimension innermost, and
+    handed the array as it is the runtime makes that transpose on the
+    HOST, a few hundred elements at a time (an image batch of 256 x 256 x
+    256 x 3 float32: 1.2 million host transposes on four threads a
+    request, each an event in a profiler's trace, which filled the 40 GiB
+    of the benchmark's host in one traced window; my chip run, PR 36).
+    Flat, the bytes go up as they lie and the device transposes."""
+    import jax.numpy as jnp
+
+    if leaf.ndim >= 3 and leaf.shape[-1] < _NARROW and leaf.size:
+        return jnp.reshape(jnp.asarray(leaf.reshape(leaf.shape[0], -1)), leaf.shape)
+    return jnp.asarray(leaf)
 
 
 def rss_bytes() -> int:

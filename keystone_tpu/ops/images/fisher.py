@@ -19,12 +19,50 @@ Math (Sanchez et al., IJCV 2013, as implemented by the reference):
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ...data.dataset import ArrayDataset, Dataset
+from ...obs import spans as _spans
+from ...parallel import linalg
 from ...workflow.optimize import DataStats, Optimizable
 from ...workflow.pipeline import BatchTransformer, Estimator
-from ..learning.gmm import GaussianMixtureModel, GaussianMixtureModelEstimator
+from ..learning.gmm import (
+    GaussianMixtureModel,
+    GaussianMixtureModelEstimator,
+    _gmm_posteriors,
+)
+
+
+@linalg.mode_jit
+def _fisher_encode(x, means, variances, weights, weight_threshold):
+    """(N, n_desc, D) descriptors -> (N, D, 2K): the posteriors and the
+    two gradients as ONE program, whose operations carry the encoder's
+    name in a device trace (dispatched one by one they carry none)."""
+    with jax.named_scope("feat/FisherVector"):
+        x = x.astype(jnp.float32)
+        n_desc = x.shape[1]
+        means = means.astype(jnp.float32)          # (D, K)
+        variances = variances.astype(jnp.float32)  # (D, K)
+        weights = weights.astype(jnp.float32)      # (K,)
+
+        flat = x.reshape(-1, x.shape[-1])
+        q = _gmm_posteriors(flat, means.T, variances.T, weights, weight_threshold)
+        q = q.reshape(x.shape[0], n_desc, -1)               # (N, n, K)
+
+        s0 = jnp.mean(q, axis=1)                            # (N, K)
+        # float32 moments on the chip too (its default rounds the inputs
+        # to bfloat16, and fv2 below is a difference of near-equal terms)
+        at = linalg.precision()
+        s1 = jnp.einsum("bnd,bnk->bdk", x, q, precision=at) / n_desc      # (N, D, K)
+        s2 = jnp.einsum("bnd,bnk->bdk", x * x, q, precision=at) / n_desc  # (N, D, K)
+
+        s0b = s0[:, None, :]                                # (N, 1, K)
+        fv1 = (s1 - means * s0b) / (jnp.sqrt(variances) * jnp.sqrt(weights))
+        fv2 = (s2 - 2.0 * means * s1 + (means * means - variances) * s0b) / (
+            variances * jnp.sqrt(2.0 * weights)
+        )
+        return jnp.concatenate([fv1, fv2], axis=2)          # (N, D, 2K)
 
 
 class FisherVector(BatchTransformer):
@@ -35,25 +73,20 @@ class FisherVector(BatchTransformer):
         self.gmm = gmm
 
     def apply_arrays(self, x):
-        x = x.astype(jnp.float32)
-        n_desc = x.shape[1]
-        means = self.gmm.means.astype(jnp.float32)          # (D, K)
-        variances = self.gmm.variances.astype(jnp.float32)  # (D, K)
-        weights = self.gmm.weights.astype(jnp.float32)      # (K,)
-
-        flat = x.reshape(-1, x.shape[-1])
-        q = self.gmm.apply_arrays(flat).reshape(x.shape[0], n_desc, -1)  # (N, n, K)
-
-        s0 = jnp.mean(q, axis=1)                            # (N, K)
-        s1 = jnp.einsum("bnd,bnk->bdk", x, q) / n_desc      # (N, D, K)
-        s2 = jnp.einsum("bnd,bnk->bdk", x * x, q) / n_desc  # (N, D, K)
-
-        s0b = s0[:, None, :]                                # (N, 1, K)
-        fv1 = (s1 - means * s0b) / (jnp.sqrt(variances) * jnp.sqrt(weights))
-        fv2 = (s2 - 2.0 * means * s1 + (means * means - variances) * s0b) / (
-            variances * jnp.sqrt(2.0 * weights)
+        return _fisher_encode(
+            x, self.gmm.means, self.gmm.variances, self.gmm.weights,
+            jnp.float32(self.gmm.weight_threshold),
         )
-        return jnp.concatenate([fv1, fv2], axis=2)          # (N, D, 2K)
+
+    def chunk_applier(self):
+        """Every batch form of ``apply_batch`` below encodes an image from
+        its own descriptors alone."""
+        return self.apply_batch
+
+    def host_span(self, dataset):
+        return _spans.span(
+            "image:fisher", rows=dataset.num_examples, centres=int(self.gmm.k)
+        )
 
     def apply_arrays_masked(self, x, valid):
         """Fisher-encode ragged descriptor batches: ``x`` (N, n_pad, D)

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from ...data.dataset import ArrayDataset, Dataset
+from ...obs import spans as _spans
 from ...parallel import linalg
 from ...workflow.pipeline import BatchTransformer, Estimator
 from ..stats.core import _as_array_dataset
@@ -53,6 +54,12 @@ class GaussianMixtureModel(BatchTransformer):
         self.weight_threshold = weight_threshold
         assert self.means.shape == self.variances.shape
         assert self.weights.shape[0] == self.means.shape[1]
+        #: What a fit was started from and how far it went, for whoever
+        #: wants to follow it (a reference implementation: the k-means++
+        #: start is drawn from a seed, the rest is arithmetic): the
+        #: initial (means, variances, weights), each (k, ...), and the
+        #: number of EM updates applied. None on a loaded model.
+        self.fit_record = None
 
     @property
     def k(self) -> int:
@@ -139,6 +146,10 @@ class GaussianMixtureModelEstimator(Estimator):
 
     def fit(self, data: Dataset) -> GaussianMixtureModel:
         ds = _as_array_dataset(data)
+        with _spans.span("gmm:fit", samples=ds.num_examples, centres=self.k):
+            return self._fit(ds)
+
+    def _fit(self, ds: ArrayDataset) -> GaussianMixtureModel:
         x = np.asarray(jax.device_get(ds.data), dtype=np.float32)[: ds.num_examples]
         n, d = x.shape
 
@@ -164,20 +175,27 @@ class GaussianMixtureModelEstimator(Estimator):
         ).astype(np.float32)
         vars0 = np.maximum(vars0, var_lb)
 
-        means, variances, weights = _gmm_em(
-            jnp.asarray(x),
-            jnp.asarray(means0, dtype=jnp.float32),
-            jnp.asarray(vars0, dtype=jnp.float32),
-            jnp.asarray(weights0, dtype=jnp.float32),
-            jnp.asarray(var_lb),
-            self.max_iterations,
-            jnp.float32(self.stop_tolerance),
-            jnp.float32(self.weight_threshold),
-            jnp.float32(self.min_cluster_size),
+        start = (
+            np.asarray(means0, np.float32), np.asarray(vars0, np.float32),
+            np.asarray(weights0, np.float32),
         )
-        return GaussianMixtureModel(
+        with _spans.span("gmm:em", samples=n, centres=self.k) as sp:
+            means, variances, weights, iterations, updates = _gmm_em(
+                jnp.asarray(x),
+                *(jnp.asarray(a) for a in start),
+                jnp.asarray(var_lb),
+                self.max_iterations,
+                jnp.float32(self.stop_tolerance),
+                jnp.float32(self.weight_threshold),
+                jnp.float32(self.min_cluster_size),
+            )
+            iterations, updates = int(iterations), int(updates)  # the loop has run
+            sp.set_attribute("iterations", iterations)
+        model = GaussianMixtureModel(
             means.T, variances.T, weights, self.weight_threshold
         )
+        model.fit_record = {"start": start, "iterations": iterations, "updates": updates}
+        return model
 
 
 @functools.partial(linalg.mode_jit, static_argnums=(5,))
@@ -187,11 +205,11 @@ def _gmm_em(x, means0, vars0, weights0, var_lb, max_iterations, tol,
     xsq = x * x
 
     def cond(state):
-        _, _, _, i, prev_cost, keep_going = state
+        _, _, _, i, _, _, keep_going = state
         return (i < max_iterations) & keep_going
 
     def body(state):
-        means, variances, weights, i, prev_cost, _ = state
+        means, variances, weights, i, updates, prev_cost, _ = state
         llh = _gmm_log_likelihood(x, means, variances, weights)
         cost = jnp.mean(jax.scipy.special.logsumexp(llh, axis=1))
         improving = jnp.where(i > 0, (cost - prev_cost) >= tol * jnp.abs(prev_cost), True)
@@ -214,10 +232,16 @@ def _gmm_em(x, means0, vars0, weights0, var_lb, max_iterations, tol,
         means = jnp.where(do_update, new_means, means)
         variances = jnp.where(do_update, new_vars, variances)
         weights = jnp.where(do_update, new_weights, weights)
-        return means, variances, weights, i + 1, cost, do_update
+        return (
+            means, variances, weights, i + 1,
+            updates + do_update.astype(jnp.int32), cost, do_update,
+        )
 
-    means, variances, weights, *_ = jax.lax.while_loop(
+    means, variances, weights, iterations, updates, *_ = jax.lax.while_loop(
         cond, body,
-        (means0, vars0, weights0, jnp.int32(0), jnp.float32(-jnp.inf), jnp.bool_(True)),
+        (
+            means0, vars0, weights0, jnp.int32(0), jnp.int32(0),
+            jnp.float32(-jnp.inf), jnp.bool_(True),
+        ),
     )
-    return means, variances, weights
+    return means, variances, weights, iterations, updates

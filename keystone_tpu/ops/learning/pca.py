@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from ...data.dataset import ArrayDataset, Dataset, ObjectDataset
+from ...obs import spans as _spans
 from ...parallel import linalg
 from ...parallel.mesh import get_mesh, num_devices
 from ...parallel.partitioner import fit_mesh
@@ -92,11 +93,38 @@ class BatchPCATransformer(Transformer):
             if x.ndim == 2:  # flat (n, d) descriptor rows
                 out = linalg.mm(x, self.components)
             else:  # uniform (n, cols, d) stack: one batched einsum on the MXU
-                out = jnp.einsum(
-                    "ncd,dk->nck", x, self.components, precision=linalg.precision()
-                )
+                with _spans.span(
+                    "image:pca", rows=dataset.num_examples,
+                    descriptors=int(x.shape[1]), dims=int(self.components.shape[1]),
+                ):
+                    out = _project_stack(x, self.components)
             return ArrayDataset(out, dataset.num_examples)
         return dataset.map(self.apply)
+
+    def chunk_applier(self):
+        """Row by row in every batch form above (a right-multiply)."""
+        return self.apply_batch
+
+    def out_spec(self, in_specs):
+        """(n, c, d) or (n, d) -> the same with ``dims`` last; the masked
+        dictionary and everything else is left to the verifier's default."""
+        from ...workflow.verify import UNKNOWN
+
+        if not in_specs or in_specs[0] is UNKNOWN or isinstance(in_specs[0], dict):
+            return UNKNOWN
+        leaves = jax.tree_util.tree_leaves(in_specs[0])
+        if len(leaves) != 1 or len(leaves[0].shape) not in (2, 3):
+            return UNKNOWN
+        shape = tuple(leaves[0].shape[:-1]) + (int(self.components.shape[1]),)
+        return jax.ShapeDtypeStruct(shape, jnp.result_type(leaves[0].dtype, self.components.dtype))
+
+
+@linalg.mode_jit
+def _project_stack(x, components):
+    """(n, cols, d) x (d, k): one program, so that its operations carry
+    the transformer's name in a device trace (an eager einsum has none)."""
+    with jax.named_scope("feat/BatchPCATransformer"):
+        return jnp.einsum("ncd,dk->nck", x, components, precision=linalg.precision())
 
 
 class PCAEstimator(Estimator, CostModel):
@@ -252,7 +280,8 @@ class LocalColumnPCAEstimator(Estimator, CostModel):
 
     def fit(self, data: Dataset) -> BatchPCATransformer:
         flat = _columns_to_vectors(data)
-        t = self._inner.fit(flat)
+        with _spans.span("pca:fit", method="local", samples=len(flat), dims=self.dims):
+            t = self._inner.fit(flat)
         return BatchPCATransformer(t.components)
 
     def cost(self, *args, **kw):
@@ -274,7 +303,8 @@ class DistributedColumnPCAEstimator(Estimator, CostModel):
 
     def fit(self, data: Dataset) -> BatchPCATransformer:
         flat = _columns_to_vectors(data)
-        t = self._inner.fit(flat)
+        with _spans.span("pca:fit", method="tsqr", samples=len(flat), dims=self.dims):
+            t = self._inner.fit(flat)
         return BatchPCATransformer(t.components)
 
     def cost(self, *args, **kw):

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from ...data.dataset import Dataset
+from ...obs import spans as _spans
 from ...parallel import linalg
 from ...workflow.pipeline import LabelEstimator
 from ..stats.core import _as_array_dataset
@@ -122,20 +123,24 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         onehot = np.zeros((n, num_classes), np.float32)
         onehot[np.arange(n), class_idx] = 1.0
 
-        w, joint_means = _weighted_bcd(
-            jnp.asarray(x),
-            jnp.asarray(xs),
-            jnp.asarray(y),
-            jnp.asarray(onehot),
-            jnp.asarray(offsets),
-            jnp.asarray(counts.astype(np.float32)),
-            jnp.float32(self.reg),
-            jnp.float32(self.mixture_weight),
-            num_blocks, bs, m, self.num_iter, self.solve_path,
-        )
+        with _spans.span(
+            "solver:weighted", classes=num_classes, blocks=num_blocks,
+            rows=n, block=bs, largest_class=m,
+        ):
+            w, joint_means = _weighted_bcd(
+                jnp.asarray(x),
+                jnp.asarray(xs),
+                jnp.asarray(y),
+                jnp.asarray(onehot),
+                jnp.asarray(offsets),
+                jnp.asarray(counts.astype(np.float32)),
+                jnp.float32(self.reg),
+                jnp.float32(self.mixture_weight),
+                num_blocks, bs, m, self.num_iter, self.solve_path,
+            )
 
-        jlm = joint_label_means(counts, n, self.mixture_weight)
-        b = weighted_intercept(jlm, joint_means, w)
+            jlm = joint_label_means(counts, n, self.mixture_weight)
+            b = weighted_intercept(jlm, joint_means, w)
         return BlockLinearMapper(w, block_size=bs, intercept=b)
 
 

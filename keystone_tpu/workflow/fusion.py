@@ -41,7 +41,7 @@ from __future__ import annotations
 
 import logging
 import threading
-from contextlib import contextmanager
+from contextlib import ExitStack, contextmanager
 from typing import List, Optional, Sequence, Tuple
 
 from ..envknobs import env_disabled
@@ -181,6 +181,20 @@ class FusedTransformerOperator(BatchTransformer):
         state = self.__dict__.copy()
         state["_jitted"] = None  # jitted callables don't pickle
         return state
+
+    def host_span(self, dataset):
+        """The members' own host spans, all around the one dispatch (none
+        of a chain's members has one, as a rule: then nothing is opened)."""
+        own = [
+            m for m in self.members
+            if type(m).host_span is not BatchTransformer.host_span
+        ]
+        if not own:
+            return BatchTransformer.host_span(self, dataset)
+        stack = ExitStack()
+        for m in own:
+            stack.enter_context(m.host_span(dataset))
+        return stack
 
     def _chain(self, x):
         for m in self.members:

@@ -79,6 +79,11 @@ class Expression:
                     self._thunk = None
         return self._value
 
+    @property
+    def forced(self) -> bool:
+        """Whether the value is there already (``get`` would run nothing)."""
+        return self._value is not _UNSET
+
     def __getstate__(self):
         # Locks don't pickle; a forced expression (thunk already dropped)
         # must stay serializable — SavedStateLoadRule splices expressions

@@ -127,6 +127,17 @@ class Transformer(TransformerOperator, Chainable):
     def apply_batch(self, dataset: Dataset) -> Dataset:
         return dataset.map(self.apply)
 
+    def chunk_applier(self) -> Optional[Callable[[Dataset], Dataset]]:
+        """A function to apply to consecutive row chunks of one batch, in
+        order, whose outputs laid end to end equal ``apply_batch`` on the
+        whole batch to the bit; ``None`` (the default) where the batch form
+        is not row by row, or nobody has said that it is. A new function a
+        call: one that carries something from chunk to chunk (a sampler's
+        generator) starts afresh. The graph executor runs a chain of such
+        transformers over row chunks where the chain would not fit the
+        device whole (workflow/executor.py, ``_RowChain``)."""
+        return None
+
     # Operator protocol -----------------------------------------------------
     def single_transform(self, datums: List[Any]) -> Any:
         return self.apply(datums[0])
@@ -226,6 +237,20 @@ class BatchTransformer(Transformer):
     def apply_arrays(self, data: Any) -> Any:
         raise NotImplementedError
 
+    def chunk_applier(self) -> Optional[Callable[[Dataset], Dataset]]:
+        """``apply_batch`` as it stands here: ``apply_arrays`` is row
+        independent by contract. A subclass with an ``apply_batch`` of its
+        own says for itself whether that is (``FisherVector`` does)."""
+        if type(self).apply_batch is BatchTransformer.apply_batch:
+            return self.apply_batch
+        return None
+
+    def host_span(self, dataset: ArrayDataset):
+        """A span of this transformer's own around its application to
+        ``dataset``, on the host (the extractors' ``image:*``); none by
+        default."""
+        return _spans._NOOP_SPAN_CM
+
     def apply(self, datum: Any) -> Any:
         import jax
         import jax.numpy as jnp
@@ -283,7 +308,7 @@ class BatchTransformer(Transformer):
         # the executor has uploaded it once for all of them
         # (executor._SharedUpload) and there is no host leaf left here.
         data = to_device(dataset.data, site=type(self).__name__)
-        with feat_scope(self):
+        with self.host_span(dataset), feat_scope(self):
             out = ArrayDataset(self.apply_arrays(data), dataset.num_examples)
         if out.physical_rows > out.num_examples:
             real_row = jnp.arange(out.physical_rows) < out.num_examples

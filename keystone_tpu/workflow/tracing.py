@@ -132,9 +132,12 @@ def _node_seconds_hist():
     return _names.metric(_names.NODE_SECONDS)
 
 
-def timed_execute(op, deps):
+def timed_execute(op, deps, execute=None):
     """Execute ``op`` under the active trace/span session (or plainly if
-    neither is active).
+    neither is active). ``execute`` stands in for ``op.execute`` where
+    the executor has something to decide before the operator's own thunk
+    runs (a row chain: workflow/executor.py); the span, the timing and
+    the ledger entry are ``op``'s either way.
 
     The blocking device sync (:func:`_force`) runs only when someone
     actually needs real per-node timings — an active ``trace()`` shim or
@@ -160,7 +163,7 @@ def timed_execute(op, deps):
     """
     tr = current_trace()
     session = _spans.active_session()
-    expression = op.execute(deps)
+    expression = (execute or op.execute)(deps)
     cost_on = _cost.cost_observatory_enabled()
     if tr is None and session is None and not cost_on:
         return expression

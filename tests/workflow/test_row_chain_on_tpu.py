@@ -1,0 +1,97 @@
+"""The executor's row-chain rule against the v5e compiler's own memory
+analysis: the flagship's extractors compiled HERE, at their real sizes,
+for a chip that is described and not attached (nothing runs; no time or
+result comes of this). The rule estimates a program's workspace as
+`executor.TEMPORARIES` times the chain's largest output: these compiles
+are where that number comes from, and they hold it there.
+
+Every TPU compile of the suite lives in this one file, and the topology
+is described inside a fixture: one process may hold the TPU's library,
+and every xdist worker imports every test file."""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from keystone_tpu.workflow import executor
+
+GIB = 2**30
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # and cannot be read back without the chip: keep these out of it
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _memory(fn, one_chip, *shapes):
+    specs = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for s in shapes]
+    analysis = jax.jit(fn).lower(*specs).compile().memory_analysis()
+    return analysis.argument_size_in_bytes, analysis.output_size_in_bytes, analysis.temp_size_in_bytes
+
+
+def _sift_prefix(x):
+    from keystone_tpu.ops.images.core import GrayScaler, PixelScaler
+    from keystone_tpu.ops.images.sift import SIFTExtractor
+    from keystone_tpu.ops.stats.core import SignedHellingerMapper
+
+    for op in (PixelScaler(), GrayScaler(), SIFTExtractor(), SignedHellingerMapper()):
+        x = op.apply_arrays(x)
+    return x
+
+
+def test_dense_sift_at_256_images_holds_under_four_times_its_descriptors(one_chip):
+    """The request's fused prefix (PixelScaler + GrayScaler + SIFTExtractor
+    + SignedHellingerMapper) at 256 images of 256 x 256 x 3."""
+    args, out, temp = _memory(_sift_prefix, one_chip, (256, 256, 256, 3))
+    assert out == 256 * 13165 * 128 * 4
+    assert 2.0 * out < temp < executor.TEMPORARIES * out  # 3.1 times, when this was written
+    # and the whole-run estimate of the request's SIFT chain covers what
+    # the program needs while it runs: its input, output and workspace
+    pca_out, encoding = out // 2, 256 * 2048 * 4
+    estimate = args + out + pca_out + 2 * encoding + executor.TEMPORARIES * out
+    assert args + out + temp < estimate < 15.75 * GIB  # a request fits whole
+    assert 2 * estimate > 15.75 * GIB  # and twice the rows do not
+
+
+def test_lcs_at_the_fits_2048_images_fits_the_chip_whole(one_chip):
+    from keystone_tpu.ops.images.lcs import _lcs_body
+
+    offsets = np.arange(-10, 9, 6)
+    args, out, temp = _memory(
+        lambda x: _lcs_body(x, 4, 16, 6, offsets), one_chip, (2048, 256, 256, 3)
+    )
+    assert out == 2048 * 3136 * 96 * 4 and temp < executor.TEMPORARIES * out
+    pca_out = out * 64 // 96
+    estimate = args + out + pca_out + executor.TEMPORARIES * out
+    assert args + out + temp + pca_out < estimate < 15.75 * GIB
+
+
+def test_the_fisher_encoding_of_a_request_needs_less_than_the_sift_before_it(one_chip):
+    from keystone_tpu.ops.images.fisher import _fisher_encode
+
+    def encode(x, means, variances, weights):
+        return _fisher_encode.__wrapped__(x, means, variances, weights, jnp.float32(1e-4))
+
+    args, out, temp = _memory(encode, one_chip, (256, 13165, 64), (64, 16), (64, 16), (16,))
+    assert out == 256 * 64 * 32 * 4
+    assert temp < executor.TEMPORARIES * 256 * 13165 * 128 * 4 / 2
