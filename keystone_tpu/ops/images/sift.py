@@ -6,9 +6,11 @@ nodes/images/external/SIFTExtractor.scala:16-40). The reference loops
 per-image through vlfeat's ``vl_dsift`` C implementation; here the whole
 batch is one XLA computation: a Gaussian pyramid (separable convs), 8
 orientation-mass planes with linear orientation interpolation, triangular
-spatial binning (the flat-window dense-SIFT formulation) via depthwise
-convolutions, and strided gathers for the 4×4 descriptor grids — all
-static shapes, fused by XLA, batched over images in HBM.
+spatial binning (the flat-window dense-SIFT formulation) as two products
+with constant banded matrices a plane (``_band_product``: on the MXU,
+where a one-channel convolution runs at a hundredth of the chip), and
+strided gathers for the 4×4 descriptor grids — all static shapes, fused
+by XLA, batched over images in HBM.
 
 Algorithm parity notes (same knobs as the reference kernel):
 - per scale ``s``: bin size ``b = bin_size + 2s``, Gaussian smoothing with
@@ -68,7 +70,10 @@ def _separable_conv(
     boundary: str = "zero",
     conv_dtype=None,
 ) -> jnp.ndarray:
-    """Depthwise same-size separable 2-D convolution over (B, H, W).
+    """Depthwise same-size separable 2-D convolution over (B, H, W): the
+    extractors' smoother, and the form of the spatial binning that
+    ``_spatial_binning`` keeps for an axis too long for its band products
+    and is held to where it takes them (tests/ops/test_sift_binning.py).
 
     ``boundary='edge'`` replicates the border (vl_imsmooth's continuity
     padding — zero padding would fabricate gradients at the image edge);
@@ -119,6 +124,66 @@ def _separable_conv(
     return out[:, 0].astype(jnp.float32)
 
 
+# Longest axis, in columns a tap of the kernel, whose spatial binning runs as
+# band products. A band product's work a pixel grows with the axis (2 x
+# length multiply-adds, on the MXU), a one-channel convolution's with the
+# taps (on the vector unit). Measured on a v5e, float32 at HIGHEST, 134 M
+# pixels a pass (PR 37): the product 2.2-2.5 ms at 256 columns, the
+# convolution 12.8, 17.8, 24.2, 25.4 ms at 7, 11, 15, 19 taps; at 512
+# columns the whole SIFT program of 64 images 185 ms by products, 343 by
+# convolutions. By those the product wins up to some 170 columns a tap; 128
+# leaves room (896 columns at 7 taps, 2,432 at 19) and nothing past 512 has
+# been timed. Output tiles of a fixed width, which make the product's work
+# flat in the axis, were tried at 512 (tiles reading 128, 256, 384 columns:
+# 237, 250, 258 ms) and lost to the one wide band: the overlapping copies
+# cost more than the doubled product.
+BAND_COLUMNS_PER_TAP = 128
+
+
+def _binning_as_products(x_dim: int, y_dim: int, taps: int) -> bool:
+    """The one rule that chooses the spatial binning's form, from static
+    shapes alone: band products where neither axis outgrows the taps."""
+    return max(x_dim, y_dim) <= BAND_COLUMNS_PER_TAP * taps
+
+
+def _band_product(x: jnp.ndarray, kernel: np.ndarray, axis: int, dtype=None) -> jnp.ndarray:
+    """Same-size correlation of ``x`` with ``kernel`` along ``axis``, zero
+    outside, as one product with the constant banded matrix
+    ``B[u, j] = kernel[u - j + pad]`` built here on the host: what
+    ``lax.conv_general_dilated`` gives a one-channel stencil, on the unit
+    built for products.
+
+    No batch dimension (a batched float32 product at HIGHEST becomes a
+    ``while`` over slices on the TPU); float32 operands at HIGHEST, or
+    operands in ``dtype`` with float32 accumulation."""
+    taps, length = len(kernel), x.shape[axis]
+    if taps % 2 == 0:
+        raise ValueError(f"a same-size band needs an odd kernel, not {taps} taps")
+    tap = np.arange(length)[:, None] - np.arange(length)[None, :] + taps // 2
+    inside = (tap >= 0) & (tap < taps)
+    band = jnp.asarray(np.where(inside, kernel[np.clip(tap, 0, taps - 1)], 0).astype(np.float32))
+    precision = lax.Precision.HIGHEST
+    if dtype is not None:
+        x, band, precision = x.astype(dtype), band.astype(dtype), None
+    out = lax.dot_general(
+        x, band, (((axis,), (0,)), ((), ())),
+        precision=precision, preferred_element_type=jnp.float32,
+    )
+    return jnp.moveaxis(out, -1, axis)
+
+
+def _spatial_binning(planes: jnp.ndarray, kernel: np.ndarray, dtype=None) -> jnp.ndarray:
+    """``_separable_conv(planes, kernel, "zero", dtype)`` over (B, H, W):
+    as two band products a plane, the last axis (a 2-D product as the
+    planes stand) and then the middle one, the intermediate cast to
+    ``dtype`` as the convolution casts it; as that convolution itself
+    where ``_binning_as_products`` says an axis is too long for the taps."""
+    if not _binning_as_products(*planes.shape[1:], len(kernel)):
+        return _separable_conv(planes, kernel, "zero", dtype)
+    out = _band_product(planes, kernel, 2, dtype)
+    return _band_product(out, kernel, 1, dtype)
+
+
 def descriptor_span(extractor, name: str, dataset, per_image: int, **attributes):
     """An extractor's ``image:<name>`` host span (``rows``,
     ``descriptors`` an image) and its count in
@@ -146,13 +211,17 @@ class SIFTExtractor(BatchTransformer):
         self.bin_size = bin_size
         self.scales = scales
         self.scale_step = scale_step
-        # Dtype for the SPATIAL-BINNING convs only (8 orientation planes
-        # per pixel per scale — the bulk of the conv work). Measured:
+        # Dtype of the SPATIAL BINNING's operands only (8 orientation planes
+        # a pixel a scale; the accumulation is float32 either way). Measured:
         # binning in bf16 stays 100% within-1 of the fp32 build at the
         # reference's x512 quantization, while bf16 SMOOTHING fails the
         # 99.5%-within-1 gate (97.5%) because the gradient stencil
         # amplifies its rounding — so the smoother is always fp32.
-        # Default fp32; flip after an on-chip throughput A/B.
+        # Default fp32 at HIGHEST. On a v5e (PR 37) the fp32 binning of 256
+        # images of 256 x 256 at four scales, the planes' own arithmetic
+        # with it, is 18 ms of a 126 ms SIFT program as band products (169
+        # of 294 ms as one-channel convolutions): bf16 has at most those
+        # 18 ms to give, and has not been timed.
         self.binning_dtype = binning_dtype
 
     @property
@@ -180,8 +249,17 @@ class SIFTExtractor(BatchTransformer):
 
         x_dim, y_dim = jax.tree_util.tree_leaves(dataset.data)[0].shape[1:3]
         return descriptor_span(
-            self, "sift", dataset, sum(self.grid_counts(x_dim, y_dim)), scales=self.scales
+            self, "sift", dataset, sum(self.grid_counts(x_dim, y_dim)),
+            scales=self.scales, binning=self.binning_form(x_dim, y_dim),
         )
+
+    def binning_form(self, x_dim: int, y_dim: int) -> str:
+        """The form the spatial binning takes at this image size, as the
+        ``image:sift`` span says it: ``product``, ``conv`` where the rule
+        keeps the convolution, ``conv+product`` where the scales differ."""
+        bins = (self.bin_size + 2 * s for s in range(self.scales))  # 2b - 1 taps each
+        forms = {"product" if _binning_as_products(x_dim, y_dim, 2 * b - 1) else "conv" for b in bins}
+        return "+".join(sorted(forms))
 
     def apply_arrays(self, x):
         if x.ndim == 4:
@@ -273,8 +351,7 @@ class SIFTExtractor(BatchTransformer):
         planes = jnp.where(inside, planes, 0.0)
 
         planes = jnp.transpose(planes, (0, 3, 1, 2)).reshape(n * NUM_ORIENTATIONS, xd, yd)
-        binned = _separable_conv(planes, _triangular_kernel(b),
-                                 conv_dtype=self.binning_dtype)
+        binned = _spatial_binning(planes, _triangular_kernel(b), self.binning_dtype)
         binned = binned.reshape(n, NUM_ORIENTATIONS, xd, yd)
 
         ox = off + np.arange(nx) * step
@@ -339,8 +416,7 @@ class SIFTExtractor(BatchTransformer):
 
         # Spatial bilinear binning = separable triangular convolution.
         planes = jnp.transpose(planes, (0, 3, 1, 2)).reshape(n * NUM_ORIENTATIONS, xd, yd)
-        binned = _separable_conv(planes, _triangular_kernel(b),
-                                 conv_dtype=self.binning_dtype)
+        binned = _spatial_binning(planes, _triangular_kernel(b), self.binning_dtype)
         binned = binned.reshape(n, NUM_ORIENTATIONS, xd, yd)
 
         # Gather the 4×4 bin centers for every keypoint origin.

@@ -43,10 +43,23 @@ def test_the_fused_sift_prefix_opens_image_sift_with_rows_scales_and_descriptors
     out = pipeline(ArrayDataset(_images())).get()
     assert out.data.shape == (6, 151, 128)
     (span,) = _named(session, "image:sift")
-    assert span.attributes == {"rows": 6, "scales": 4, "descriptors": 151}
+    assert span.attributes == {"rows": 6, "scales": 4, "descriptors": 151, "binning": "product"}
     assert counter.value(extractor="SIFTExtractor") - before == 6 * 151
     (node,) = [s for s in session.spans() if s.name.startswith("node:Fused[")]
     assert span.parent_id == node.span_id  # inside the fused node's span
+
+
+@pytest.mark.parametrize(
+    "side,binning", [(256, "product"), (1000, "conv+product"), (2500, "conv")], ids=["products", "by-scale", "convolutions"]
+)
+def test_image_sift_says_which_form_the_binning_took(session, side, binning):
+    """`binning` (PR 37): the form of the spatial binning at this image
+    size, by the one rule that chooses it (`sift._binning_as_products`)."""
+    extractor = SIFTExtractor()
+    with extractor.host_span(ArrayDataset(np.zeros((2, side, 40), np.float32))):
+        pass
+    (span,) = _named(session, "image:sift")
+    assert span.attributes["binning"] == binning == extractor.binning_form(side, 40)
 
 
 def test_lcs_pca_and_fisher_open_their_spans(session):
