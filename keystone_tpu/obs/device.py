@@ -12,18 +12,28 @@ RSS for HBM.
 the batch-apply path: an ``h2d`` span (in a profiler trace through the
 span layer's bridge, obs/spans.py) and the ``keystone_h2d_*`` counters.
 Its callers are ``BatchTransformer.apply_batch`` (a transformer's own
-input) and the graph executor (a node's output shared by several).
+input), the graph executor (a node's output shared by several) and the
+kernel solver. ``h2d`` closes when the upload is ENQUEUED; while
+somebody is recording, ``watch_transfer`` hands the arrays to the
+process's one watcher thread, whose ``h2d:transfer`` span runs from
+there to the arrival of the last byte (the streamed fold's chunks go
+the same way).
 
 Imports jax lazily; importable before any backend initializes.
 """
 
 from __future__ import annotations
 
+import logging
 import os
+import queue
+import threading
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, Optional
 
 from . import names, spans
+
+logger = logging.getLogger(__name__)
 
 _PAGE_SIZE = os.sysconf("SC_PAGE_SIZE") if hasattr(os, "sysconf") else 4096
 
@@ -36,7 +46,11 @@ def to_device(data: Any, site: str, consumers: int = 1) -> Any:
     on this one copy: 1 where a transformer uploads its own input, k
     where the executor shares a node's output between k of them), and is
     counted in ``keystone_h2d_bytes_total{site}`` /
-    ``keystone_h2d_transfers_total{site}`` (one transfer per leaf)."""
+    ``keystone_h2d_transfers_total{site}`` (one transfer per leaf).
+    While somebody is recording, an upload of ``_WATCH_MIN_BYTES`` or
+    more is also handed to the transfer watcher, whose ``h2d:transfer``
+    span ends when the bytes have arrived (:func:`watch_transfer`);
+    with nobody recording that is one flag check and nothing else."""
     import numpy as np
 
     import jax
@@ -47,14 +61,16 @@ def to_device(data: Any, site: str, consumers: int = 1) -> Any:
     ]
     if not host:
         return data
-    import jax.numpy as jnp
 
     nbytes = sum(leaf.nbytes for leaf in host)
+    sent = [] if watching(nbytes) else None
     with spans.span("h2d", site=site, bytes=nbytes, consumers=consumers):
         out = jax.tree_util.tree_map(
-            lambda leaf: _upload(leaf) if isinstance(leaf, np.ndarray) else leaf,
+            lambda leaf: _upload(leaf, sent) if isinstance(leaf, np.ndarray) else leaf,
             data,
         )
+    if sent is not None:
+        watch_transfer(sent, site, nbytes)
     names.metric(names.H2D_BYTES).inc(nbytes, site=site)
     names.metric(names.H2D_TRANSFERS).inc(len(host), site=site)
     return out
@@ -70,7 +86,17 @@ def to_device(data: Any, site: str, consumers: int = 1) -> Any:
 _NARROW = 128
 
 
-def _upload(leaf):
+#: An upload of fewer bytes than this is never handed to the transfer
+#: watcher. A host batch goes up at some 6 GB/s on the v5e's host (115 MB
+#: in 19 ms: my chip run, PR 33), so 3 MiB are in flight for about the
+#: 0.5 ms under which the benchmark's trace reduction drops a host event
+#: (``benchmark/harness/trace.py::read_profile``): a shorter
+#: ``h2d:transfer`` could not be read, and the serving path's small
+#: batches pay nothing for it, traced or not.
+_WATCH_MIN_BYTES = 3 << 20
+
+
+def _upload(leaf, sent: Optional[list] = None):
     """``jnp.asarray(leaf)``. A batch of three or more dimensions with a
     narrow last one is uploaded flat and reshaped on the device: the
     device keeps such an array with a wider dimension innermost, and
@@ -79,12 +105,92 @@ def _upload(leaf):
     256 x 3 float32: 1.2 million host transposes on four threads a
     request, each an event in a profiler's trace, which filled the 40 GiB
     of the benchmark's host in one traced window; my chip run, PR 36).
-    Flat, the bytes go up as they lie and the device transposes."""
+    Flat, the bytes go up as they lie and the device transposes.
+    ``sent``, where given, collects the array the bytes went up as (the
+    flat one: what the transfer watcher waits for is the copy, not the
+    device's reshape)."""
     import jax.numpy as jnp
 
-    if leaf.ndim >= 3 and leaf.shape[-1] < _NARROW and leaf.size:
-        return jnp.reshape(jnp.asarray(leaf.reshape(leaf.shape[0], -1)), leaf.shape)
-    return jnp.asarray(leaf)
+    narrow = leaf.ndim >= 3 and leaf.shape[-1] < _NARROW and leaf.size
+    up = jnp.asarray(leaf.reshape(leaf.shape[0], -1) if narrow else leaf)
+    if sent is not None:
+        sent.append(up)
+    return jnp.reshape(up, leaf.shape) if narrow else up
+
+
+def watching(nbytes: int) -> bool:
+    """Whether an upload of ``nbytes`` enqueued now is to be handed to
+    :func:`watch_transfer`: it is large enough to be seen and somebody is
+    recording (``spans.recording()``: a session, or a profiler trace in
+    progress). With nobody recording this is all an upload pays."""
+    return nbytes >= _WATCH_MIN_BYTES and spans.recording()
+
+
+def watch_transfer(arrays: Any, site: str, nbytes: int) -> None:
+    """Hand device arrays whose upload was just enqueued (a pytree; the
+    caller has asked :func:`watching`) to the process's transfer watcher
+    and return at once. The watcher, one daemon thread, takes the
+    uploads in the order they were enqueued and for each holds an
+    ``h2d:transfer`` span (``site``, ``bytes``; its parent the span the
+    caller is in, which is its ``h2d`` span's parent) until
+    ``jax.block_until_ready`` says the last byte has arrived. Uploads to
+    a chip arrive in order, so a span starts at the later of its own
+    enqueue and its predecessor's arrival, and the union of the spans is
+    "a host batch is in flight". ``ks:h2d:transfer`` in a profiler
+    trace; a recorded span under a session."""
+    _WATCHER.watch(arrays, site, nbytes, spans.current_context())
+
+
+class _TransferWatcher:
+    """The FIFO of uploads in flight and the one thread that waits for
+    them. The thread starts with the first upload handed over (so never
+    in a process where nobody records), is a daemon, and outlives every
+    session and trace: idle, it waits on the queue."""
+
+    def __init__(self):
+        self._lock = threading.Lock()  # the thread's start, nothing else
+        self._queue: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._thread: Optional[threading.Thread] = None
+
+    def watch(self, arrays, site, nbytes, parent) -> None:
+        if parent is not None and not parent[1]:
+            parent = None  # "the session's root": what the h2d span got
+        self._queue.put((arrays, site, nbytes, parent))
+        if self._thread is None:  # the first upload anybody recorded
+            with self._lock:
+                if self._thread is None:
+                    self._thread = threading.Thread(
+                        target=self._run, name="keystone-h2d-watcher", daemon=True
+                    )
+                    self._thread.start()
+
+    def _run(self) -> None:
+        while True:
+            entry = self._queue.get()
+            try:
+                self._await(*entry)
+            except Exception:
+                # the boundary: nothing in here may reach the uploader or
+                # end the thread
+                logger.warning("h2d:transfer watcher: an entry failed", exc_info=True)
+            entry = None  # hold no upload while waiting for the next
+
+    @staticmethod
+    def _await(arrays, site, nbytes, parent) -> None:
+        import jax
+
+        with spans.span("h2d:transfer", parent=parent, site=site, bytes=nbytes) as record:
+            try:
+                jax.block_until_ready(arrays)
+            except jax.errors.JaxRuntimeError as exc:
+                # freed or donated before its turn came (its consumer ran
+                # and let go of it), or the copy itself failed, which the
+                # caller sees at its own first use
+                gone = "deleted or donated" in str(exc)
+                record.set_attribute("ended", "deleted" if gone else "error")
+
+
+_WATCHER = _TransferWatcher()
 
 
 def rss_bytes() -> int:

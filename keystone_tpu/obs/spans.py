@@ -18,7 +18,9 @@ program's spans into the profiler's ``.xplane.pb``, on the device's
 clock. A session is what records spans into memory (ids, parents,
 attributes, exporters); the profiler sees them with or without one.
 Span names are stable — no ids, addresses or counters — so two runs of
-one program give the same set of names.
+one program give the same set of names. :func:`recording` answers "is
+anybody recording?" (a session, or a profiler trace in progress) for
+instrumentation that costs something of its own when it is on.
 
 Design constraints (the serving 5%-overhead budget):
 
@@ -309,6 +311,21 @@ def _find_annotation():
     except Exception:
         _annotation_cls = False
     return _annotation_cls
+
+
+def recording() -> bool:
+    """Whether anybody would keep a span opened now: a session is
+    installed, or the profiler is taking a trace (the annotation class's
+    own flag: ``TraceAnnotation.is_enabled()``, some tens of nanoseconds,
+    false outside ``jax.profiler.trace(...)``). The one question that
+    instrumentation with a cost of its own asks before it spends it
+    (``obs/device.py``'s transfer watcher); a plain ``span()`` need not."""
+    if _session is not None:
+        return True
+    annotate = _annotation_cls
+    if annotate is None:
+        annotate = _find_annotation()
+    return bool(annotate) and annotate.is_enabled()
 
 
 class _AnnotatedSpan:

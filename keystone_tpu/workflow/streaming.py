@@ -63,6 +63,7 @@ from ..data.dataset import (
     transfer_dtype,
 )
 from ..obs import cost as _cost
+from ..obs import device as _device
 from ..obs import names as _names
 from ..obs import spans as _spans
 from ..obs import store as _store
@@ -867,6 +868,11 @@ class _FoldRun:
         return x, y, mask, rows
 
     def stage(self, chunk):
+        """Enqueue one prepared chunk's upload (never waited for here).
+        While somebody is recording, the chunk's device arrays also go to
+        the transfer watcher, whose ``h2d:transfer`` span
+        (``site="ChunkStream"``) ends when they have arrived
+        (obs/device.py::watch_transfer); otherwise one flag check."""
         import jax
 
         x, y, mask, rows = chunk
@@ -883,6 +889,8 @@ class _FoldRun:
             return jax.device_put(a, sharding)
 
         dev = (jax.tree_util.tree_map(put, x), put(y), put(mask), rows)
+        if _device.watching(nbytes):
+            _device.watch_transfer(dev[:3], "ChunkStream", nbytes)
         report.bytes_transferred += nbytes
         self.bytes_c.inc(nbytes)
         return dev
