@@ -35,29 +35,40 @@ from ..learning.gmm import (
 
 
 @linalg.mode_jit
-def _fisher_encode(x, means, variances, weights, weight_threshold):
+def _fisher_encode(x, means, variances, weights, weight_threshold, valid=None):
     """(N, n_desc, D) descriptors -> (N, D, 2K): the posteriors and the
     two gradients as ONE program, whose operations carry the encoder's
-    name in a device trace (dispatched one by one they carry none)."""
+    name in a device trace (dispatched one by one they carry none).
+
+    (image, descriptor) stay two axes from the first operation to the
+    last: merged into one and split again they are two copies through
+    linear memory, slice by slice, wherever the descriptor count is no
+    multiple of the chip's 128-wide tile (13,165 is none).
+
+    ``valid`` (N, n_desc), where given, marks an image's own descriptors
+    in a padded batch: the others weigh nothing and the statistics divide
+    by each image's count."""
     with jax.named_scope("feat/FisherVector"):
         x = x.astype(jnp.float32)
-        n_desc = x.shape[1]
         means = means.astype(jnp.float32)          # (D, K)
         variances = variances.astype(jnp.float32)  # (D, K)
         weights = weights.astype(jnp.float32)      # (K,)
 
-        flat = x.reshape(-1, x.shape[-1])
-        q = _gmm_posteriors(flat, means.T, variances.T, weights, weight_threshold)
-        q = q.reshape(x.shape[0], n_desc, -1)               # (N, n, K)
+        q = _gmm_posteriors(x, means.T, variances.T, weights, weight_threshold)  # (N, n, K)
+        if valid is None:
+            count = x.shape[1]
+        else:
+            m = jnp.asarray(valid, jnp.float32)                       # (N, n)
+            q = q * m[..., None]
+            count = jnp.maximum(jnp.sum(m, axis=1), 1.0)[:, None, None]
 
-        s0 = jnp.mean(q, axis=1)                            # (N, K)
+        s0b = jnp.sum(q, axis=1, keepdims=True) / count     # (N, 1, K)
         # float32 moments on the chip too (its default rounds the inputs
         # to bfloat16, and fv2 below is a difference of near-equal terms)
         at = linalg.precision()
-        s1 = jnp.einsum("bnd,bnk->bdk", x, q, precision=at) / n_desc      # (N, D, K)
-        s2 = jnp.einsum("bnd,bnk->bdk", x * x, q, precision=at) / n_desc  # (N, D, K)
+        s1 = jnp.einsum("bnd,bnk->bdk", x, q, precision=at) / count      # (N, D, K)
+        s2 = jnp.einsum("bnd,bnk->bdk", x * x, q, precision=at) / count  # (N, D, K)
 
-        s0b = s0[:, None, :]                                # (N, 1, K)
         fv1 = (s1 - means * s0b) / (jnp.sqrt(variances) * jnp.sqrt(weights))
         fv2 = (s2 - 2.0 * means * s1 + (means * means - variances) * s0b) / (
             variances * jnp.sqrt(2.0 * weights)
@@ -96,27 +107,10 @@ class FisherVector(BatchTransformer):
         ``apply_arrays`` on the image's own valid descriptors (the
         reference encodes per-image descriptor sets of varying size,
         FisherVector.scala:33-53)."""
-        x = x.astype(jnp.float32)
-        means = self.gmm.means.astype(jnp.float32)
-        variances = self.gmm.variances.astype(jnp.float32)
-        weights = self.gmm.weights.astype(jnp.float32)
-
-        m = jnp.asarray(valid, jnp.float32)                 # (N, n)
-        count = jnp.maximum(jnp.sum(m, axis=1), 1.0)        # (N,)
-        flat = x.reshape(-1, x.shape[-1])
-        q = self.gmm.apply_arrays(flat).reshape(x.shape[0], x.shape[1], -1)
-        q = q * m[..., None]                                # zero invalid rows
-
-        s0 = jnp.sum(q, axis=1) / count[:, None]
-        s1 = jnp.einsum("bnd,bnk->bdk", x, q) / count[:, None, None]
-        s2 = jnp.einsum("bnd,bnk->bdk", x * x, q) / count[:, None, None]
-
-        s0b = s0[:, None, :]
-        fv1 = (s1 - means * s0b) / (jnp.sqrt(variances) * jnp.sqrt(weights))
-        fv2 = (s2 - 2.0 * means * s1 + (means * means - variances) * s0b) / (
-            variances * jnp.sqrt(2.0 * weights)
+        return _fisher_encode(
+            x, self.gmm.means, self.gmm.variances, self.gmm.weights,
+            jnp.float32(self.gmm.weight_threshold), valid,
         )
-        return jnp.concatenate([fv1, fv2], axis=2)
 
     def apply_batch(self, dataset):
         """Masked-descriptor datasets ({"desc", "valid"}) encode through

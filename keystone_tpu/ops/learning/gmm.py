@@ -86,8 +86,11 @@ class GaussianMixtureModel(BatchTransformer):
 
 @linalg.mode_jit
 def _gmm_log_likelihood(x, means, variances, weights):
-    """Per-sample per-cluster log-likelihood. means/vars here are (k, d)."""
-    d = x.shape[1]
+    """Per-sample per-cluster log-likelihood over the LAST axis of x:
+    (..., d) -> (..., k), the leading axes carried as they are (a batch
+    of images' descriptors is never flattened: no reshape for the
+    compiler to lay out again). means/vars here are (k, d)."""
+    d = x.shape[-1]
     xsq = x * x
     inv_var = 1.0 / variances
     sq_mahal = (
@@ -105,12 +108,13 @@ def _gmm_log_likelihood(x, means, variances, weights):
 
 @linalg.mode_jit
 def _gmm_posteriors(x, means, variances, weights, weight_threshold):
+    """Thresholded posteriors over the centres, (..., d) -> (..., k)."""
     llh = _gmm_log_likelihood(x, means, variances, weights)
-    llh = llh - jnp.max(llh, axis=1, keepdims=True)
+    llh = llh - jnp.max(llh, axis=-1, keepdims=True)
     q = jnp.exp(llh)
-    q = q / jnp.sum(q, axis=1, keepdims=True)
+    q = q / jnp.sum(q, axis=-1, keepdims=True)
     q = jnp.where(q > weight_threshold, q, 0.0)
-    return q / jnp.maximum(jnp.sum(q, axis=1, keepdims=True), 1e-30)
+    return q / jnp.maximum(jnp.sum(q, axis=-1, keepdims=True), 1e-30)
 
 
 class GaussianMixtureModelEstimator(Estimator):

@@ -127,6 +127,165 @@ def test_gmm_fisher_vector_estimator_end_to_end():
     assert np.isfinite(out).all()
 
 
+def _fisher_by_the_docstring(x, means, variances, weights, threshold):
+    """One image's (n, D) descriptors in float64, from the formulas at
+    the head of ``ops/images/fisher.py`` and the thresholded posteriors
+    of ``ops/learning/gmm.py``, a centre at a time. Returns the (D, 2K)
+    encoding and the posteriors before and after the threshold."""
+    n, d = x.shape
+    k = len(weights)
+    llh = np.empty((n, k))
+    for j in range(k):
+        llh[:, j] = (
+            np.log(weights[j])
+            - 0.5 * d * np.log(2 * np.pi)
+            - 0.5 * np.log(variances[:, j]).sum()
+            - 0.5 * (((x - means[:, j]) ** 2) / variances[:, j]).sum(axis=1)
+        )
+    raw = np.exp(llh - llh.max(axis=1, keepdims=True))
+    raw /= raw.sum(axis=1, keepdims=True)
+    q = np.where(raw > threshold, raw, 0.0)
+    q /= q.sum(axis=1, keepdims=True)
+    s0 = q.mean(axis=0)
+    s1 = x.T @ q / n
+    s2 = (x * x).T @ q / n
+    fv1 = (s1 - means * s0) / (np.sqrt(variances) * np.sqrt(weights))
+    fv2 = (s2 - 2 * means * s1 + (means**2 - variances) * s0) / (variances * np.sqrt(2 * weights))
+    return np.concatenate([fv1, fv2], axis=1), raw, q
+
+
+def test_fisher_encode_at_a_descriptor_count_no_tile_divides():
+    """173 descriptors an image (no multiple of 8 or 128: what the chip
+    tiles by) and a threshold that zeroes posteriors, against a float64
+    loop over images."""
+    import jax.numpy as jnp
+
+    from keystone_tpu.ops.images.fisher import _fisher_encode
+
+    gmm = _toy_gmm(d=8, k=5, seed=4)
+    x = (1.5 * np.random.default_rng(5).normal(size=(3, 173, 8))).astype(np.float32)
+    threshold = 0.0517
+    got = np.asarray(_fisher_encode(x, gmm.means, gmm.variances, gmm.weights, jnp.float32(threshold)))
+    assert got.shape == (3, 8, 10)
+
+    means, variances, weights = (
+        np.asarray(a, np.float64) for a in (gmm.means, gmm.variances, gmm.weights)
+    )
+    zeroed = 0
+    for i in range(3):
+        want, raw, q = _fisher_by_the_docstring(
+            x[i].astype(np.float64), means, variances, weights, threshold
+        )
+        # no posterior so near the threshold that float32 could fall on its other side
+        assert np.abs(raw - threshold).min() > 1e-5
+        zeroed += int(((raw > 0) & (q == 0)).sum())
+        np.testing.assert_allclose(got[i], want, rtol=1e-4, atol=1e-5)
+    assert zeroed > 50  # the threshold did something
+
+
+def _log_likelihood_over_axis_one(x, means, variances, weights):
+    """``gmm._gmm_log_likelihood`` as it stood while it took
+    two-dimensional input only (before PR 39), written out: the width
+    from ``shape[1]``."""
+    import jax.numpy as jnp
+
+    from keystone_tpu.parallel import linalg
+
+    d = x.shape[1]
+    xsq = x * x
+    inv_var = 1.0 / variances
+    sq_mahal = (
+        linalg.mm(xsq, (0.5 * inv_var).T)
+        - linalg.mm(x, (means * inv_var).T)
+        + 0.5 * jnp.sum(means * means * inv_var, axis=1)
+    )
+    log_norm = (
+        -0.5 * d * jnp.log(2 * jnp.pi)
+        - 0.5 * jnp.sum(jnp.log(variances), axis=1)
+        + jnp.log(weights)
+    )
+    return log_norm - sq_mahal
+
+
+def _posteriors_over_axis_one(x, means, variances, weights, weight_threshold):
+    """``gmm._gmm_posteriors`` of the same time: the reductions over the
+    centres at ``axis=1``."""
+    import jax.numpy as jnp
+
+    llh = _log_likelihood_over_axis_one(x, means, variances, weights)
+    llh = llh - jnp.max(llh, axis=1, keepdims=True)
+    q = jnp.exp(llh)
+    q = q / jnp.sum(q, axis=1, keepdims=True)
+    q = jnp.where(q > weight_threshold, q, 0.0)
+    return q / jnp.maximum(jnp.sum(q, axis=1, keepdims=True), 1e-30)
+
+
+def _posterior_case(seed=6, shape=(3, 173, 8), k=5, threshold=0.05):
+    """A mixture, descriptors of `shape`, and the mixture as the helpers
+    take it: (means (k, d), variances (k, d), weights, threshold)."""
+    import jax.numpy as jnp
+
+    gmm = _toy_gmm(d=shape[-1], k=k, seed=seed)
+    gmm.weight_threshold = threshold
+    x = (1.5 * np.random.default_rng(seed + 1).normal(size=shape)).astype(np.float32)
+    params = (
+        jnp.asarray(gmm.means, jnp.float32).T, jnp.asarray(gmm.variances, jnp.float32).T,
+        jnp.asarray(gmm.weights, jnp.float32), jnp.float32(threshold),
+    )
+    return gmm, x, params
+
+
+def test_gmm_posteriors_of_a_batch_are_its_rows_posteriors():
+    """(N, n, D) -> (N, n, K) with no flat intermediate, equal to the
+    same rows flattened."""
+    from keystone_tpu.ops.learning.gmm import _gmm_posteriors
+
+    _, x, params = _posterior_case()
+    batch = np.asarray(_gmm_posteriors(x, *params))
+    rows = np.asarray(_gmm_posteriors(x.reshape(-1, x.shape[-1]), *params))
+    assert batch.shape == (3, 173, 5)
+    assert (rows == 0).any() and np.allclose(batch.sum(axis=-1), 1.0, atol=1e-6)
+    np.testing.assert_allclose(batch.reshape(rows.shape), rows, rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("what", ["posteriors", "log_likelihood", "apply_arrays", "em"])
+def test_gmm_on_two_dimensional_input_is_the_program_it_was(what, monkeypatch):
+    """The helpers work over the LAST axis since PR 39; for (n, D) input
+    that is axis 1, and everything that hands them such input
+    (``GaussianMixtureModel.apply_arrays``, the EM loop: the flagship's
+    set-up fit) comes out to the bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.ops.learning import gmm as gmm_module
+
+    model, x, params = _posterior_case(shape=(519, 8))
+    if what == "posteriors":
+        got = gmm_module._gmm_posteriors(x, *params)
+        want = jax.jit(_posteriors_over_axis_one)(x, *params)
+    elif what == "log_likelihood":
+        got = gmm_module._gmm_log_likelihood(x, *params[:3])
+        want = jax.jit(_log_likelihood_over_axis_one)(x, *params[:3])
+    elif what == "apply_arrays":
+        got = model.apply_arrays(x)
+        want = jax.jit(_posteriors_over_axis_one)(x, *params)
+    else:
+        def em():
+            # a fresh callable a call: `jax.jit(f)` twice over one f shares
+            # one trace, and the second call would replay the first's
+            return jax.jit(lambda *a: gmm_module._gmm_em.__wrapped__(*a[:5], 7, *a[5:]))(
+                jnp.asarray(x), *params[:3], jnp.full((x.shape[1],), 1e-3, jnp.float32),
+                jnp.float32(1e-6), params[3], jnp.float32(2.0),
+            )
+
+        got = em()
+        assert int(got[4]) >= 2  # the loop updated the mixture, and again
+        monkeypatch.setattr(gmm_module, "_gmm_log_likelihood", _log_likelihood_over_axis_one)
+        want = em()
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want), strict=True):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 # ----------------------------------------------------------------------- LCS
 
 

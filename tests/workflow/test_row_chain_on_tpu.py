@@ -43,9 +43,13 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _memory(fn, one_chip, *shapes):
+def _compiled(fn, one_chip, *shapes):
     specs = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for s in shapes]
-    analysis = jax.jit(fn).lower(*specs).compile().memory_analysis()
+    return jax.jit(fn).lower(*specs).compile()
+
+
+def _memory(fn, one_chip, *shapes):
+    analysis = _compiled(fn, one_chip, *shapes).memory_analysis()
     return analysis.argument_size_in_bytes, analysis.output_size_in_bytes, analysis.temp_size_in_bytes
 
 
@@ -87,12 +91,49 @@ def test_lcs_at_the_fits_2048_images_fits_the_chip_whole(one_chip):
     assert args + out + temp + pca_out < estimate < 15.75 * GIB
 
 
-def test_the_fisher_encoding_of_a_request_needs_less_than_the_sift_before_it(one_chip):
+def _encode(x, means, variances, weights):
     from keystone_tpu.ops.images.fisher import _fisher_encode
 
-    def encode(x, means, variances, weights):
-        return _fisher_encode.__wrapped__(x, means, variances, weights, jnp.float32(1e-4))
+    return _fisher_encode.__wrapped__(x, means, variances, weights, jnp.float32(1e-4))
 
-    args, out, temp = _memory(encode, one_chip, (256, 13165, 64), (64, 16), (64, 16), (16,))
+
+_MIXTURE = ((64, 16), (64, 16), (16,))  # means, variances, weights: 16 Gaussians of 64
+
+
+def test_the_fisher_encoding_of_a_request_needs_less_than_the_sift_before_it(one_chip):
+    args, out, temp = _memory(_encode, one_chip, (256, 13165, 64), *_MIXTURE)
     assert out == 256 * 64 * 32 * 4
     assert temp < executor.TEMPORARIES * 256 * 13165 * 128 * 4 / 2
+
+
+def _copies_through_linear_memory(compiled) -> dict:
+    """How often the compiled program loops or writes a buffer slice by
+    slice: what a reshape that is no bitcast under the chip's tiling
+    turns into (256 images x 13,165 descriptors merged into one axis
+    are four `while` loops and 84 `dynamic-update-slice`s, 45 ms a
+    request on the chip: PERF.md section 6, PR 39)."""
+    text = compiled.as_text()
+    return {op: text.count(f" {op}(") for op in ("while", "dynamic-update-slice")}
+
+
+@pytest.mark.parametrize("descriptors", [13165, 3136], ids=["sift", "lcs"])
+def test_the_fisher_encoder_never_merges_images_with_descriptors(one_chip, descriptors):
+    compiled = _compiled(_encode, one_chip, (256, descriptors, 64), *_MIXTURE)
+    assert _copies_through_linear_memory(compiled) == {"while": 0, "dynamic-update-slice": 0}
+    if descriptors == 13165:
+        # 2.01 GiB with the two reshapes and their linear buffers, 1.81 without
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.9 * GIB
+
+
+def test_the_fisher_encoder_behind_its_projection_is_one_program_without_a_loop(one_chip):
+    """As the request's fused chain has it: the signed root, the PCA
+    projection, the encoder."""
+    from keystone_tpu.ops.learning.pca import _project_stack
+    from keystone_tpu.ops.stats.core import SignedHellingerMapper
+
+    def chain(x, basis, *mixture):
+        x = SignedHellingerMapper().apply_arrays(x)
+        return _encode(_project_stack.__wrapped__(x, basis), *mixture)
+
+    compiled = _compiled(chain, one_chip, (256, 13165, 128), (128, 64), *_MIXTURE)
+    assert _copies_through_linear_memory(compiled) == {"while": 0, "dynamic-update-slice": 0}
