@@ -40,7 +40,7 @@ from ...parallel.collectives import shard_map
 from ...parallel.mesh import get_mesh, row_axes, row_shard_count
 from ...parallel.partitioner import fit_mesh
 from ...workflow.pipeline import BatchTransformer, LabelEstimator
-from ..images.core import FusedConvFeaturizer
+from ..images.core import FusedConvFeaturizer, _norm_stats, _pack_filters, _pooled_block
 from ..stats.core import _as_array_dataset
 from .block import BlockLinearMapper
 
@@ -183,7 +183,7 @@ class ConvBlockLeastSquaresEstimator(LabelEstimator):
         f_pad = nb * fb
 
         # Shared packing with the featurizer, at the solver's block width.
-        kblocks, fsum_blocks, offset_blocks = fz.packed_filter_blocks(fb)
+        kblocks, fsum_blocks, offset_blocks = _pack_filters(conv.kernel, conv.filter_sums, conv.offset, fb)
 
         # Row-shard images/labels; chunk size must divide the per-shard rows.
         ndev = row_shard_count(mesh)
@@ -281,10 +281,10 @@ def _conv_bcd_step_fn(
         xr = x_local.reshape((nloc // chunk, chunk) + x_local.shape[1:])
 
         def per_chunk(xc):
-            # Shared featurizer math (FusedConvFeaturizer.block_pooled) —
+            # Shared featurizer math (images.core._pooled_block) —
             # the solver computes exactly what the featurizer computes.
-            m, sd = featurizer.norm_stats(xc)
-            pooled = featurizer.block_pooled(xc, kb, fs_b, off_b, m, sd)
+            m, sd = _norm_stats(featurizer.spec, xc)
+            pooled = _pooled_block(featurizer.spec, xc, kb, fs_b, off_b, m, sd)
             return jnp.transpose(pooled, (0, 2, 1, 3)).reshape(chunk, bs)
 
         return lax.map(per_chunk, xr).reshape(nloc, bs)

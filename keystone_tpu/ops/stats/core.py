@@ -20,6 +20,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ...data.dataset import ArrayDataset, Dataset, default_ingest_workers
@@ -193,10 +194,25 @@ class StandardScalerModel(BatchTransformer):
         self.std = None if std is None else jnp.asarray(std)
 
     def apply_arrays(self, x):
-        out = x - self.mean
-        if self.std is not None:
-            out = out / self.std
-        return out
+        return _standardised(x, self.mean, self.std)
+
+
+# The scaler's passes over x each run as ONE program, so that no (rows,
+# columns) temporary is allocated beside x and the result: eagerly, the
+# moments made `x * mask` twice and its square, and the model `x - mean`
+# before the division, each as large as x (2.6 GB at CIFAR's 8,192 x
+# 80,000), and a host running ahead of the device holds them all at once.
+@jax.jit
+def _masked_moments(x, mask):
+    """Column sums of the masked rows and of their squares."""
+    xm = x * mask
+    return jnp.sum(xm, axis=0), jnp.sum(xm * xm, axis=0)
+
+
+@jax.jit
+def _standardised(x, mean, std):
+    out = x - mean
+    return out if std is None else out / std
 
 
 class StandardScaler(Estimator):
@@ -221,11 +237,10 @@ class StandardScaler(Estimator):
         x = ds.data
         n = ds.num_examples
         mask = ds.mask().reshape((-1,) + (1,) * (x.ndim - 1))
-        s1 = jnp.sum(x * mask, axis=0)
+        s1, s2 = _masked_moments(x, mask)
         mean = s1 / n
         if not self.normalize_std_dev:
             return StandardScalerModel(mean, None)
-        s2 = jnp.sum((x * mask) ** 2, axis=0)
         var = (s2 - n * mean**2) / max(n - 1, 1)
         std = jnp.sqrt(jnp.maximum(var, 0.0))
         std = jnp.where(
@@ -253,7 +268,8 @@ class Sampler(Transformer):
         if isinstance(dataset, ArrayDataset):
             import jax
 
-            data = jax.tree_util.tree_map(lambda a: jnp.asarray(a)[idx], dataset.data)
+            # a host batch is sampled on the host, a device batch on the device
+            data = jax.tree_util.tree_map(lambda a: a[idx], dataset.data)
             return ArrayDataset(data, num_examples=take)
         items = dataset.collect()
         return type(dataset)([items[i] for i in idx])

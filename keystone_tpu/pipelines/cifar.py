@@ -26,6 +26,7 @@ import numpy as np
 from ..data.dataset import ArrayDataset
 from ..data.loaders.cifar import load_cifar
 from ..evaluation.multiclass import MulticlassClassifierEvaluator
+from ..obs import spans
 from ..ops.images import (
     Convolver,
     FusedConvFeaturizer,
@@ -101,19 +102,21 @@ def _load(config_location: str, sample_frac: Optional[float], seed: int) -> Arra
 def normalize_rows(mat: np.ndarray, alpha: float = 1.0) -> np.ndarray:
     """Row mean/variance normalization (reference: utils/Stats.scala:112-124)."""
     means = np.nan_to_num(mat.mean(axis=1, keepdims=True))
-    var = ((mat - means) ** 2).sum(axis=1, keepdims=True) / (mat.shape[1] - 1)
+    centred = mat - means
+    var = np.square(centred).sum(axis=1, keepdims=True) / (mat.shape[1] - 1)
     sds = np.sqrt(var + alpha)
     sds[np.isnan(sds)] = np.sqrt(alpha)
-    return (mat - means) / sds
+    centred /= sds
+    return centred
 
 
-def learn_random_patch_filters(
+def sample_random_patches(
     train_images: ArrayDataset, config: RandomCifarConfig, whitener_size: int = 100000
-) -> tuple[np.ndarray, ZCAWhitener]:
-    """Sampled-patch filter bank + ZCA whitener
-    (reference: RandomPatchCifar.scala:45-57): windows → vectorize →
-    sample → row-normalize → fit ZCA → sample numFilters rows → whiten,
-    L2-row-normalize, multiply by Wᵀ."""
+) -> np.ndarray:
+    """The (whitener_size, s*s*c) float64 row-normalised patches that the
+    whitener is fitted on and the filters are drawn from
+    (reference: RandomPatchCifar.scala:45-52): windows → sample →
+    vectorize → row-normalize. The same rows for the same images and seed."""
     # Subsample images before windowing: at full CIFAR scale all windows of
     # all images is ~36M patches (~16 GB) of which the Sampler keeps 100k —
     # the reference streams this through an RDD, here we bound it up front.
@@ -128,26 +131,40 @@ def learn_random_patch_filters(
         )
         train_images = ArrayDataset(np.asarray(train_images.data)[idx])
 
+    # sampled before they are vectorized (a row-by-row map: the same rows),
+    # so that only the sample goes to the device
     patch_pipe = (
         Windower(config.patch_steps, config.patch_size)
         .to_pipeline()
-        .then(ImageVectorizer())
         .then(Sampler(whitener_size, seed=config.seed))
+        .then(ImageVectorizer())
     )
     base_filters = patch_pipe(train_images).get()
-    base_mat = normalize_rows(np.asarray(base_filters.data, dtype=np.float64), 10.0)
-    whitener = ZCAWhitenerEstimator(eps=config.whitening_epsilon).fit_single(
-        base_mat.astype(np.float32)
-    )
-    rng = np.random.default_rng(config.seed)
-    idx = rng.choice(base_mat.shape[0], size=min(config.num_filters, base_mat.shape[0]), replace=False)
-    sample_filters = base_mat[idx]
-    w = np.asarray(whitener.whitener, dtype=np.float64)
-    mu = np.asarray(whitener.means, dtype=np.float64)
-    unnorm = (sample_filters - mu) @ w
-    two_norms = np.sqrt((unnorm**2).sum(axis=1, keepdims=True))
-    filters = (unnorm / (two_norms + 1e-10)) @ w.T
-    return filters.astype(np.float32), whitener
+    return normalize_rows(np.asarray(base_filters.data, dtype=np.float64), 10.0)
+
+
+def learn_random_patch_filters(
+    train_images: ArrayDataset, config: RandomCifarConfig, whitener_size: int = 100000
+) -> tuple[np.ndarray, ZCAWhitener]:
+    """Sampled-patch filter bank + ZCA whitener
+    (reference: RandomPatchCifar.scala:45-57): :func:`sample_random_patches`
+    → fit ZCA → sample numFilters rows → whiten, L2-row-normalize,
+    multiply by Wᵀ."""
+    dim = config.patch_size ** 2 * np.asarray(train_images.data).shape[-1]
+    with spans.span("build:filters", patches=whitener_size, filters=config.num_filters, dim=dim):
+        base_mat = sample_random_patches(train_images, config, whitener_size)
+        whitener = ZCAWhitenerEstimator(eps=config.whitening_epsilon).fit_single(
+            base_mat.astype(np.float32)
+        )
+        rng = np.random.default_rng(config.seed)
+        idx = rng.choice(base_mat.shape[0], size=min(config.num_filters, base_mat.shape[0]), replace=False)
+        sample_filters = base_mat[idx]
+        w = np.asarray(whitener.whitener, dtype=np.float64)
+        mu = np.asarray(whitener.means, dtype=np.float64)
+        unnorm = (sample_filters - mu) @ w
+        two_norms = np.sqrt((unnorm**2).sum(axis=1, keepdims=True))
+        filters = (unnorm / (two_norms + 1e-10)) @ w.T
+        return filters.astype(np.float32), whitener
 
 
 def build_linear_pixels(train: ArrayDataset) -> Pipeline:
