@@ -92,6 +92,7 @@ IMAGE_DESCRIPTORS = "keystone_image_descriptors_total"
 EXEC_CHUNKS = "keystone_exec_chunks_total"
 CONV_PANELS = "keystone_conv_panels_total"
 CONV_PANEL_BYTES = "keystone_conv_panel_bytes"
+CONV_KERNEL_PANELS = "keystone_conv_kernel_panels_total"
 
 # ---------------------------------------------------------------- sketch tier
 SKETCH_FITS = "keystone_sketch_fits_total"
@@ -274,6 +275,7 @@ SCHEMA: Dict[str, Tuple] = {
     IMAGE_DESCRIPTORS: ("counter", "Descriptors computed by the dense image extractors' batch applications (images x descriptors an image: 13,165 for dense SIFT at 256 x 256, 3,136 for LCS), by extractor class", ("extractor",)),
     EXEC_CHUNKS: ("counter", "Row chunks the graph executor ran a chain of row-by-row transformers over because the chain would not fit the device whole (workflow/executor.py _RowChain), by the reason for splitting; a chain that runs whole counts nothing", ("reason",)),
     CONV_PANELS: ("counter", "Convolution panels the fused convolution featurizer computes (ops/images/core.py FusedConvFeaturizer: row blocks x filter blocks a batch application, one (row_block, rx, ry, filter_block) float32 block of responses each), by site", ("site",)),
+    CONV_KERNEL_PANELS: ("counter", "Of keystone_conv_panels_total, the panels the Pallas kernel computed (ops/pallas/conv_pool.py: responses normalised, rectified and pooled in VMEM, only the pooled sums written), by site; the rest went through XLA's form (a CPU, or a spec the kernel does not express)", ("site",)),
     CONV_PANEL_BYTES: ("gauge", "Bytes of the one convolution panel that is live during the last batch application of the fused convolution featurizer (row_block x rx x ry x filter_block x 4): the panel is bounded in rows and filters, whatever the batch", ("site",)),
     GRAM_SYMMETRIC: ("counter", "Fits whose Gram products come from linalg.gram_sym (one count a streamed Gram fold, one a block_coordinate_descent call), by the column panels its width rule cuts the product into: the upper block triangle is computed and mirrored; 1 = the single full product", ("panels",)),
     SKETCH_FITS: ("counter", "Sketched least-squares fits completed, by sketch variant (countsketch/srht)", ("variant",)),

@@ -183,10 +183,12 @@ class Convolver(BatchTransformer):
 #: the featurizer's scan holds at a time. Its rows are the largest power of
 #: two whose panel fits this share (512 rows of 27 x 27 x 512 at CIFAR's
 #: widths on a 16 GB v5e: 0.76 GB, where 8,192 rows whole were 12.2 GB and
-#: did not fit beside the fit's features, PERF.md section 6, PR 40). The
-#: compiler's temporaries are a few panels (the normalised and rectified
-#: responses, the pooled outputs), so a sixteenth leaves the rest of the
-#: chip to the features, the standardised copy and the solver.
+#: did not fit beside the fit's features, PERF.md section 6). In
+#: XLA's form the compiler's temporaries are a few panels (the normalised
+#: and rectified responses, the pooled outputs), so a sixteenth leaves the
+#: rest of the chip to the features, the standardised copy and the solver.
+#: In the kernel's form no panel exists: the row block bounds the patches
+#: (113 MB at 512 rows) and the block's pooled cells (168 MB).
 PANEL_SHARE = 1.0 / 16.0
 
 
@@ -240,10 +242,11 @@ def _norm_stats(spec: _ConvSpec, x):
     return _patch_stats(x, spec.conv_size, spec.channels, spec.var_constant)
 
 
-def _pooled_block(spec: _ConvSpec, x, kb, fs_b, off_b, m, sd):
-    """conv → normalize → rectify → pool for ONE filter block: (N, px, py,
-    2·fb). The main convolution at the MXU default, as shipped (bfloat16
-    inputs, float32 sums)."""
+def _pooled_xla(spec: _ConvSpec, x, kb, fs_b, off_b, m, sd):
+    """conv → normalize → rectify → pool for ONE filter block in XLA's
+    form: (N, px, py, 2·fb). The main convolution at the MXU default, as
+    shipped (bfloat16 inputs, float32 sums); its (N, rx, ry, fb) panel is
+    written to HBM and read back by the pooling."""
     with jax.named_scope("conv/panel"):
         raw = lax.conv_general_dilated(
             x, kb, (1, 1), "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"),
@@ -255,6 +258,83 @@ def _pooled_block(spec: _ConvSpec, x, kb, fs_b, off_b, m, sd):
         neg = jnp.maximum(spec.max_val, -out - spec.alpha)
         pool = Pooler(spec.pool_stride, spec.pool_size, spec.pixel_function, spec.pool_function)
         return jnp.concatenate([pool.apply_arrays(pos), pool.apply_arrays(neg)], axis=-1)
+
+
+def _kernel_layout(spec: _ConvSpec, x_dim: int, y_dim: int):
+    """(rx, ry, yp, kp) of the kernel's operands: the responses' extent,
+    y padded to bfloat16's 16 sublanes, and the patch to 128 lanes."""
+    s = spec.conv_size
+    rx, ry = x_dim - s + 1, y_dim - s + 1
+    return rx, ry, -(-ry // 16) * 16, -(-(s * s * spec.channels) // 128) * 128
+
+
+def _conv_form(spec: _ConvSpec, x_dim: int, y_dim: int) -> str:
+    """Which form computes the featurizer's panels for images of x_dim x
+    y_dim: ``"kernel"`` (``ops/pallas/conv_pool.py``: the responses
+    normalised, rectified and pooled in VMEM, only the pooled sums
+    written) on a TPU backend, for every spec the kernel expresses (no
+    pixel function; sum or max pooling, with or without normalisation)
+    whose grid step fits the chip's VMEM; ``"xla"`` everywhere else.
+    Decided at trace time, from the backend and the widths alone."""
+    if jax.default_backend() != "tpu" or spec.pixel_function is not None:
+        return "xla"
+    from ..pallas import conv_pool  # Pallas takes a second to import: only where it runs
+
+    rx, _, yp, kp = _kernel_layout(spec, x_dim, y_dim)
+    return "kernel" if conv_pool.fits(rx, yp, kp, spec.num_filters, conv_pool.vmem_bytes()) else "xla"
+
+
+def _pooled_kernel(spec: _ConvSpec, x, kernel, fsums, offset, m, sd, interpret: bool = False):
+    """conv → normalize → rectify → pool for all of `kernel`'s F filters
+    in the kernel's form, vectorised as the featurizer orders them: (N,
+    py·px·2·F). The patches are made here from the images rounded to
+    bfloat16, as the convolution at the MXU default rounds them, by a
+    convolution with a one-hot filter bank (each output channel one
+    element of the patch, exactly). `interpret` runs the kernel in the
+    Pallas interpreter: the CPU's parity tests call this function with
+    it."""
+    from ..pallas import conv_pool
+
+    n, x_dim, y_dim, c = x.shape
+    s, f = spec.conv_size, kernel.shape[-1]
+    rx, ry, yp, kp = _kernel_layout(spec, x_dim, y_dim)
+    geometry = conv_pool.regions(rx, ry, spec.pool_stride, spec.pool_size)
+    n_pad = -(-n // conv_pool.ROW_TILE) * conv_pool.ROW_TILE
+    tile = conv_pool.filter_tile(f)
+    f_pad = -(-f // tile) * tile
+    with jax.named_scope("conv/panel"):
+        # y padded so the valid convolution gives yp positions; the ones past ry are never pooled
+        xb = jnp.pad(x.astype(jnp.bfloat16), ((0, n_pad - n), (0, 0), (0, yp - ry), (0, 0)))
+        pick = jnp.eye(kp, dtype=jnp.bfloat16)[: s * s * c].reshape(s, s, c, kp)
+        patches = lax.conv_general_dilated(xb, pick, (1, 1), "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        if m is None:  # (raw - 0 · Σf) · 1 is raw, to the bit
+            m, inv = jnp.zeros((n, rx, ry, 1), jnp.float32), jnp.ones((n, rx, ry, 1), jnp.float32)
+        else:
+            inv = 1.0 / sd
+        stats = jnp.swapaxes(jnp.stack([m[..., 0], inv[..., 0]], axis=1), 2, 3)  # (n, 2, ry, rx)
+        stats = jnp.pad(stats, ((0, n_pad - n), (0, 0), (0, yp - ry), (0, -(-rx // 128) * 128 - rx)))
+        weights = jnp.pad(kernel.reshape(-1, f), ((0, kp - s * s * c), (0, f_pad - f)))
+        if offset is None:
+            offset = jnp.zeros((f,), jnp.float32)
+        pooled = conv_pool.conv_pool(
+            patches.reshape(n_pad, rx * yp, kp), stats, weights.astype(jnp.bfloat16),
+            jnp.pad(fsums, (0, f_pad - f)), jnp.pad(offset, (0, f_pad - f)),
+            geometry=geometry, yp=yp, max_val=spec.max_val, alpha=spec.alpha,
+            pool=spec.pool_function, interpret=interpret,
+        )
+    with jax.named_scope("conv/pool"):
+        return jnp.concatenate([cell[:n, :f] for cell in pooled], axis=1)
+
+
+def _pooled_block(spec: _ConvSpec, x, kb, fs_b, off_b, m, sd):
+    """conv → normalize → rectify → pool for ONE filter block in the form
+    :func:`_conv_form` picks, vectorised as the featurizer orders it: (N,
+    py·px·2·fb), pooled positives of the block's filters then its
+    negatives at each pooling cell."""
+    if _conv_form(spec, *x.shape[1:3]) == "xla":
+        pooled = _pooled_xla(spec, x, kb, fs_b, off_b, m, sd)
+        return jnp.transpose(pooled, (0, 2, 1, 3)).reshape(x.shape[0], -1)
+    return _pooled_kernel(spec, x, kb, fs_b, off_b, m, sd)
 
 
 def _pack_filters(kernel, fsums, offset, fb: int):
@@ -274,34 +354,46 @@ def _pack_filters(kernel, fsums, offset, fb: int):
     return kblocks, fsums.reshape(nb, fb), offset.reshape(nb, fb)
 
 
+def _scanned_blocks(spec: _ConvSpec, x, kernel, fsums, offset, m, sd):
+    """XLA's form of one row block: a scan over blocks of filters, then
+    (rows, py·px·2F) in global filter order."""
+    f = spec.num_filters
+    fb = min(spec.filter_block, f)
+    kblocks, fsum_blocks, offset_blocks = _pack_filters(kernel, fsums, offset, fb)
+    f_pad = kblocks.shape[0] * fb
+
+    def block_step(_, inputs):
+        kb, fs_b, off_b = inputs
+        pooled = _pooled_xla(spec, x, kb, fs_b, off_b, m, sd)
+        return _, (pooled[..., :fb], pooled[..., fb:])
+
+    _, (pp, pn) = lax.scan(block_step, None, (kblocks, fsum_blocks, offset_blocks))
+    # (nb, rows, px, py, fb) → (rows, px, py, nb·fb) in global filter order.
+    r, px, py = pp.shape[1:4]
+    pp = jnp.moveaxis(pp, 0, 3).reshape(r, px, py, f_pad)[..., :f]
+    pn = jnp.moveaxis(pn, 0, 3).reshape(r, px, py, f_pad)[..., :f]
+    pooled = jnp.concatenate([pp, pn], axis=-1)
+    return jnp.transpose(pooled, (0, 2, 1, 3)).reshape(r, -1)
+
+
 @partial(jax.jit, static_argnames=("spec", "row_block"))
 def _featurize(x, kernel, fsums, offset, spec: _ConvSpec, row_block: int):
     """The featurizer's one program: over blocks of `row_block` rows (one
-    step where the batch is no larger), a scan over blocks of filters; at
-    most one (row_block, rx, ry, filter_block) panel is live."""
+    step where the batch is no larger), in the form :func:`_conv_form`
+    picks: the kernel over every filter, or XLA's scan over blocks of
+    filters with at most one (row_block, rx, ry, filter_block) panel
+    live."""
     with jax.named_scope("feat/FusedConvFeaturizer"):
         x = x.astype(jnp.float32)
-        n, f = x.shape[0], spec.num_filters
-        fb = min(spec.filter_block, f)
-        kblocks, fsum_blocks, offset_blocks = _pack_filters(kernel, fsums, offset, fb)
-        f_pad = kblocks.shape[0] * fb
+        n = x.shape[0]
+        form = _conv_form(spec, *x.shape[1:3])
 
         def rows(xr):
             with jax.named_scope("conv/stats"):
                 m, sd = _norm_stats(spec, xr)
-
-            def block_step(_, inputs):
-                kb, fs_b, off_b = inputs
-                pooled = _pooled_block(spec, xr, kb, fs_b, off_b, m, sd)
-                return _, (pooled[..., :fb], pooled[..., fb:])
-
-            _, (pp, pn) = lax.scan(block_step, None, (kblocks, fsum_blocks, offset_blocks))
-            # (nb, rows, px, py, fb) → (rows, px, py, nb·fb) in global filter order.
-            r, px, py = pp.shape[1:4]
-            pp = jnp.moveaxis(pp, 0, 3).reshape(r, px, py, f_pad)[..., :f]
-            pn = jnp.moveaxis(pn, 0, 3).reshape(r, px, py, f_pad)[..., :f]
-            pooled = jnp.concatenate([pp, pn], axis=-1)
-            return jnp.transpose(pooled, (0, 2, 1, 3)).reshape(r, -1)
+            if form == "xla":
+                return _scanned_blocks(spec, xr, kernel, fsums, offset, m, sd)
+            return _pooled_kernel(spec, xr, kernel, fsums, offset, m, sd)
 
         if row_block >= n:
             return rows(x)
@@ -384,18 +476,24 @@ class FusedConvFeaturizer(BatchTransformer):
 
     def host_span(self, dataset):
         """``image:conv`` around the batch application that holds this
-        featurizer (its own, or the fused chain's it heads), and its
+        featurizer (its own, or the fused chain's it heads), with the
+        ``form`` that computes its panels (:func:`_conv_form`), and its
         panels in ``keystone_conv_panels_total`` /
-        ``keystone_conv_panel_bytes``."""
+        ``keystone_conv_panel_bytes``, and in
+        ``keystone_conv_kernel_panels_total`` where the kernel computes
+        them."""
         rows, x_dim, y_dim = jax.tree_util.tree_leaves(dataset.data)[0].shape[:3]
         held = self.panels(rows, x_dim, y_dim)
+        form = _conv_form(self.spec, x_dim, y_dim)
         site = type(self).__name__
         _names.metric(_names.CONV_PANELS).inc(held["panels"], site=site)
+        if form == "kernel":
+            _names.metric(_names.CONV_KERNEL_PANELS).inc(held["panels"], site=site)
         _names.metric(_names.CONV_PANEL_BYTES).set(held["panel_bytes"], site=site)
         return _spans.span(
             "image:conv", rows=dataset.num_examples, filters=self.conv.num_filters,
             row_block=held["row_block"], filter_block=min(self.filter_block, self.conv.num_filters),
-            panels=held["panels"],
+            panels=held["panels"], form=form,
         )
 
     def apply_arrays(self, x):

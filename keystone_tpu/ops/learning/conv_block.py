@@ -281,11 +281,11 @@ def _conv_bcd_step_fn(
         xr = x_local.reshape((nloc // chunk, chunk) + x_local.shape[1:])
 
         def per_chunk(xc):
-            # Shared featurizer math (images.core._pooled_block) —
-            # the solver computes exactly what the featurizer computes.
+            # Shared featurizer math (images.core._pooled_block), in the
+            # featurizer's form: the solver computes exactly what the
+            # featurizer computes.
             m, sd = _norm_stats(featurizer.spec, xc)
-            pooled = _pooled_block(featurizer.spec, xc, kb, fs_b, off_b, m, sd)
-            return jnp.transpose(pooled, (0, 2, 1, 3)).reshape(chunk, bs)
+            return _pooled_block(featurizer.spec, xc, kb, fs_b, off_b, m, sd)
 
         return lax.map(per_chunk, xr).reshape(nloc, bs)
 

@@ -1,7 +1,8 @@
 """ZCA whitening.
 
 TPU-native re-design of reference: nodes/learning/ZCAWhitener.scala:12-77.
-Fit: SVD of the centered patch matrix; whitener = V·diag((s²/(n−1)+ε)^-½)·Vᵀ.
+Fit: the eigendecomposition C = V·diag(λ)·Vᵀ of the patches' covariance,
+whitener = V·diag((λ+ε)^-½)·Vᵀ, in float64 on the host (``_zca_fit``).
 Apply: (M − μ) · W for per-item patch matrices — one batched matmul when
 items are uniformly shaped.
 """
@@ -50,24 +51,33 @@ class ZCAWhitenerEstimator(Estimator):
 
     def fit(self, data: Dataset) -> ZCAWhitener:
         if isinstance(data, ArrayDataset):
-            mat = jnp.asarray(data.data, dtype=jnp.float32)[: data.num_examples]
+            mat = np.asarray(data.data)[: data.num_examples]
             if mat.ndim == 3:  # dataset of matrices: use the first, like the reference
                 mat = mat[0]
         else:
-            mat = jnp.asarray(np.asarray(data.take(1)[0]), dtype=jnp.float32)
+            mat = np.asarray(data.take(1)[0])
         return self.fit_single(mat)
 
-    def fit_single(self, mat: jnp.ndarray) -> ZCAWhitener:
-        whitener, means = _zca_fit(mat, jnp.float32(self.eps))
-        return ZCAWhitener(whitener, means)
+    def fit_single(self, mat) -> ZCAWhitener:
+        whitener, means = _zca_fit(np.asarray(mat, np.float64), self.eps)
+        return ZCAWhitener(whitener.astype(np.float32), means.astype(np.float32))
 
 
-@linalg.mode_jit
-def _zca_fit(mat, eps):
-    means = jnp.mean(mat, axis=0)
-    centered = mat - means
+def _zca_fit(mat: np.ndarray, eps: float):
+    """The whitener and the means of the (n, d) patches, in float64 on
+    the host: a d x d eigenproblem (108 at CIFAR's 6 x 6 x 3 patches).
+
+    Row-normalised patches each sum to zero, so their covariance has a
+    null direction, which the whitener scales by ε^-½ (316 at ε = 1e-5):
+    the float32 rounding of the covariance or of an SVD lands there. A
+    float32 SVD of CIFAR's 100,000 centred patches on a TPU v5e left the
+    filters' max |f C f' - 1| at 5.3e-6 to 1.8e-5; a float32 covariance
+    left the whitener 3.5e-5 to 4.6e-4 from C^-½ in relative Frobenius
+    norm (on a CPU). In float64, stored as float32, they read 6-9e-7 and
+    2.4e-8. The covariance is taken from the Gram less n·μμᵀ, one pass
+    over the patches and no centred copy."""
     n = mat.shape[0]
-    _, s, vt = jnp.linalg.svd(centered, full_matrices=False)
-    scale = (s**2 / (n - 1.0) + eps) ** -0.5
-    whitener = linalg.mm(vt.T * scale, vt)
-    return whitener, means
+    means = mat.mean(axis=0)
+    cov = (mat.T @ mat - n * np.outer(means, means)) / (n - 1.0)
+    lam, vec = np.linalg.eigh(cov)
+    return (vec * (lam + eps) ** -0.5) @ vec.T, means

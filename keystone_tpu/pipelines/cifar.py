@@ -153,9 +153,7 @@ def learn_random_patch_filters(
     dim = config.patch_size ** 2 * np.asarray(train_images.data).shape[-1]
     with spans.span("build:filters", patches=whitener_size, filters=config.num_filters, dim=dim):
         base_mat = sample_random_patches(train_images, config, whitener_size)
-        whitener = ZCAWhitenerEstimator(eps=config.whitening_epsilon).fit_single(
-            base_mat.astype(np.float32)
-        )
+        whitener = ZCAWhitenerEstimator(eps=config.whitening_epsilon).fit_single(base_mat)
         rng = np.random.default_rng(config.seed)
         idx = rng.choice(base_mat.shape[0], size=min(config.num_filters, base_mat.shape[0]), replace=False)
         sample_filters = base_mat[idx]

@@ -1,10 +1,12 @@
-"""The fused convolution featurizer at CIFAR RandomPatch's size (PR 40):
-its panel bounded in rows as well as filters, one program for every
-filter bank, its patch statistics at HIGHEST, and its spans, scopes and
-counters (`image:conv`, `build:filters`, `conv/*`,
-`keystone_conv_panels_total`, `keystone_conv_panel_bytes`)."""
+"""The fused convolution featurizer at CIFAR RandomPatch's size: its
+panel bounded in rows as well as filters, the kernel's form against XLA's
+and which of them runs, one program for every filter bank, its patch
+statistics at HIGHEST, and its spans, scopes and counters (`image:conv`,
+`build:filters`, `conv/*`, `keystone_conv_panels_total`,
+`keystone_conv_kernel_panels_total`, `keystone_conv_panel_bytes`)."""
 
 import re
+from functools import partial
 
 import numpy as np
 import pytest
@@ -86,6 +88,115 @@ def test_apply_arrays_takes_the_row_block_it_decides(monkeypatch):
     assert np.array_equal(bounded, np.asarray(featurizer.apply_arrays(jnp.asarray(x))))
 
 
+# ------------------------------------------------------ the kernel's form
+
+
+@pytest.fixture
+def kernel_form(monkeypatch):
+    """A switch to the kernel's form as a TPU takes it, run in the Pallas
+    interpreter. The form is decided when `_featurize` is traced, so its
+    traces are dropped on the switch and after the test."""
+
+    def switch():
+        interpreted = partial(core._pooled_kernel, interpret=True)
+        monkeypatch.setattr(core, "_conv_form", lambda spec, x_dim, y_dim: "kernel")
+        monkeypatch.setattr(core, "_pooled_kernel", interpreted)
+        core._featurize.clear_cache()
+
+    yield switch
+    core._featurize.clear_cache()
+
+
+def _bf16_exact(a):
+    """`a` rounded to bfloat16 and held in float32: the filters a product
+    at the MXU default would multiply, so that XLA's float32 form on the
+    CPU and the kernel's bfloat16 products are the same products."""
+    return np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
+
+
+def _kernel_case(num_filters, filter_block, pool="sum", normalize=True, whiten=True, seed=0):
+    from keystone_tpu.ops.learning.zca import ZCAWhitener
+
+    rng = np.random.default_rng(seed)
+    filters = _bf16_exact(rng.normal(size=(num_filters, 6 * 6 * 3)) * 0.1)
+    whitener = ZCAWhitener(np.eye(108, dtype=np.float32), rng.normal(size=108).astype(np.float32) * 3) if whiten else None
+    conv = Convolver(filters, 3, whitener=whitener, normalize_patches=normalize, var_constant=10.0)
+    return FusedConvFeaturizer(conv, SymmetricRectifier(alpha=0.25), Pooler(13, 14, None, pool), filter_block)
+
+
+@pytest.mark.parametrize(
+    "num_filters,filter_block,pool,normalize,tile",
+    [(512, 512, "sum", True, None), (600, 512, "sum", True, 256), (37, 16, "max", True, None), (24, 8, "sum", False, None)],
+    ids=["cifar-block", "filters-no-multiple-of-the-tile", "max-pooling", "no-normalisation"],
+)
+def test_the_kernel_pools_what_xlas_form_pools(monkeypatch, kernel_form, num_filters, filter_block, pool, normalize, tile):
+    """The kernel in the Pallas interpreter against XLA's form: CIFAR's
+    geometry (32 x 32 x 3, 6 x 6 filters, pool 14 / 13, alpha 0.25,
+    variance constant 10, the whitener's offset) on 3 images, a row tile
+    short; apart by float32's summation order alone."""
+    from keystone_tpu.ops.pallas import conv_pool
+
+    if tile is not None:  # several filter tiles of two products each, the last one ragged
+        monkeypatch.setattr(conv_pool, "FILTER_TILE", tile)
+        monkeypatch.setattr(conv_pool, "LANES", tile // 2)
+    featurizer = _kernel_case(num_filters, filter_block, pool, normalize)
+    conv = featurizer.conv
+    x = jnp.asarray(_images(3, seed=2))
+    args = (x, conv.kernel, conv.filter_sums, conv.offset)
+    xla = np.asarray(core._featurize(*args, spec=featurizer.spec, row_block=3))
+    kernel_form()
+    kernel = np.asarray(core._featurize(*args, spec=featurizer.spec, row_block=3))
+    assert kernel.shape == xla.shape == (3, 2 * 2 * 2 * num_filters)
+    np.testing.assert_allclose(kernel, xla, rtol=1e-5, atol=1e-5 * np.abs(xla).max())
+
+
+def test_the_solvers_block_takes_the_featurizers_form(kernel_form):
+    """`conv_block._conv_bcd_step_fn` featurizes one solver block through
+    `_pooled_block`: its kernel form against its XLA form."""
+    featurizer = _kernel_case(16, 16)
+    conv, spec = featurizer.conv, featurizer.spec
+    kblocks, fs, off = core._pack_filters(conv.kernel, conv.filter_sums, conv.offset, 16)
+    x = jnp.asarray(_images(5, seed=3))
+    m, sd = core._norm_stats(spec, x)
+    xla = np.asarray(core._pooled_block(spec, x, kblocks[0], fs[0], off[0], m, sd))
+    kernel_form()
+    kernel = np.asarray(core._pooled_block(spec, x, kblocks[0], fs[0], off[0], m, sd))
+    assert kernel.shape == xla.shape == (5, 2 * 2 * 2 * 16)
+    np.testing.assert_allclose(kernel, xla, rtol=1e-5, atol=1e-5 * np.abs(xla).max())
+
+
+@pytest.mark.parametrize(
+    "backend,pixel_function,image,vmem,form",
+    [
+        ("tpu", None, 32, 128 * 2**20, "kernel"), ("tpu", np.abs, 32, 128 * 2**20, "xla"),
+        ("cpu", None, 32, 128 * 2**20, "xla"), ("tpu", None, 256, 128 * 2**20, "xla"),
+        ("tpu", None, 32, 64 * 2**20, "xla"), ("tpu", None, 32, 0, "xla"),
+    ],
+    ids=[
+        "a-tpu-takes-the-kernel", "a-pixel-function-takes-xla", "the-cpu-takes-xla", "a-step-past-vmem-takes-xla",
+        "a-chip-with-less-vmem-takes-xla", "a-chip-pallas-does-not-know-takes-xla",
+    ],
+)
+def test_the_form_follows_the_backend_and_what_the_kernel_expresses(monkeypatch, backend, pixel_function, image, vmem, form):
+    from keystone_tpu.ops.pallas import conv_pool
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(conv_pool, "vmem_bytes", lambda: vmem)
+    conv = Convolver(np.zeros((10000, 108), np.float32), 3)
+    featurizer = FusedConvFeaturizer(conv, SymmetricRectifier(alpha=0.25), Pooler(13, 14, pixel_function, "sum"), 512)
+    assert core._conv_form(featurizer.spec, image, image) == form
+
+
+def test_a_chip_pallas_does_not_know_has_no_vmem_for_the_kernel():
+    """`pltpu.get_tpu_info` raises off its table of TPU generations (here,
+    the CPU): `vmem_bytes` reads 0, and `fits` sends the featurizer to
+    XLA's form rather than into a Mosaic compile that cannot be sized."""
+    from keystone_tpu.ops.pallas import conv_pool
+
+    assert conv_pool.vmem_bytes() == 0
+    assert not conv_pool.fits(27, 32, 128, 10000, 0) and conv_pool.fits(27, 32, 128, 10000, 128 * 2**20)
+
+
 # ------------------------------------------------------ one program
 
 
@@ -144,7 +255,7 @@ def _convolutions(lowered_text):
     return found
 
 
-def test_the_patch_statistics_are_summed_at_highest_and_the_main_convolution_as_shipped():
+def test_the_patch_statistics_are_summed_at_highest_and_the_main_convolution_as_shipped(kernel_form):
     featurizer = _featurizer(20, 8)
     conv = featurizer.conv
     text = core._featurize.lower(
@@ -155,6 +266,20 @@ def test_the_patch_statistics_are_summed_at_highest_and_the_main_convolution_as_
     # the unfused Convolver shares the same statistics
     unfused = jax.jit(conv.apply_arrays).lower(jnp.zeros((2, 32, 32, 3))).as_text()
     assert sorted(_convolutions(unfused)) == [("DEFAULT", 20), ("HIGHEST", 1), ("HIGHEST", 1)]
+    # in the kernel's form (its body as the interpreter lowers it): the same
+    # statistics, and the main product of bfloat16 patches and filters
+    # summed in float32, whatever the filters' dtype
+    kernel_form()
+    kernel_text = core._featurize.lower(
+        jnp.zeros((4, 32, 32, 3)), conv.kernel, conv.filter_sums, conv.offset,
+        spec=featurizer.spec, row_block=2,
+    ).as_text()
+    statistics = [line for line in kernel_text.splitlines() if "stablehlo.convolution" in line and "xf32>" in line]
+    assert len(statistics) == 2 and all("precision HIGHEST" in line for line in statistics)
+    products = [line for line in kernel_text.splitlines() if "stablehlo.dot_general" in line]
+    assert products and all(
+        re.search(r": \(tensor<\d+x128xbf16>, tensor<128x\d+xbf16>\) -> tensor<\d+x\d+xf32>", line) for line in products
+    )
 
 
 # ------------------------------------------------------ spans, scopes, counters
@@ -177,9 +302,27 @@ def test_image_conv_says_rows_filters_and_its_panels_and_counts_them(session, mo
     before = panels.value(site="FusedConvFeaturizer")
     featurizer.to_pipeline()(ArrayDataset(_images(10))).get()
     (span,) = _named(session, "image:conv")
-    assert span.attributes == {"rows": 10, "filters": 37, "row_block": 4, "filter_block": 8, "panels": 3 * 5}
+    assert span.attributes == {"rows": 10, "filters": 37, "row_block": 4, "filter_block": 8, "panels": 3 * 5, "form": "xla"}
     assert panels.value(site="FusedConvFeaturizer") - before == 15
     assert panel_bytes.value(site="FusedConvFeaturizer") == 4 * 27 * 27 * 8 * 4
+
+
+@pytest.mark.parametrize("form", ["kernel", "xla"])
+def test_image_conv_says_its_form_and_the_kernel_counts_its_panels(session, monkeypatch, form):
+    """`keystone_conv_kernel_panels_total` over `keystone_conv_panels_total`
+    is the share of panels the kernel computed: all of them where the
+    span says `kernel`, none where it says `xla`."""
+    featurizer = _featurizer(37, 8)
+    monkeypatch.setattr(mesh, "device_memory_limit_bytes", lambda: _limit_for_rows(featurizer, 4))
+    monkeypatch.setattr(core, "_conv_form", lambda spec, x_dim, y_dim: form)
+    panels, kernel_panels = names.metric(names.CONV_PANELS), names.metric(names.CONV_KERNEL_PANELS)
+    before = panels.value(site="FusedConvFeaturizer"), kernel_panels.value(site="FusedConvFeaturizer")
+    with featurizer.host_span(ArrayDataset(_images(10))):
+        pass
+    (span,) = _named(session, "image:conv")
+    assert span.attributes["form"] == form and span.attributes["panels"] == 15
+    counted = panels.value(site="FusedConvFeaturizer") - before[0], kernel_panels.value(site="FusedConvFeaturizer") - before[1]
+    assert counted == ((15, 15) if form == "kernel" else (15, 0))
 
 
 def test_a_fused_chain_headed_by_the_featurizer_opens_image_conv_inside_its_node(session):
@@ -204,27 +347,36 @@ def test_build_filters_spans_the_filter_learning(session):
     assert filters.shape == (16, 108)
 
 
-@pytest.mark.parametrize("path", ["fit", "apply"])
-def test_the_conv_scopes_sit_inside_the_featurizers_on_either_path(path):
+@pytest.mark.parametrize("path", ["fit", "apply", "kernel"])
+def test_the_conv_scopes_sit_inside_the_featurizers_on_either_path(kernel_form, path):
     """What `scope_ms.conv.fit` reads: `conv/stats`, `conv/panel` and
     `conv/pool` under `feat/FusedConvFeaturizer`, in the program a fit
-    runs (the featurizer's own, eagerly) and in the fused chain a request
-    runs."""
+    runs (the featurizer's own, eagerly), in the fused chain a request
+    runs, and in the kernel's form, whose kernel runs under `conv/panel`
+    (as the interpreter lowers it here; the compiled kernel's custom call
+    for a described v5e: tests/workflow/test_row_chain_on_tpu.py)."""
     from keystone_tpu.workflow.fusion import _shared_chain_jit
     from keystone_tpu.ops.stats.core import StandardScalerModel
 
     featurizer = _featurizer(12, 5)
     x = jnp.zeros((4, 32, 32, 3))
-    if path == "fit":
+    if path == "kernel":
+        kernel_form()
+    if path != "apply":
         conv = featurizer.conv
         compiled = core._featurize.lower(
-            x, conv.kernel, conv.filter_sums, conv.offset, spec=featurizer.spec, row_block=2
+            x, conv.kernel, conv.filter_sums, conv.offset, spec=featurizer.spec, row_block=2,
         ).compile()
     else:
         width = 2 * 2 * 2 * 12
         chain = (featurizer, StandardScalerModel(np.zeros(width, np.float32), np.ones(width, np.float32)))
         compiled = _shared_chain_jit(chain).lower(x).compile()
     names_seen = set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
+    kernel_ops = [n for n in names_seen if "/conv_pool/" in n]
+    if path == "kernel":
+        assert kernel_ops and all("feat/FusedConvFeaturizer/" in n and "/conv/panel/" in n for n in kernel_ops)
+    else:
+        assert not kernel_ops
     for scope in ("conv/stats", "conv/panel", "conv/pool"):
         assert any("feat/FusedConvFeaturizer/" in n and f"/{scope}/" in n for n in names_seen), scope
 

@@ -98,6 +98,23 @@ def test_zca_whitens_covariance():
     np.testing.assert_allclose(cov, np.eye(6), atol=0.05)
 
 
+def test_zca_holds_the_null_direction_of_row_normalised_patches():
+    """Row-normalised patches each sum to zero: their covariance C has a
+    null direction, which epsilon 1e-5 scales by 316. The whitener is
+    (C + eps I)^(-1/2) of the float64 covariance to float32's rounding of
+    it (a float32 SVD of these patches reads 8e-7 to 1.9e-6 here)."""
+    from keystone_tpu.pipelines.cifar import normalize_rows
+
+    rng = np.random.default_rng(4)
+    patches = normalize_rows(rng.integers(0, 256, size=(20000, 108)).astype(np.float64), 10.0)
+    model = ZCAWhitenerEstimator(eps=1e-5).fit_single(patches)
+    means = np.asarray(model.means, np.float64)
+    centred = patches - means
+    lam, vec = np.linalg.eigh(centred.T @ centred / (len(patches) - 1) + 1e-5 * np.eye(108))
+    own = (vec / np.sqrt(lam)) @ vec.T
+    assert np.linalg.norm(np.asarray(model.whitener, np.float64) - own) / np.linalg.norm(own) < 1e-7
+
+
 def test_kmeans_recovers_separated_clusters():
     rng = np.random.default_rng(3)
     centers = np.array([[0, 0], [10, 10], [-10, 10]], dtype=np.float32)

@@ -125,6 +125,83 @@ def test_the_fisher_encoder_never_merges_images_with_descriptors(one_chip, descr
         assert compiled.memory_analysis().temp_size_in_bytes < 1.9 * GIB
 
 
+@pytest.fixture
+def kernel_form(monkeypatch):
+    """The featurizer's panels in the kernel's form, as on the chip: the
+    form is decided when a program is traced, from the backend, which is
+    the CPU here."""
+    from keystone_tpu.ops.images import core
+
+    monkeypatch.setattr(core, "_conv_form", lambda spec, x_dim, y_dim: "kernel")
+    core._featurize.clear_cache()
+    yield
+    core._featurize.clear_cache()
+
+
+def _cifar_featurizer(filter_block=512):
+    from keystone_tpu.ops.images import Convolver, FusedConvFeaturizer, Pooler, SymmetricRectifier
+
+    conv = Convolver(np.zeros((10000, 108), np.float32), 3)
+    return FusedConvFeaturizer(conv, SymmetricRectifier(alpha=0.25), Pooler(13, 14, None, "sum"), filter_block)
+
+
+def _one_kernel_call_under_conv_panel(text):
+    import re
+
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    (op_name,) = re.findall(r'op_name="([^"]*)"', calls[0])
+    assert "/conv/panel/" in op_name
+    return op_name
+
+
+def test_the_cifar_featurizer_pools_its_panels_in_the_kernel_and_holds_no_panel(one_chip, kernel_form):
+    """`_featurize` at CIFAR RandomPatch's widths, 8,192 images in row
+    blocks of 512, in the kernel's form (`ops/pallas/conv_pool.py`): the
+    Mosaic kernel compiles (its VMEM is the chip's to refuse), runs under
+    `feat/FusedConvFeaturizer/.../conv/panel/`, and no float32 (512, 27,
+    27, 512) panel is left: the compiler's temporaries are under one
+    panel, 0.76 GB (1.13 GiB in XLA's form, PERF.md section 6)."""
+    from keystone_tpu.ops.images import core
+
+    spec = _cifar_featurizer().spec
+
+    def featurize(x, kernel, fsums, offset):
+        return core._featurize(x, kernel, fsums, offset, spec=spec, row_block=512)
+
+    compiled = _compiled(featurize, one_chip, (8192, 32, 32, 3), (6, 6, 3, 10000), (10000,), (10000,))
+    text = compiled.as_text()
+    assert "feat/FusedConvFeaturizer/" in _one_kernel_call_under_conv_panel(text)
+    assert "f32[512,27,27,512]" not in text
+    analysis = compiled.memory_analysis()
+    assert analysis.output_size_in_bytes == 8192 * 80000 * 4
+    assert analysis.temp_size_in_bytes < 512 * 27 * 27 * 512 * 4
+
+
+def test_the_convolutional_block_solvers_step_pools_in_the_kernel(one_chip, kernel_form):
+    """`conv_block._conv_bcd_step_fn`, one BCD update that featurizes its
+    filter block inside `shard_map`, at CIFAR's widths (a 4,096-wide
+    block: 512 filters, 8,192 images in chunks of 512, 10 classes): the
+    same kernel compiles there, once, under `conv/panel`, and no
+    float32 (512, 27, 27, 512) panel is left."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from keystone_tpu.ops.learning.conv_block import _conv_bcd_step_fn
+    from keystone_tpu.parallel.mesh import DATA_AXIS
+
+    mesh = Mesh(np.array(list(one_chip.device_set)), (DATA_AXIS,))
+    step = _conv_bcd_step_fn(mesh, _cifar_featurizer(), 512, True, 8, 512, 2, 2)
+    rows, replicated = NamedSharding(mesh, P(DATA_AXIS)), NamedSharding(mesh, P())
+    shapes = [
+        ((8192, 32, 32, 3), rows), ((8192, 1), rows), ((8192, 10), rows), ((8192, 10), rows), ((4096, 10), replicated),
+        ((6, 6, 3, 512), replicated), ((512,), replicated), ((512,), replicated), ((), replicated), ((), replicated),
+    ]
+    specs = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding) for shape, sharding in shapes]
+    text = step.lower(*specs).compile().as_text()
+    _one_kernel_call_under_conv_panel(text)
+    assert "f32[512,27,27,512]" not in text
+
+
 def test_the_fisher_encoder_behind_its_projection_is_one_program_without_a_loop(one_chip):
     """As the request's fused chain has it: the signed root, the PCA
     projection, the encoder."""
