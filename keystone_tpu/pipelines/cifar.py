@@ -18,12 +18,13 @@ from __future__ import annotations
 
 import logging
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Optional
 
 import numpy as np
 
-from ..data.dataset import ArrayDataset
+from ..data.dataset import ArrayDataset, default_ingest_workers, transfer_dtype
 from ..data.loaders.cifar import load_cifar
 from ..evaluation.multiclass import MulticlassClassifierEvaluator
 from ..obs import spans
@@ -34,13 +35,12 @@ from ..ops.images import (
     ImageVectorizer,
     Pooler,
     SymmetricRectifier,
-    Windower,
 )
 from ..ops.learning.block import BlockLeastSquaresEstimator
 from ..ops.learning.kernel import GaussianKernelGenerator, KernelRidgeRegression
 from ..ops.learning.linear import LinearMapEstimator
 from ..ops.learning.zca import ZCAWhitener, ZCAWhitenerEstimator
-from ..ops.stats.core import Sampler, StandardScaler
+from ..ops.stats.core import StandardScaler
 from ..ops.util.labels import ClassLabelIndicators, MaxClassifier
 from ..workflow.pipeline import Pipeline
 
@@ -110,37 +110,64 @@ def normalize_rows(mat: np.ndarray, alpha: float = 1.0) -> np.ndarray:
     return centred
 
 
+#: Rows a host thread gathers and normalises at a time: its float64 block
+#: (3.5 MB at 108 columns) stays in cache through the passes over it.
+_GATHER_ROWS = 4096
+
+
 def sample_random_patches(
     train_images: ArrayDataset, config: RandomCifarConfig, whitener_size: int = 100000
 ) -> np.ndarray:
     """The (whitener_size, s*s*c) float64 row-normalised patches that the
     whitener is fitted on and the filters are drawn from
     (reference: RandomPatchCifar.scala:45-52): windows → sample →
-    vectorize → row-normalize. The same rows for the same images and seed."""
+    vectorize → row-normalize. The rows of ``Windower > Sampler >
+    ImageVectorizer > normalize_rows`` to the bit, with the same draws of
+    images and of windows (``Sampler``'s, over ``Windower``'s x-major
+    order), but only the sampled windows are read from the host images,
+    in row blocks on ``default_ingest_workers()`` host threads. No BLAS
+    call runs on a worker: the whitener's Gram, summed per block on 13
+    threads of a TPU v5e host, took the gather from 54 to 214 ms, against
+    21 ms for one product over all rows afterwards."""
+    images = np.asarray(train_images.data)[: len(train_images)]
+    n, x_dim, y_dim, c = images.shape
+    w, s = config.patch_size, config.patch_steps
     # Subsample images before windowing: at full CIFAR scale all windows of
     # all images is ~36M patches (~16 GB) of which the Sampler keeps 100k —
     # the reference streams this through an RDD, here we bound it up front.
-    x_dim, y_dim = np.asarray(train_images.data).shape[1:3]
-    per_image = (max(0, (x_dim - config.patch_size) // config.patch_steps) + 1) * (
-        max(0, (y_dim - config.patch_size) // config.patch_steps) + 1
-    )
-    want_images = max(1, min(len(train_images), (2 * whitener_size) // per_image + 1))
-    if want_images < len(train_images):
-        idx = np.random.default_rng(config.seed).choice(
-            len(train_images), size=want_images, replace=False
-        )
-        train_images = ArrayDataset(np.asarray(train_images.data)[idx])
+    per_image = (max(0, (x_dim - w) // s) + 1) * (max(0, (y_dim - w) // s) + 1)
+    want_images = max(1, min(n, (2 * whitener_size) // per_image + 1))
+    if want_images < n:
+        images = images[np.random.default_rng(config.seed).choice(n, size=want_images, replace=False)]
+    n_windows = want_images * per_image
+    take = min(whitener_size, n_windows)
+    flat = np.sort(np.random.default_rng(config.seed).choice(n_windows, size=take, replace=False))
+    image, at = np.divmod(flat, per_image)
+    wx, wy = np.divmod(at, (y_dim - w) // s + 1)  # Windower's x-major grid
+    # ImageVectorizer's row is y-major, then x, then channel: in a y-major
+    # copy of the images a window is w runs of w*C contiguous values, so
+    # the window (x0, y0) of image i is view[i, y0, x0 * C] as it goes out.
+    # The values pass through the dtype a device would hold them in.
+    src = images.astype(transfer_dtype(images.dtype), copy=False).transpose(0, 2, 1, 3)
+    src = np.ascontiguousarray(src).reshape(want_images, y_dim, x_dim * c)
+    view = np.lib.stride_tricks.sliding_window_view(src, (w, w * c), axis=(1, 2))
+    rows_y, cols_x = wy * s, wx * (s * c)
+    out = np.empty((take, w * w * c), np.float64)
 
-    # sampled before they are vectorized (a row-by-row map: the same rows),
-    # so that only the sample goes to the device
-    patch_pipe = (
-        Windower(config.patch_steps, config.patch_size)
-        .to_pipeline()
-        .then(Sampler(whitener_size, seed=config.seed))
-        .then(ImageVectorizer())
-    )
-    base_filters = patch_pipe(train_images).get()
-    return normalize_rows(np.asarray(base_filters.data, dtype=np.float64), 10.0)
+    def block(lo: int):
+        hi = min(lo + _GATHER_ROWS, take)
+        gathered = view[image[lo:hi], rows_y[lo:hi], cols_x[lo:hi]].reshape(hi - lo, -1)
+        out[lo:hi] = normalize_rows(gathered.astype(np.float64), 10.0)
+
+    starts = range(0, take, _GATHER_ROWS)
+    workers = max(1, min(default_ingest_workers(), len(starts)))
+    if workers == 1:
+        for lo in starts:
+            block(lo)
+    else:
+        with ThreadPoolExecutor(workers, thread_name_prefix="keystone-patches") as pool:
+            list(pool.map(block, starts))
+    return out
 
 
 def learn_random_patch_filters(
@@ -151,7 +178,10 @@ def learn_random_patch_filters(
     → fit ZCA → sample numFilters rows → whiten, L2-row-normalize,
     multiply by Wᵀ."""
     dim = config.patch_size ** 2 * np.asarray(train_images.data).shape[-1]
-    with spans.span("build:filters", patches=whitener_size, filters=config.num_filters, dim=dim):
+    with spans.span(
+        "build:filters", patches=whitener_size, filters=config.num_filters, dim=dim,
+        workers=default_ingest_workers(),
+    ):
         base_mat = sample_random_patches(train_images, config, whitener_size)
         whitener = ZCAWhitenerEstimator(eps=config.whitening_epsilon).fit_single(base_mat)
         rng = np.random.default_rng(config.seed)

@@ -14,7 +14,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from keystone_tpu.data.dataset import ArrayDataset
+from keystone_tpu.data.dataset import ArrayDataset, default_ingest_workers
 from keystone_tpu.obs import names, spans
 from keystone_tpu.ops.images import Convolver, FusedConvFeaturizer, Pooler, SymmetricRectifier
 from keystone_tpu.ops.images import core
@@ -343,7 +343,9 @@ def test_build_filters_spans_the_filter_learning(session):
     config = cifar.RandomCifarConfig(num_filters=16, whitening_epsilon=1e-5)
     filters, _ = cifar.learn_random_patch_filters(ArrayDataset(_images(8)), config, whitener_size=500)
     (span,) = _named(session, "build:filters")
-    assert span.attributes == {"patches": 500, "filters": 16, "dim": 108}
+    assert span.attributes == {
+        "patches": 500, "filters": 16, "dim": 108, "workers": default_ingest_workers(),
+    }
     assert filters.shape == (16, 108)
 
 

@@ -98,3 +98,75 @@ def test_random_patch_cifar_augmented_learns(tmp_path):
     # train == test and the classes are linearly separable prototypes:
     # augmented voting should beat chance (0.9 error) comfortably
     assert results["test_error"] < 0.5
+
+
+def _pipeline_patches(images, config, whitener_size):
+    """The reference's composition, written out: the kept images, every
+    window of them, a sample, vectorized, row-normalised."""
+    from keystone_tpu.ops.images import ImageVectorizer, Windower
+    from keystone_tpu.ops.stats.core import Sampler
+
+    n, x_dim, y_dim = images.shape[:3]
+    s, w = config.patch_steps, config.patch_size
+    per_image = ((x_dim - w) // s + 1) * ((y_dim - w) // s + 1)
+    want = max(1, min(n, (2 * whitener_size) // per_image + 1))
+    if want < n:
+        images = images[np.random.default_rng(config.seed).choice(n, size=want, replace=False)]
+    windows = Windower(s, w).apply_batch(ArrayDataset(images))
+    sampled = Sampler(whitener_size, seed=config.seed).apply_batch(windows)
+    vectors = np.asarray(ImageVectorizer().apply_batch(sampled).data, dtype=np.float64)
+    return cifar.normalize_rows(vectors, 10.0)
+
+
+@pytest.mark.parametrize(
+    "n, shape, steps, whitener_size, dtype",
+    [
+        (20, (32, 32, 3), 1, 2000, np.float32),  # 6 of 20 images kept
+        (20, (32, 32, 3), 2, 1000, np.float32),  # stride 2
+        (3, (32, 32, 3), 1, 2000, np.float32),  # every image kept
+        (2, (32, 32, 3), 1, 5000, np.float32),  # fewer windows (1,458) than asked for
+        (9, (11, 9, 3), 1, 40, np.float32),  # not square
+        (7, (13, 10, 3), 2, 30, np.uint8),  # not square, stride 2, bytes
+        (5, (32, 32, 3), 1, 3000, np.float64),  # held as float32 on the way
+    ],
+)
+def test_gathered_patches_are_the_pipelines_to_the_bit(monkeypatch, n, shape, steps, whitener_size, dtype):
+    """`sample_random_patches` gathers only the sampled windows from the
+    host images: the rows of Windower > Sampler > ImageVectorizer >
+    normalize_rows, bit for bit, in one row block inline or in many on
+    several host threads."""
+    rng = np.random.default_rng(n)
+    images = (rng.random((n, *shape)) * 255).astype(dtype)
+    config = cifar.RandomCifarConfig(patch_steps=steps, seed=77)
+    expected = _pipeline_patches(images, config, whitener_size)
+    assert expected.shape == (min(whitener_size, len(expected)), 6 * 6 * 3)
+    for rows, workers in ((cifar._GATHER_ROWS, 1), (7, 5)):
+        monkeypatch.setenv("KEYSTONE_INGEST_WORKERS", str(workers))
+        monkeypatch.setattr(cifar, "_GATHER_ROWS", rows)
+        got = cifar.sample_random_patches(ArrayDataset(images), config, whitener_size)
+        assert got.dtype == np.float64 and np.array_equal(got, expected)
+
+
+def test_filter_learning_gathers_the_pipelines_patches_without_a_node_span(monkeypatch):
+    """`build:filters` says how many host threads gathered the patches and
+    opens no `node:` span inside; the filters and the whitener are the
+    ones learned from the written-out pipeline's patches, to the bit."""
+    from keystone_tpu.data.dataset import default_ingest_workers
+    from keystone_tpu.obs import spans
+
+    images = make_synthetic_cifar(12, seed=4).data["image"]
+    config = cifar.RandomCifarConfig(num_filters=40, patch_steps=2, seed=5)
+
+    with spans.tracing_session("t") as session:
+        filters, whitener = cifar.learn_random_patch_filters(ArrayDataset(images), config, 1500)
+    (learn,) = session.spans()
+    assert learn.name == "build:filters"
+    assert learn.attributes["workers"] == default_ingest_workers()
+
+    monkeypatch.setattr(
+        cifar, "sample_random_patches", lambda data, cfg, size: _pipeline_patches(np.asarray(data.data), cfg, size)
+    )
+    ref_filters, ref_whitener = cifar.learn_random_patch_filters(ArrayDataset(images), config, 1500)
+    assert np.array_equal(filters, ref_filters)
+    assert np.array_equal(np.asarray(whitener.whitener), np.asarray(ref_whitener.whitener))
+    assert np.array_equal(np.asarray(whitener.means), np.asarray(ref_whitener.means))
