@@ -33,6 +33,7 @@ FUSION_FUSED_NODES = "keystone_fusion_fused_nodes_total"
 FUSION_DISPATCHES_SAVED = "keystone_fusion_dispatches_saved_total"
 FUSION_COMPILES = "keystone_fusion_compiles_total"
 FUSION_BATCH_DISPATCHES = "keystone_fusion_batch_dispatches_total"
+FUSION_GATHERS = "keystone_fusion_gathers_total"
 
 # ------------------------------------------------------------------- streaming
 STREAM_PLANS = "keystone_stream_plans_total"
@@ -234,6 +235,7 @@ SCHEMA: Dict[str, Tuple] = {
     FUSION_DISPATCHES_SAVED: ("counter", "Per-execution dispatches avoided by fusion (members-1 per chain)", ()),
     FUSION_COMPILES: ("counter", "Fused-chain executable traces (one per new shape/dtype)", ()),
     FUSION_BATCH_DISPATCHES: ("counter", "Transformer batch-apply dispatches, split fused vs unfused", ("fused",)),
+    FUSION_GATHERS: ("counter", "Gathers of fusable branches into a VectorCombiner folded into one fused operator (fitted graphs)", ()),
     STREAM_PLANS: ("counter", "Estimator fits rewritten onto the streaming engine by StreamingPlanRule", ()),
     STREAM_CHUNKS: ("counter", "Chunks dispatched by the streaming execution engine", ()),
     STREAM_BYTES: ("counter", "Host-to-device bytes uploaded by the streaming engine (post narrow-dtype)", ()),

@@ -104,11 +104,21 @@ def test_a_profiler_trace_of_a_fit_holds_the_programs_phases(traced, name):
 
 
 @pytest.mark.parametrize("name", [
-    "ks:apply:bind", "ks:h2d", "ks:node:CosineRandomFeatures", "ks:node:VectorCombiner",
-    "ks:node:Fused[BlockLinearMapper+MaxClassifier]",
+    "ks:apply:bind", "ks:h2d",
+    "ks:node:Fused[Gather[2*CosineRandomFeatures]+BlockLinearMapper+MaxClassifier]",
 ])
 def test_a_profiler_trace_of_apply_batch_holds_the_programs_phases(traced, name):
     assert name in {e[0] for e in _inside(traced, "apply")}
+
+
+@pytest.mark.parametrize("name", [
+    "ks:node:CosineRandomFeatures", "ks:node:VectorCombiner",
+    "ks:node:Fused[BlockLinearMapper+MaxClassifier]",
+])
+def test_a_profiler_trace_of_apply_batch_has_no_node_of_the_one_program(traced, name):
+    """The fitted gather, its branches, the mapper and the arg-max are one
+    fused node: none of them is a node of its own in a request."""
+    assert name not in {e[0] for e in _inside(traced, "apply")}
 
 
 @pytest.mark.parametrize("operation", ["fit0", "apply"])
@@ -116,7 +126,8 @@ def test_node_spans_of_one_operation_are_siblings_in_time(traced, operation):
     """A node's span is its own work, opened after its dependencies are
     forced: however the pulls nest, no node span overlaps another."""
     nodes = [e for e in _inside(traced, operation) if e[0].startswith("ks:node:")]
-    assert len(nodes) >= 4
+    # a request is one fused node (the fitted gather is part of it)
+    assert len(nodes) >= 4 if operation == "fit0" else len(nodes) == 1
     for (_, _, end), (_, start, _) in zip(nodes, nodes[1:]):
         assert end <= start
 
@@ -271,16 +282,15 @@ def test_with_a_session_the_profiler_sees_each_node_once(tmp_path):
         jax.profiler.stop_trace()
     nodes = [e[0] for e in _host_events(str(tmp_path)) if e[0].startswith("ks:node:")]
     assert sorted(nodes) == sorted([
-        "ks:node:CosineRandomFeatures", "ks:node:CosineRandomFeatures", "ks:node:Gather",
-        "ks:node:VectorCombiner", "ks:node:Fused[BlockLinearMapper+MaxClassifier]",
+        "ks:node:Fused[Gather[2*CosineRandomFeatures]+BlockLinearMapper+MaxClassifier]",
         "ks:node:Dataset[n=256]",  # the executor times the constant input too
     ])
 
 
 def test_one_host_input_of_two_branches_is_uploaded_once():
-    """One host input, two cosine branches: one upload, counted under the
-    operator whose output it was, and one branch handed the other's copy
-    (two uploads under `CosineRandomFeatures` until PR 33)."""
+    """One host input, two cosine branches: one upload, made by the one
+    fused program the fitted gather, the mapper and the arg-max are (no
+    upload under the branches, nor one shared under the input's node)."""
     pipeline, x = _pipeline(3)
     fitted = pipeline.fit()
 
@@ -293,13 +303,15 @@ def test_one_host_input_of_two_branches_is_uploaded_once():
     def gained(site, since):
         return tuple(now - was for now, was in zip(counted(site), since))
 
-    source0, cosine0 = counted("DatasetOperator"), counted("CosineRandomFeatures")
+    sites = ("FusedTransformerOperator", "DatasetOperator", "CosineRandomFeatures")
+    before = [counted(site) for site in sites]
     fitted.apply_batch(ArrayDataset(x))
-    assert gained("DatasetOperator", source0) == (x.nbytes, 1, 1)
-    assert gained("CosineRandomFeatures", cosine0) == (0, 0, 0)
+    assert [gained(site, was) for site, was in zip(sites, before)] == [
+        (x.nbytes, 1, 0), (0, 0, 0), (0, 0, 0),
+    ]
     device_input = ArrayDataset(jnp.asarray(x))
     fitted.apply_batch(device_input)  # already on the device: nothing to upload
-    assert gained("DatasetOperator", source0) == (x.nbytes, 1, 1)
+    assert gained("FusedTransformerOperator", before[0]) == (x.nbytes, 1, 0)
 
 
 @pytest.mark.parametrize("num_iter,mode", [(5, "reused"), (1, "single_pass")])
@@ -410,7 +422,8 @@ def test_the_cosine_featurizer_and_the_mapper_carry_their_scopes():
     mapper = BlockLinearMapper(jnp.ones((16, CLASSES)), 16, jnp.zeros(CLASSES), jnp.zeros(16))
     fused = FusedTransformerOperator([cosine, mapper])
     x = jnp.ones((8, DIM))
-    text = fused._compiled().lower(x).as_text(debug_info=True)
+    jitted, arrays = fused._compiled()
+    text = jitted.lower(x, arrays).as_text(debug_info=True)
     assert "feat/CosineRandomFeatures/cos" in text
     assert "feat/CosineRandomFeatures/dot_general" in text
     assert "feat/BlockLinearMapper/mapper/apply/dot_general" in text

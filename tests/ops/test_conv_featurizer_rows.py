@@ -372,7 +372,8 @@ def test_the_conv_scopes_sit_inside_the_featurizers_on_either_path(kernel_form, 
     else:
         width = 2 * 2 * 2 * 12
         chain = (featurizer, StandardScalerModel(np.zeros(width, np.float32), np.ones(width, np.float32)))
-        compiled = _shared_chain_jit(chain).lower(x).compile()
+        jitted, arrays = _shared_chain_jit(chain)
+        compiled = jitted.lower(x, arrays).compile()
     names_seen = set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
     kernel_ops = [n for n in names_seen if "/conv_pool/" in n]
     if path == "kernel":

@@ -114,3 +114,20 @@ def test_a_fit_keeps_how_each_mixture_was_started_and_how_far_em_went(fits):
         assert weights0.sum() == pytest.approx(1.0, abs=1e-5) and (vars0 > 0).all()
         assert 1 <= record["updates"] <= record["iterations"] <= 100
         assert record["iterations"] - record["updates"] in (0, 1)  # the last look may not update
+
+
+def test_the_flagships_fitted_gather_is_not_fused(fits):
+    """Its branches end in Fisher encodings of their own: no shared input
+    of fusable branches, so its gather and concatenation stay nodes."""
+    from keystone_tpu.ops.util.gather import GatherTransformer
+    from keystone_tpu.workflow.fusion import FusedGather, fuse_graph
+
+    fitted, _ = fits(4_000_000)
+    ops = list(fitted.graph.operators.values())
+    assert any(isinstance(op, GatherTransformer) for op in ops)
+    assert not any(
+        isinstance(m, FusedGather) for op in ops for m in getattr(op, "members", (op,))
+    )
+    before = names.metric(names.FUSION_GATHERS).total()
+    assert fuse_graph(fitted.graph) == fitted.graph
+    assert names.metric(names.FUSION_GATHERS).total() == before

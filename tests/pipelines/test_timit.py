@@ -37,6 +37,30 @@ def test_cauchy_variant_runs():
     assert len(np.asarray(preds.data)) >= 256
 
 
+def test_fitted_pipeline_scores_in_one_dispatch_with_the_unfused_labels():
+    """The fitted gather of cosine branches, their concatenation, the
+    mapper and the arg-max are one program: one dispatch a request."""
+    from keystone_tpu.obs import names
+    from keystone_tpu.workflow import fusion_disabled
+    from keystone_tpu.workflow.executor import PipelineEnv
+
+    config = small_config(num_cosines=3)
+    train = t.synthetic_timit(512, seed=4)
+    x = t.synthetic_timit(200, seed=5).data
+    PipelineEnv.reset()
+    fitted = t.build_pipeline(config, train).fit()
+    dispatches = names.metric(names.FUSION_BATCH_DISPATCHES)
+    before = dispatches.value(fused="1"), dispatches.value(fused="0")
+    labels = np.asarray(fitted.apply_batch(x).data)
+    after = dispatches.value(fused="1"), dispatches.value(fused="0")
+    assert (after[0] - before[0], after[1] - before[1]) == (1, 0)
+    assert len(fitted.graph.nodes) == 1
+    PipelineEnv.reset()
+    with fusion_disabled():
+        unfused = t.build_pipeline(config, train).fit()
+        np.testing.assert_array_equal(labels, np.asarray(unfused.apply_batch(x).data))
+
+
 def test_timit_loader(tmp_path):
     """Features CSV + 1-indexed sparse label files
     (reference: TimitFeaturesDataLoader.scala:326-390)."""

@@ -365,6 +365,80 @@ def test_repeated_unfitted_apply_shares_one_compiled_chain():
     )
 
 
+def test_a_refitted_chain_of_the_same_structure_builds_no_program():
+    """The members' arrays are arguments of the fused program, not
+    constants: a model fitted anew (other weights, the same shapes) runs
+    the program the first one built, and gives its own answer."""
+    from keystone_tpu.utils.compilation_cache import (
+        compile_count,
+        install_compile_counter,
+    )
+
+    install_compile_counter()
+    x = ArrayDataset(x_rows)
+    first, _ = _fit_both(_scoring(3, path=True))
+    a = np.asarray(first.apply_batch(x).data)
+    refit, unfused = _fit_both(_scoring(3, path=True, seed=40))
+    before = compile_count()
+    b = np.asarray(refit.apply_batch(x).data)
+    assert compile_count() - before == 0
+    np.testing.assert_array_equal(b, np.asarray(unfused.apply_batch(x).data))
+    assert not np.array_equal(a, b)
+
+
+def test_a_member_that_reads_an_array_value_is_closed_over():
+    """A member that needs an array's VALUE while traced keeps it a
+    constant: the chain is still one compiled dispatch."""
+    import jax.numpy as jnp
+
+    class Repeat(BatchTransformer):
+        def __init__(self, times):
+            self.times = jnp.asarray(times)
+
+        def apply_arrays(self, x):
+            return jnp.tile(x, (1, int(self.times)))
+
+    pipe = _chain(Shift(1), Repeat(2))
+    PipelineEnv.reset()
+    fitted = pipe.fit()
+    (fused,) = _fused_ops(fitted.graph)
+    before_f, before_u = _dispatch_counts()
+    out = fitted.apply_batch(ArrayDataset(np.ones((3, 4), np.float32)))
+    assert _dispatch_counts() == (before_f + 1, before_u)
+    np.testing.assert_array_equal(np.asarray(out.data), 2.0 * np.ones((3, 8)))
+    assert fused._eager_fallback is False and fused._lift is False
+
+
+def test_a_member_array_on_one_device_serves_a_batch_sharded_over_all():
+    """An array committed to one device is no argument of a program whose
+    input is sharded over the mesh: the chain closes over it, as before."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    devices = jax.devices()
+    if len(devices) < 2:
+        pytest.skip("needs a mesh of two devices or more")
+
+    class Weigh(BatchTransformer):
+        def __init__(self, w):
+            self.w = jax.device_put(w, devices[0])
+
+        def apply_arrays(self, x):
+            return x @ self.w
+
+    w = np.arange(12, dtype=np.float32).reshape(6, 2)
+    pipe = _chain(Shift(1), Weigh(w))
+    PipelineEnv.reset()
+    fitted = pipe.fit()
+    (fused,) = _fused_ops(fitted.graph)
+    mesh = Mesh(np.array(devices), ("data",))
+    rows = 2 * len(devices)
+    x = jax.device_put(np.ones((rows, 6), np.float32), NamedSharding(mesh, PartitionSpec("data")))
+    out = fitted.apply_batch(ArrayDataset(x))
+    np.testing.assert_array_equal(np.asarray(out.data), np.full((rows, 6), 2.0) @ w)
+    assert fused._lift is False and fused._eager_fallback is False
+
+
 def test_untraceable_member_falls_back_to_eager():
     class HostBranch(BatchTransformer):
         """Reads a concrete value at trace time — not jit-traceable."""
@@ -514,3 +588,184 @@ def test_synthetic_chain_fused_unfused_parity():
     b = np.asarray(unfused.apply_batch(ArrayDataset(x)).data, dtype=np.float64)
     err = np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30)
     assert err <= 1e-5
+
+
+# ------------------------------------------------- fitted gathers of branches
+
+
+def _branches(k, path=False, seed=0, d_in=6, d_out=8):
+    """k fusable branches over one input: cosine features, the last of
+    them a chain of two nodes where ``path`` (a branch may be a path)."""
+    from keystone_tpu.ops.stats.core import CosineRandomFeatures
+
+    out = [
+        CosineRandomFeatures.create(d_in, d_out, 0.7, seed=seed + i).to_pipeline()
+        for i in range(k)
+    ]
+    if path:
+        out[-1] = _chain(Scale(1.5), Shift(-2))
+    return out
+
+
+def _scoring(k, path=False, seed=0, classes=5, with_mapper=True, with_classifier=True):
+    from keystone_tpu.ops.learning.block import BlockLinearMapper
+    from keystone_tpu.ops.util.labels import MaxClassifier
+    from keystone_tpu.ops.util.vectors import VectorCombiner
+
+    pipe = Pipeline.gather(_branches(k, path, seed)) >> VectorCombiner()
+    if with_mapper:
+        rng = np.random.default_rng(seed + k)
+        d = 8 * k - (2 if path else 0)
+        pipe = pipe >> BlockLinearMapper(
+            rng.normal(size=(d, classes)).astype(np.float32), 8,
+            intercept=rng.normal(size=classes).astype(np.float32),
+            feature_mean=rng.normal(size=d).astype(np.float32),
+        )
+        if with_classifier:
+            pipe = pipe >> MaxClassifier()
+    return pipe
+
+
+def _fit_both(pipe):
+    PipelineEnv.reset()
+    fitted = pipe.fit()
+    PipelineEnv.reset()
+    with fusion_disabled():
+        unfused = pipe.fit()
+    return fitted, unfused
+
+
+def _gathers_folded():
+    return _names.metric(_names.FUSION_GATHERS).total()
+
+
+x_rows = np.random.default_rng(9).normal(size=(7, 6)).astype(np.float32)
+
+
+@pytest.mark.parametrize("k,path", [(2, False), (3, False), (3, True)])
+def test_fitted_gather_into_a_linear_map_is_one_fused_operator(k, path):
+    from keystone_tpu.ops.learning.block import BlockLinearMapper
+    from keystone_tpu.ops.util.labels import MaxClassifier
+    from keystone_tpu.workflow.fusion import FusedGather
+
+    before = _gathers_folded()
+    fitted, unfused = _fit_both(_scoring(k, path))
+    assert _gathers_folded() - before == 1
+    (fused,) = _fused_ops(fitted.graph)
+    assert len(fitted.graph.nodes) == 1
+    gather, mapper, classifier = fused.members
+    assert isinstance(gather, FusedGather) and len(gather.branches) == k
+    assert gather.label == "Gather[" + (
+        f"{k - 1}*CosineRandomFeatures,Fused[Scale[1.5]+Shift[-2.0]]" if path
+        else f"{k}*CosineRandomFeatures"
+    ) + "]"
+    assert isinstance(mapper, BlockLinearMapper)  # a direct member
+    assert isinstance(classifier, MaxClassifier)
+
+    before_f, before_u = _dispatch_counts()
+    got = np.asarray(fitted.apply_batch(ArrayDataset(x_rows)).data)
+    after_f, after_u = _dispatch_counts()
+    assert (after_f - before_f, after_u - before_u) == (1, 0)
+    np.testing.assert_array_equal(
+        got, np.asarray(unfused.apply_batch(ArrayDataset(x_rows)).data)
+    )
+
+
+@pytest.mark.parametrize("k", [2, 3])
+def test_fused_gather_features_to_the_bit_and_scores_to_rounding(k):
+    fitted, unfused = _fit_both(_scoring(k, with_mapper=False))
+    assert len(_fused_ops(fitted.graph)) == 1
+    np.testing.assert_array_equal(
+        np.asarray(fitted.apply_batch(ArrayDataset(x_rows)).data),
+        np.asarray(unfused.apply_batch(ArrayDataset(x_rows)).data),
+    )
+    fitted, unfused = _fit_both(_scoring(k, path=k == 3, with_classifier=False))
+    got = np.asarray(fitted.apply_batch(ArrayDataset(x_rows)).data)
+    ref = np.asarray(unfused.apply_batch(ArrayDataset(x_rows)).data)
+    np.testing.assert_allclose(got, ref, rtol=0, atol=1e-5 * np.abs(ref).max())
+
+
+def test_fused_gather_keeps_padded_rows_zero_and_per_datum_apply():
+    fitted, unfused = _fit_both(_scoring(3, path=True, with_classifier=False))
+    data = ArrayDataset(np.ones((6, 6), np.float32), num_examples=4)
+    out = fitted.apply_batch(data)
+    assert out.num_examples == 4
+    np.testing.assert_array_equal(np.asarray(out.data)[4:], 0.0)
+    # (an unfused graph runs a two-node branch a datum at a time as a
+    # row chain, which takes datasets only: the reference here has none)
+    fitted, unfused = _fit_both(_scoring(3, with_classifier=False))
+    for row in x_rows[:3]:
+        got, ref = np.asarray(fitted.apply(row)), np.asarray(unfused.apply(row))
+        assert got.shape == ref.shape
+        np.testing.assert_allclose(got, ref, rtol=0, atol=1e-5 * np.abs(ref).max())
+    # the features of one datum: a one-row product, summed in its own order
+    fitted, unfused = _fit_both(_scoring(2, with_mapper=False))
+    got, ref = np.asarray(fitted.apply(x_rows[0])), np.asarray(unfused.apply(x_rows[0]))
+    assert got.shape == ref.shape == (16,)
+    np.testing.assert_allclose(got, ref, rtol=0, atol=1e-6)
+
+
+def test_fused_gather_pickles_and_refuses_to_itself(tmp_path):
+    fitted, _ = _fit_both(_scoring(3, path=True))
+    path = str(tmp_path / "gather.pkl")
+    fitted.save(path)
+    loaded = FittedPipeline.load(path)
+    before = _gathers_folded()
+    assert loaded.fused() is loaded
+    assert fitted.fused() is fitted
+    assert _gathers_folded() == before
+    np.testing.assert_array_equal(
+        np.asarray(loaded.apply_batch(ArrayDataset(x_rows)).data),
+        np.asarray(fitted.apply_batch(ArrayDataset(x_rows)).data),
+    )
+
+
+def _gather_of(branches):
+    from keystone_tpu.ops.util.vectors import VectorCombiner
+
+    return Pipeline.gather(branches) >> VectorCombiner()
+
+
+def test_gather_with_a_bespoke_branch_is_left_alone():
+    from keystone_tpu.ops.util.gather import GatherTransformer
+
+    pipe = _gather_of(_branches(2) + [CustomBatch().to_pipeline()])
+    before = _gathers_folded()
+    fitted, _ = _fit_both(pipe)
+    assert _gathers_folded() == before
+    assert any(isinstance(op, GatherTransformer) for op in fitted.graph.operators.values())
+
+
+def test_gather_whose_branch_has_a_second_consumer_is_left_alone():
+    from keystone_tpu.ops.util.gather import GatherTransformer
+
+    pipe = _gather_of(_branches(2))
+    graph = pipe.graph
+    end = graph.get_dependencies(
+        next(n for n in graph.nodes if isinstance(graph.get_operator(n), GatherTransformer))
+    )[0]
+    graph, _ = graph.add_sink(end)  # the branch's output is read a second time
+    before = _gathers_folded()
+    out = fuse_graph(graph)
+    assert _gathers_folded() == before
+    assert any(isinstance(op, GatherTransformer) for op in out.operators.values())
+
+
+def test_timit_in_core_fit_is_not_planned_onto_streaming(monkeypatch):
+    """Fit time keeps the gather form: folded there, the estimator's
+    input would be one chain, and a bound input above 2 x chunk rows
+    would be streamed (another solver path)."""
+    from keystone_tpu.pipelines import timit
+    from keystone_tpu.workflow.fusion import FusedGather
+    from keystone_tpu.workflow.streaming import stream_chunk_rows
+
+    monkeypatch.setenv("KEYSTONE_STREAM_CHUNK_ROWS", "64")
+    train = timit.synthetic_timit(256, seed=3)
+    assert len(train.data) > 2 * stream_chunk_rows()
+    config = timit.TimitConfig(num_cosines=2, num_cosine_features=64, reg=5.0, num_epochs=1)
+    plans = _names.metric(_names.STREAM_PLANS).total()
+    PipelineEnv.reset()
+    fitted = timit.build_pipeline(config, train).fit()
+    assert _names.metric(_names.STREAM_PLANS).total() == plans
+    (fused,) = _fused_ops(fitted.graph)
+    assert isinstance(fused.members[0], FusedGather)

@@ -78,14 +78,23 @@ class _Recording(Estimator):
         return Identity()
 
 
+# Fitted, a gather into a VectorCombiner is one program that uploads its
+# input itself (workflow/fusion.py); a gather that is not concatenated
+# keeps its branches as nodes, which share one upload.
+
+
+def _joined(out):
+    return np.concatenate([np.asarray(p) for p in out.data], axis=1)
+
+
 def _through_apply_batch(branches, x):
-    fitted = (Pipeline.gather(branches) >> VectorCombiner()).fit()
-    return np.asarray(fitted.apply_batch(ArrayDataset(x)).data)
+    fitted = Pipeline.gather(branches).fit()
+    return _joined(fitted.apply_batch(ArrayDataset(x)))
 
 
 def _through_compiled_apply(branches, x):
-    fitted = (Pipeline.gather(branches) >> VectorCombiner()).fit()
-    return np.asarray(fitted.compiled_apply()(ArrayDataset(x)).data)
+    fitted = Pipeline.gather(branches).fit()
+    return _joined(fitted.compiled_apply()(ArrayDataset(x)))
 
 
 def _through_fit(branches, x):
@@ -115,6 +124,21 @@ def test_a_gather_of_k_branches_uploads_its_host_input_once(route, k):
     (upload,) = session.find("h2d")
     assert upload.attributes == {"site": SOURCE, "bytes": x.nbytes, "consumers": k}
     assert got.dtype == expected.dtype and np.array_equal(got, expected)
+
+
+@pytest.mark.parametrize("k", [2, 4])
+@pytest.mark.parametrize("route", ["apply_batch", "compiled_apply"])
+def test_a_fitted_gather_into_a_combiner_goes_up_once_in_its_one_program(route, k):
+    branches, x = _branches(k), _x()
+    fitted = (Pipeline.gather(branches) >> VectorCombiner()).fit()
+    apply = fitted.apply_batch if route == "apply_batch" else fitted.compiled_apply()
+    counts = _Counts()
+    with spans.tracing_session("t", sync_timings=False) as session:
+        got = np.asarray(apply(ArrayDataset(x)).data)
+    assert counts() == {"FusedTransformerOperator": (x.nbytes, 1, 0)}
+    (upload,) = session.find("h2d")
+    assert upload.attributes == {"site": "FusedTransformerOperator", "bytes": x.nbytes, "consumers": 1}
+    assert np.array_equal(got, _one_by_one(branches, x))
 
 
 def test_a_single_chain_uploads_in_its_consumer_as_before():
@@ -208,7 +232,7 @@ def test_consumers_are_counted_on_the_graph_that_runs():
     assert np.array_equal(np.asarray(twice.data), _one_by_one([cosine, cosine], x))
 
     chains = [b >> LinearRectifier(0.0, 0.25) for b in _branches(2)]
-    fitted = (Pipeline.gather(chains) >> VectorCombiner()).fit()
+    fitted = Pipeline.gather(chains).fit()
     labels = sorted(fitted.graph.get_operator(n).label for n in fitted.graph.nodes)
     assert labels.count("Fused[CosineRandomFeatures+LinearRectifier]") == 2
     counts = _Counts()
@@ -230,7 +254,7 @@ class _Witness(BatchTransformer):
 @pytest.mark.parametrize("route", ["apply_batch", "compiled_apply", "lazy apply"])
 def test_the_copy_is_shared_in_a_call_gone_after_it_and_made_again_by_the_next(route):
     witnesses, x = [_Witness(), _Witness()], _x()
-    pipeline = Pipeline.gather(witnesses) >> VectorCombiner()
+    pipeline = Pipeline.gather(witnesses)
     held = []  # the lazy result handles, which keep their executors alive
     if route == "lazy apply":
 
