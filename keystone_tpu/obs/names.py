@@ -270,7 +270,7 @@ SCHEMA: Dict[str, Tuple] = {
     SOLVER_FIT_SECONDS: ("histogram", "Solver fit wall time", ("solver",)),
     SOLVER_RUNG_ATTEMPTS: ("counter", "Degradation-ladder rung attempts inside solvers", ("solver",)),
     SOLVER_ITERATIONS: ("counter", "Host-level solver iterations (e.g. L-BFGS steps)", ("solver",)),
-    BCD_FACTOR_REUSE: ("counter", "In-core block_coordinate_descent calls, by program form: reused = each block's Gram and Cholesky factor computed once in a factor pass and reused in every epoch (num_epochs > 1), single_pass = factored inside the one pass (num_epochs == 1)", ("mode",)),
+    BCD_FACTOR_REUSE: ("counter", "Calls of linalg's block Gauss-Seidel epoch scan (block_coordinate_descent, its rematerialized form, bcd_from_gram), by program form: reused = each block's Gram and Cholesky factor computed once in a factor pass and reused in every epoch (num_epochs > 1), single_pass = factored inside the one pass (num_epochs == 1)", ("mode",)),
     KERNEL_PANELS: ("counter", "Kernel column panels computed by the exact kernel suite (ops/learning/kernel.py): blocks x epochs a KernelRidgeRegression fit, train blocks a KernelBlockLinearMapper request", ("site",)),
     KERNEL_PANEL_BYTES: ("gauge", "Bytes of the kernel panel that is live on one device during the last fit (a shard's rows x block) or request (a shard's test rows x block)", ("site",)),
     FEATURE_DRAWS: ("counter", "Banks of random-feature branches drawn on the host (CosineRandomFeatures.draw_branches: one count a bank, whatever its branches), by the host threads that drew them side by side; 1 = inline on the caller, no pool", ("workers",)),
@@ -279,7 +279,7 @@ SCHEMA: Dict[str, Tuple] = {
     CONV_PANELS: ("counter", "Convolution panels the fused convolution featurizer computes (ops/images/core.py FusedConvFeaturizer: row blocks x filter blocks a batch application, one (row_block, rx, ry, filter_block) float32 block of responses each), by site", ("site",)),
     CONV_KERNEL_PANELS: ("counter", "Of keystone_conv_panels_total, the panels the Pallas kernel computed (ops/pallas/conv_pool.py: responses normalised, rectified and pooled in VMEM, only the pooled sums written), by site; the rest went through XLA's form (a CPU, or a spec the kernel does not express)", ("site",)),
     CONV_PANEL_BYTES: ("gauge", "Bytes of the one convolution panel that is live during the last batch application of the fused convolution featurizer (row_block x rx x ry x filter_block x 4): the panel is bounded in rows and filters, whatever the batch", ("site",)),
-    GRAM_SYMMETRIC: ("counter", "Fits whose Gram products come from linalg.gram_sym (one count a streamed Gram fold, one a block_coordinate_descent call), by the column panels its width rule cuts the product into: the upper block triangle is computed and mirrored; 1 = the single full product", ("panels",)),
+    GRAM_SYMMETRIC: ("counter", "Fits whose Gram products come from linalg.gram_sym (one count a streamed Gram fold, one a block_coordinate_descent or block_coordinate_descent_rematerialized call), by the column panels its width rule cuts the product into: the upper block triangle is computed and mirrored; 1 = the single full product", ("panels",)),
     SKETCH_FITS: ("counter", "Sketched least-squares fits completed, by sketch variant (countsketch/srht)", ("variant",)),
     SKETCH_SIZE: ("gauge", "Sketch rows s chosen for the last sketched fit (knob/tuned/width default)", ()),
     SKETCH_STATE_BYTES: ("gauge", "Bytes of the last sketched fit's O(s·d) carry — the number KV308 compares to the device budget", ()),

@@ -304,13 +304,7 @@ def _conv_bcd_step_fn(
         else:
             inv_sd = jnp.ones_like(mu)
         a_b = (a_raw - mu) * inv_sd * mask_local
-        eye = jnp.eye(bs, dtype=a_b.dtype)
-        r_local = y_local - p_local + linalg.mm(a_b, w_b)
-        g = lax.psum(linalg.mm(a_b.T, a_b), axes)
-        cvec = lax.psum(linalg.mm(a_b.T, r_local), axes)
-        factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)
-        w_b_new = jax.scipy.linalg.cho_solve(factor, cvec)
-        p_local = p_local + linalg.mm(a_b, w_b_new - w_b)
+        w_b_new, p_local = linalg.block_update(a_b, y_local, p_local, w_b, reg, axes)
         return w_b_new, p_local, mu, inv_sd
 
     fn = shard_map(

@@ -21,7 +21,7 @@ import contextlib
 import functools
 import threading
 import warnings
-from typing import Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -687,18 +687,148 @@ def tsqr_svd(
 
 
 # ---------------------------------------------------------------------- BCD
-
-
-# The parts of one BCD block step, as `jax.named_scope`s: the same five
-# names in every variant of the step (in-core, rematerialized, streamed,
-# from-Gram, 2-D), so a device trace splits Gram against Cholesky against
-# the rest whatever numbers XLA gives its fusions. Metadata only.
+#
+# Block Gauss-Seidel is written once: the epoch scan `_block_gauss_seidel`
+# runs `_block_update` over a `_BlockSource`, the one thing the forms
+# differ in; the one-block programs and the 2-D form call `block_update`.
+# The parts of the update are `jax.named_scope`s, each opened at one site
+# (`_block_update`, `_gram`, `_cholesky`), so a device trace of any form
+# splits Gram against Cholesky against the rest. Metadata only.
 BCD_RESIDUAL = "bcd/residual"
 BCD_GRAM = "bcd/gram"
 BCD_CROSS = "bcd/cross"
 BCD_CHOLESKY = "bcd/cholesky"
 BCD_UPDATE = "bcd/update"
 BCD_SCOPES = (BCD_RESIDUAL, BCD_GRAM, BCD_CROSS, BCD_CHOLESKY, BCD_UPDATE)
+
+
+class _BlockSource(NamedTuple):
+    """Where block b's system (A_bᵀA_b + λI) W_b = A_bᵀ(Y − P + A_b W_b)
+    comes from. ``block(b, start)`` is what the others read for block b;
+    ``gram`` is A_bᵀA_b summed over the mesh; ``residual`` then ``cross``
+    make the right-hand side; ``update`` moves the carried state (from
+    ``init()``) to the new block weights."""
+
+    block: Callable
+    gram: Callable
+    residual: Callable
+    cross: Callable
+    update: Callable
+    init: Callable
+
+
+def _panel_source(panel, y_local, axes) -> _BlockSource:
+    """Block b as this shard's rows of its panel of A (``panel(b, start)``;
+    unused by the one-block programs), the predictions P carried."""
+    return _BlockSource(
+        block=panel,
+        gram=lambda a_b: lax.psum(gram_sym(a_b), axes),
+        residual=lambda a_b, p_local, w, w_b: y_local - p_local + mm(a_b, w_b),
+        cross=lambda a_b, r_local, w_b: lax.psum(mm(a_b.T, r_local), axes),
+        update=lambda a_b, p_local, w_b, w_b_new: p_local + mm(a_b, w_b_new - w_b),
+        init=lambda: jnp.zeros_like(y_local),
+    )
+
+
+def _statistics_source(gc, cc, block_size: int) -> _BlockSource:
+    """Block b as slices of the centred statistics Gc = AᵀA, Cc = AᵀY:
+    A_bᵀA_b is Gc's diagonal block, and the right-hand side is
+    (AᵀY)_b − (AᵀA·W)_b + A_bᵀA_b·W_b. Nothing is carried."""
+    d, k = cc.shape
+
+    def diagonal(start):
+        return lax.dynamic_slice(gc, (start, start), (block_size, block_size))
+
+    return _BlockSource(
+        block=lambda b, start: start,
+        gram=diagonal,
+        # A_bᵀP, the predictions as block b sees them
+        residual=lambda start, _, w, w_b: mm(lax.dynamic_slice(gc, (start, 0), (block_size, d)), w),
+        cross=lambda start, a_bt_p, w_b: (
+            lax.dynamic_slice(cc, (start, 0), (block_size, k)) - a_bt_p + mm(diagonal(start), w_b)
+        ),
+        update=lambda start, state, w_b, w_b_new: state,
+        init=tuple,
+    )
+
+
+def _gram(source: _BlockSource, blk):
+    with jax.named_scope(BCD_GRAM):
+        return source.gram(blk)
+
+
+def _cholesky(g, reg, eye, rhs=None, factor=None):
+    """The lower factor of ``g + λI`` (or ``factor``), solved against ``rhs`` if given."""
+    with jax.named_scope(BCD_CHOLESKY):
+        if factor is None:
+            factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)[0]
+        if rhs is None:
+            return factor
+        return jax.scipy.linalg.cho_solve((factor, True), rhs)
+
+
+def _block_update(source, blk, state, w, w_b, start, reg, eye, factors=None, b=None):
+    """One block's update in every form; returns ``(w, state)`` with ``w_b``'s
+    rows of ``w`` (from ``start``) rewritten. The factor is ``factors[b]``
+    where a factor pass made it, else made here from the block's Gram."""
+    with jax.named_scope(BCD_RESIDUAL):
+        r = source.residual(blk, state, w, w_b)
+    g = _gram(source, blk) if factors is None else None
+    with jax.named_scope(BCD_CROSS):
+        c = source.cross(blk, r, w_b)
+    factor = None if factors is None else lax.dynamic_index_in_dim(factors, b, keepdims=False)
+    w_b_new = _cholesky(g, reg, eye, c, factor)
+    with jax.named_scope(BCD_UPDATE):
+        state = source.update(blk, state, w_b, w_b_new)
+        w = lax.dynamic_update_slice(w, w_b_new, (start, 0))
+    return w, state
+
+
+def _block_gauss_seidel(source, num_blocks: int, block_size: int, num_epochs: int, reg, k: int, dtype):
+    """One scan over epoch x block of ``source``'s blocks; returns the
+    (num_blocks · block_size, k) weights. No factor depends on the
+    iterate, so with ``num_epochs > 1`` a factor pass ahead of the scan
+    makes each once, stacked (``d · block_size`` floats a device: 256 MiB
+    at d = 16384, block 4096); with one epoch the step factors in place."""
+    eye = jnp.eye(block_size, dtype=dtype)
+    w0 = jnp.zeros((num_blocks * block_size, k), dtype=dtype)
+    state0 = source.init()
+    factors = lax.map(
+        lambda b: _cholesky(_gram(source, source.block(b, b * block_size)), reg, eye),
+        jnp.arange(num_blocks),
+    ) if bcd_factor_mode(num_epochs) == "reused" else None
+
+    def step(carry, b):
+        w, state = carry
+        start = b * block_size
+        blk = source.block(b, start)
+        w_b = lax.dynamic_slice(w, (start, 0), (block_size, k))
+        return _block_update(source, blk, state, w, w_b, start, reg, eye, factors, b), None
+
+    blocks = jnp.tile(jnp.arange(num_blocks), num_epochs)
+    (w, _), _ = lax.scan(step, (w0, state0), blocks)
+    return w
+
+
+def block_update(a_b, y_local, p_local, w_b, reg, axes):
+    """``(w_b_new, p_local)`` from block panel ``a_b`` (this shard's rows in a
+    ``shard_map`` over ``axes``), factored in place: the step of the
+    one-block programs (streamed BCD, conv_block.py) and the 2-D form."""
+    eye = jnp.eye(a_b.shape[1], dtype=a_b.dtype)
+    return _block_update(_panel_source(None, y_local, axes), a_b, p_local, w_b, w_b, 0, reg, eye)
+
+
+def bcd_factor_mode(num_epochs: int) -> str:
+    """Which form of the epoch scan ``num_epochs`` compiles to: the ``mode``
+    label of ``keystone_bcd_factor_reuse_total`` and the ``factor_reuse``
+    attribute of the ``solver:bcd`` span."""
+    return "reused" if num_epochs > 1 else "single_pass"
+
+
+def _count_factor_rule(num_epochs: int) -> None:
+    from ..obs import names as _names
+
+    _names.metric(_names.BCD_FACTOR_REUSE).inc(mode=bcd_factor_mode(num_epochs))
 
 
 def block_coordinate_descent(
@@ -720,20 +850,8 @@ def block_coordinate_descent(
 
     where P are current predictions. Per-shard products ride the MXU;
     cross-shard sums are one psum per block; the whole solve is a single
-    compiled program — no host round trips inside training.
-
-    Neither ``A_bᵀA_b`` nor its Cholesky factor depends on the iterate, so
-    with ``num_epochs > 1`` the program is two loops: a factor pass over
-    the blocks (Gram at HIGHEST, float32 Cholesky), stacked to
-    ``(num_blocks, block_size, block_size)`` float32, then one scan over
-    epoch×block whose body is residual, cross term, two triangular solves
-    against the stacked factor, update — the same Gauss-Seidel iterates
-    with each Gram computed once, not once per epoch. The stack costs
-    ``d · block_size · 4`` bytes per device (256 MiB at d = 16384, block
-    4096). With ``num_epochs == 1`` nothing would be reused, so the
-    program is the single scan that factors inside each step and holds
-    no stack. ``keystone_bcd_factor_reuse_total{mode}`` counts the calls
-    of each form.
+    compiled program — no host round trips inside training: the epoch
+    scan :func:`_block_gauss_seidel` over the panels of ``a``.
 
     ``a`` is (n, d) row-sharded (rows may be zero-padded), ``y`` is (n, k).
     ``d`` must be a multiple of ``block_size`` (pad features if needed).
@@ -755,77 +873,27 @@ def block_coordinate_descent(
     reg_arr = jnp.asarray(reg, dtype=a.dtype)
     # Cost-observatory attribution (obs/cost.py): avals, not the arrays
     # — a/y may be donated into the solve below.
-    from ..obs import cost as _cost, names as _names
+    from ..obs import cost as _cost
 
-    _names.metric(_names.BCD_FACTOR_REUSE).inc(mode=bcd_factor_mode(num_epochs))
+    _count_factor_rule(num_epochs)
     _count_gram_panels(block_size)
     _cost.note_solver_call("solver_bcd", fn, (a, y, reg_arr))
     return fn(a, y, reg_arr)
 
 
-def bcd_factor_mode(num_epochs: int) -> str:
-    """Which form of the in-core program ``num_epochs`` compiles to: the
-    ``mode`` label of ``keystone_bcd_factor_reuse_total`` and the
-    ``factor_reuse`` attribute of the ``solver:bcd`` span."""
-    return "reused" if num_epochs > 1 else "single_pass"
-
-
 @_mode_cached()
 def _bcd_fn(mesh: Mesh, num_epochs: int, block_size: int, donate_xy: bool = False):
     axes = row_axes(mesh)
-    reuse = bcd_factor_mode(num_epochs) == "reused"
 
     def per_device(a_local, y_local, reg):
-        d = a_local.shape[1]
-        k = y_local.shape[1]
-        num_blocks = d // block_size
-        eye = jnp.eye(block_size, dtype=a_local.dtype)
-        w0 = jnp.zeros((d, k), dtype=a_local.dtype)
-        p0 = jnp.zeros_like(y_local)
-
-        def block_at(start):
-            return lax.dynamic_slice(a_local, (0, start), (a_local.shape[0], block_size))
-
-        def gram(a_b):
-            with jax.named_scope(BCD_GRAM):
-                return lax.psum(gram_sym(a_b), axes)
-
-        def factorize(g):
-            with jax.named_scope(BCD_CHOLESKY):
-                return jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)[0]
-
-        # The factor pass: each block's Gram and Cholesky factor once per
-        # fit, as neither depends on the iterate.
-        factors = lax.map(
-            lambda block_idx: factorize(gram(block_at(block_idx * block_size))),
-            jnp.arange(num_blocks),
-        ) if reuse else None
-
-        def block_step(carry, block_idx):
-            w, p_local = carry
-            start = block_idx * block_size
-            a_b = block_at(start)
-            w_b = lax.dynamic_slice(w, (start, 0), (block_size, k))
-            with jax.named_scope(BCD_RESIDUAL):
-                r_local = y_local - p_local + mm(a_b, w_b)
-            if not reuse:
-                g = gram(a_b)
-            with jax.named_scope(BCD_CROSS):
-                c = lax.psum(mm(a_b.T, r_local), axes)
-            if reuse:
-                factor = lax.dynamic_index_in_dim(factors, block_idx, keepdims=False)
-            else:
-                factor = factorize(g)
-            with jax.named_scope(BCD_CHOLESKY):
-                w_b_new = jax.scipy.linalg.cho_solve((factor, True), c)
-            with jax.named_scope(BCD_UPDATE):
-                p_local = p_local + mm(a_b, w_b_new - w_b)
-                w = lax.dynamic_update_slice(w, w_b_new, (start, 0))
-            return (w, p_local), None
-
-        blocks = jnp.tile(jnp.arange(num_blocks), num_epochs)
-        (w, _), _ = lax.scan(block_step, (w0, p0), blocks)
-        return w
+        rows, d = a_local.shape
+        source = _panel_source(
+            lambda b, start: lax.dynamic_slice(a_local, (0, start), (rows, block_size)),
+            y_local, axes,
+        )
+        return _block_gauss_seidel(
+            source, d // block_size, block_size, num_epochs, reg, y_local.shape[1], a_local.dtype
+        )
 
     return jax.jit(
         shard_map(
@@ -863,31 +931,8 @@ def _bcd_remat_fn(mesh: Mesh, num_epochs: int, block_size: int,
     def per_device(y_local, reg):
         rows, k = y_local.shape
         offset = _linear_row_index(axes, mesh) * rows
-        eye = jnp.eye(block_size, dtype=y_local.dtype)
-        w0 = jnp.zeros((num_blocks * block_size, k), y_local.dtype)
-        p0 = jnp.zeros_like(y_local)
-
-        def block_step(carry, b):
-            w, p_local = carry
-            a_b = block_fn(b, offset, rows)          # (rows, block_size)
-            w_b = lax.dynamic_slice(w, (b * block_size, 0), (block_size, k))
-            with jax.named_scope(BCD_RESIDUAL):
-                r_local = y_local - p_local + mm(a_b, w_b)
-            with jax.named_scope(BCD_GRAM):
-                g = lax.psum(mm(a_b.T, a_b), axes)
-            with jax.named_scope(BCD_CROSS):
-                c = lax.psum(mm(a_b.T, r_local), axes)
-            with jax.named_scope(BCD_CHOLESKY):
-                factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)
-                w_b_new = jax.scipy.linalg.cho_solve(factor, c)
-            with jax.named_scope(BCD_UPDATE):
-                p_local = p_local + mm(a_b, w_b_new - w_b)
-                w = lax.dynamic_update_slice(w, w_b_new, (b * block_size, 0))
-            return (w, p_local), None
-
-        blocks = jnp.tile(jnp.arange(num_blocks), num_epochs)
-        (w, _), _ = lax.scan(block_step, (w0, p0), blocks)
-        return w
+        source = _panel_source(lambda b, start: block_fn(b, offset, rows), y_local, axes)
+        return _block_gauss_seidel(source, num_blocks, block_size, num_epochs, reg, k, y_local.dtype)
 
     return jax.jit(
         shard_map(
@@ -911,18 +956,20 @@ def block_coordinate_descent_rematerialized(
     HBM *and* host RAM (TIMIT-wide at full n is 144 GB; the streaming
     path needs it in host RAM, this path needs only a generator).
 
-    Same per-block Gauss-Seidel update as :func:`block_coordinate_descent`
-    (the conv-block solver applies the identical idea with a conv
-    featurizer — ops/learning/conv_block.py); ``block_fn(b, row_offset,
-    rows)`` must return the local (rows, block_size) panel of block ``b``
-    for the shard whose global row range starts at ``row_offset``, as a
-    pure traceable function (e.g. seeded ``jax.random`` generation, or a
-    featurizer over a resident small input). ``y`` is row-sharded;
-    returns the replicated (num_blocks·block_size, k) weights.
+    The epoch scan of :func:`block_coordinate_descent` over panels made
+    by ``block_fn`` (its factor pass makes each once more; the conv-block
+    solver applies the idea with a conv featurizer — ops/learning/
+    conv_block.py). ``block_fn(b, row_offset, rows)`` must return the
+    local (rows, block_size) panel of block ``b`` for the shard whose
+    global row range starts at ``row_offset``, as a pure traceable
+    function (e.g. seeded ``jax.random`` generation). ``y`` is
+    row-sharded; returns the replicated (num_blocks·block_size, k) weights.
     """
     mesh = mesh or get_mesh()
     fn = _bcd_remat_fn(mesh, int(num_epochs), int(block_size),
                        int(num_blocks), block_fn)
+    _count_factor_rule(num_epochs)
+    _count_gram_panels(block_size)
     return fn(y, jnp.asarray(reg, dtype=jnp.float32))
 
 
@@ -942,23 +989,9 @@ def _bcd_stream_step_fn(mesh: Mesh):
     # for temporaries, so per-step residency stays one panel + one
     # predictions buffer instead of two of each.
     def per_device(a_b_local, mask_local, mu_block, y_local, p_local, w_b, reg):
-        bs = a_b_local.shape[1]
-        k = y_local.shape[1]
-        eye = jnp.eye(bs, dtype=a_b_local.dtype)
         # Center on device (padding rows stay exactly zero via the mask).
         a_b = (a_b_local - mu_block) * mask_local
-        with jax.named_scope(BCD_RESIDUAL):
-            r_local = y_local - p_local + mm(a_b, w_b)
-        with jax.named_scope(BCD_GRAM):
-            g = lax.psum(mm(a_b.T, a_b), axes)
-        with jax.named_scope(BCD_CROSS):
-            c = lax.psum(mm(a_b.T, r_local), axes)
-        with jax.named_scope(BCD_CHOLESKY):
-            factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)
-            w_b_new = jax.scipy.linalg.cho_solve(factor, c)
-        with jax.named_scope(BCD_UPDATE):
-            p_local = p_local + mm(a_b, w_b_new - w_b)
-        return w_b_new, p_local
+        return block_update(a_b, y_local, p_local, w_b, reg, axes)
 
     return jax.jit(
         shard_map(
@@ -1180,33 +1213,10 @@ def solve_from_gram(gc, cc, reg) -> jnp.ndarray:
 @_mode_cached()
 def _bcd_gram_fn(num_epochs: int, block_size: int):
     def run(gc, cc, reg):
-        d = gc.shape[0]
-        k = cc.shape[1]
-        num_blocks = d // block_size
-        eye = jnp.eye(block_size, dtype=gc.dtype)
-        w0 = jnp.zeros((d, k), dtype=gc.dtype)
-
-        def block_step(w, block_idx):
-            start = block_idx * block_size
-            with jax.named_scope(BCD_GRAM):  # here a slice of the statistics
-                g_rows = lax.dynamic_slice(gc, (start, 0), (block_size, d))
-                g_bb = lax.dynamic_slice(g_rows, (0, start), (block_size, block_size))
-            w_b = lax.dynamic_slice(w, (start, 0), (block_size, k))
-            # A_bᵀ(Y − P + A_b W_b) expressed in statistics:
-            #   (AᵀY)_b − (AᵀA·W)_b + A_bᵀA_b·W_b
-            with jax.named_scope(BCD_CROSS):
-                c_b = lax.dynamic_slice(cc, (start, 0), (block_size, k))
-            with jax.named_scope(BCD_RESIDUAL):
-                rhs = c_b - mm(g_rows, w) + mm(g_bb, w_b)
-            with jax.named_scope(BCD_CHOLESKY):
-                factor = jax.scipy.linalg.cho_factor(g_bb + reg * eye, lower=True)
-                w_b_new = jax.scipy.linalg.cho_solve(factor, rhs)
-            with jax.named_scope(BCD_UPDATE):
-                return lax.dynamic_update_slice(w, w_b_new, (start, 0)), None
-
-        blocks = jnp.tile(jnp.arange(num_blocks), num_epochs)
-        w, _ = lax.scan(block_step, w0, blocks)
-        return w
+        source = _statistics_source(gc, cc, block_size)
+        return _block_gauss_seidel(
+            source, gc.shape[0] // block_size, block_size, num_epochs, reg, cc.shape[1], gc.dtype
+        )
 
     return jax.jit(run)
 
@@ -1219,10 +1229,11 @@ def bcd_from_gram(
     block_size: int,
 ) -> jnp.ndarray:
     """Feature-block Gauss-Seidel least squares driven entirely by the
-    centered Gram statistics — the identical per-block update (and block
-    order) as :func:`block_coordinate_descent`, so a streaming fit
-    matches the materialized fit to accumulation rounding. ``gc`` must
-    be (d_pad, d_pad) with d_pad a multiple of ``block_size`` (zero
+    centered Gram statistics — the epoch scan of
+    :func:`block_coordinate_descent` (the same update, block order and
+    factor pass) over blocks sliced from the statistics, so a streaming
+    fit matches the materialized fit to accumulation rounding. ``gc``
+    must be (d_pad, d_pad) with d_pad a multiple of ``block_size`` (zero
     pad rows/cols are inert: λ keeps the factor PD, exactly as the
     in-core solver's zero column padding). Returns (d_pad, k) weights.
     """
@@ -1230,6 +1241,7 @@ def bcd_from_gram(
     if d % block_size != 0:
         raise ValueError(f"d={d} not divisible by block_size={block_size}")
     fn = _bcd_gram_fn(int(num_epochs), int(block_size))
+    _count_factor_rule(num_epochs)
     return fn(gc, cc, jnp.asarray(reg, dtype=gc.dtype))
 
 
@@ -1296,7 +1308,8 @@ def block_coordinate_descent_2d(
 
     Block update order is (local block, model group)-major — a fixed
     permutation of the reference's sequential order with the identical
-    fixed point (AᵀA+λI)W = AᵀY.
+    fixed point (AᵀA+λI)W = AᵀY. The loop is its own (one ``all_to_all``
+    serves a local block's M updates); each is :func:`block_update`.
 
     ``a`` must be laid out by ``prepare_block_sharded(a)`` and ``y`` by
     ``prepare_block_sharded(y, fine_rows=True)``. d must divide into
@@ -1326,7 +1339,6 @@ def _bcd2d_fn(mesh: Mesh, num_epochs: int, block_size: int):
         k = y_fine.shape[1]
         num_local_blocks = d_loc // block_size
         j = lax.axis_index(MODEL_AXIS)
-        eye = jnp.eye(block_size, dtype=a_local.dtype)
         w0 = jnp.zeros((d_loc, k), dtype=a_local.dtype)
         p0 = jnp.zeros_like(y_fine)
 
@@ -1350,22 +1362,12 @@ def _bcd2d_fn(mesh: Mesh, num_epochs: int, block_size: int):
                     jnp.where(j == jp, w_b_own, jnp.zeros_like(w_b_own)),
                     MODEL_AXIS,
                 )
-                with jax.named_scope(BCD_RESIDUAL):
-                    r = y_fine - p + mm(a_j, w_b_old)
-                with jax.named_scope(BCD_GRAM):
-                    g = lax.psum(mm(a_j.T, a_j), all_axes)
-                with jax.named_scope(BCD_CROSS):
-                    c = lax.psum(mm(a_j.T, r), all_axes)
-                with jax.named_scope(BCD_CHOLESKY):
-                    factor = jax.scipy.linalg.cho_factor(g + reg * eye, lower=True)
-                    w_b_new = jax.scipy.linalg.cho_solve(factor, c)
-                with jax.named_scope(BCD_UPDATE):
-                    p = p + mm(a_j, w_b_new - w_b_old)
-                    w_local = jnp.where(
-                        j == jp,
-                        lax.dynamic_update_slice(w_local, w_b_new, (start, 0)),
-                        w_local,
-                    )
+                w_b_new, p = block_update(a_j, y_fine, p, w_b_old, reg, all_axes)
+                w_local = jnp.where(
+                    j == jp,
+                    lax.dynamic_update_slice(w_local, w_b_new, (start, 0)),
+                    w_local,
+                )
             return (w_local, p), None
 
         blocks = jnp.tile(jnp.arange(num_local_blocks), num_epochs)

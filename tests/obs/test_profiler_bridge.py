@@ -356,7 +356,10 @@ def _mesh1d():
     return make_mesh(devices=jax.devices()[:8])
 
 
-def _lower_bcd(variant):
+def _lower_bcd(variant, num_epochs=2):
+    """One form of block Gauss-Seidel, lowered: the epoch scan over each
+    block source, the 2-D form and the host-driven one-block step (the
+    last two call the scan's per-block update)."""
     from keystone_tpu.parallel import linalg
     from keystone_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, make_mesh
 
@@ -364,21 +367,21 @@ def _lower_bcd(variant):
     a = jax.ShapeDtypeStruct((64, 16), f32)
     y = jax.ShapeDtypeStruct((64, 3), f32)
     reg = jax.ShapeDtypeStruct((), f32)
-    if variant == "in_core":
-        return linalg._bcd_fn(_mesh1d(), 2, 8, False).lower(a, y, reg)
+    if variant == "in_core":  # the in-memory source
+        return linalg._bcd_fn(_mesh1d(), num_epochs, 8, False).lower(a, y, reg)
     if variant == "rematerialized":
         def block_fn(b, offset, rows):
             return jnp.ones((rows, 8), f32) * (b + 1)
 
-        return linalg._bcd_remat_fn(_mesh1d(), 2, 8, 2, block_fn).lower(y, reg)
-    if variant == "two_d":
-        mesh = make_mesh((4, 2), (DATA_AXIS, MODEL_AXIS), devices=jax.devices()[:8])
-        return linalg._bcd2d_fn(mesh, 2, 4).lower(a, y, reg)
-    if variant == "from_gram":
+        return linalg._bcd_remat_fn(_mesh1d(), num_epochs, 8, 2, block_fn).lower(y, reg)
+    if variant == "from_gram":  # the statistics source
         gc = jax.ShapeDtypeStruct((16, 16), f32)
         cc = jax.ShapeDtypeStruct((16, 3), f32)
-        return linalg._bcd_gram_fn(2, 8).lower(gc, cc, reg)
-    assert variant == "streamed"
+        return linalg._bcd_gram_fn(num_epochs, 8).lower(gc, cc, reg)
+    if variant == "two_d":
+        mesh = make_mesh((4, 2), (DATA_AXIS, MODEL_AXIS), devices=jax.devices()[:8])
+        return linalg._bcd2d_fn(mesh, num_epochs, 4).lower(a, y, reg)
+    assert variant == "streamed"  # the one-block step
     panel = jax.ShapeDtypeStruct((64, 8), f32)
     mask = jax.ShapeDtypeStruct((64, 1), f32)
     mu = jax.ShapeDtypeStruct((8,), f32)
@@ -387,12 +390,22 @@ def _lower_bcd(variant):
 
 
 @pytest.mark.parametrize(
-    "variant", ["in_core", "rematerialized", "two_d", "from_gram", "streamed"]
+    "variant,num_epochs",
+    [
+        pytest.param("in_core", 2, id="in_core"),
+        pytest.param("in_core", 1, id="in_core-single_pass"),
+        pytest.param("rematerialized", 2, id="rematerialized"),
+        pytest.param("rematerialized", 1, id="rematerialized-single_pass"),
+        pytest.param("from_gram", 2, id="from_gram"),
+        pytest.param("from_gram", 1, id="from_gram-single_pass"),
+        pytest.param("two_d", 2, id="two_d"),
+        pytest.param("streamed", 1, id="streamed"),
+    ],
 )
-def test_every_bcd_variant_carries_the_same_five_scopes(variant):
+def test_every_bcd_variant_carries_the_same_five_scopes(variant, num_epochs):
     from keystone_tpu.parallel.linalg import BCD_SCOPES
 
-    text = _lower_bcd(variant).as_text(debug_info=True)
+    text = _lower_bcd(variant, num_epochs).as_text(debug_info=True)
     assert BCD_SCOPES == (
         "bcd/residual", "bcd/gram", "bcd/cross", "bcd/cholesky", "bcd/update"
     )
@@ -400,6 +413,20 @@ def test_every_bcd_variant_carries_the_same_five_scopes(variant):
         assert re.search(rf'["/]{scope}/', text), scope
     assert re.search(r"bcd/gram/dot_general|bcd/gram/dynamic_slice", text)
     assert "bcd/cholesky/jit(_cholesky)" in text
+
+
+def test_each_bcd_scope_is_opened_at_one_site():
+    """Block Gauss-Seidel is written once: in the solver kernels each of
+    the five scopes is opened in one place, which every form runs."""
+    import inspect
+
+    from keystone_tpu.ops.learning import conv_block
+    from keystone_tpu.parallel import linalg
+
+    source = inspect.getsource(linalg) + inspect.getsource(conv_block)
+    opened = re.findall(r"named_scope\((BCD_\w+)\)", source)
+    assert sorted(opened) == sorted(("BCD_RESIDUAL", "BCD_GRAM", "BCD_CROSS", "BCD_CHOLESKY", "BCD_UPDATE"))
+    assert "cho_factor" not in inspect.getsource(conv_block)  # its step is linalg.block_update
 
 
 def test_the_streamed_gram_fold_carries_its_scope():

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from keystone_tpu.parallel import linalg
 from keystone_tpu.parallel.mesh import make_mesh, use_mesh
@@ -141,13 +142,50 @@ def _gauss_seidel(a, y, reg, num_epochs, block_size):
     return w
 
 
+def _bcd_by_source(source, a, y, reg, num_epochs, block_size):
+    """The epoch scan over one block source: the panels of ``a`` in
+    memory, the same panels made by a ``block_fn``, or slices of the
+    statistics AᵀA, AᵀY."""
+    num_blocks = a.shape[1] // block_size
+    if source == "statistics":
+        a64, y64 = a.astype(np.float64), y.astype(np.float64)
+        gc, cc = (a64.T @ a64).astype(np.float32), (a64.T @ y64).astype(np.float32)
+        return linalg.bcd_from_gram(gc, cc, reg=reg, num_epochs=num_epochs, block_size=block_size)
+    ys = linalg.prepare_row_sharded(y)
+    if source == "rematerialized":
+        padded = jnp.asarray(np.pad(a, ((0, ys.shape[0] - a.shape[0]), (0, 0))))
+
+        def block_fn(b, offset, rows):
+            return jax.lax.dynamic_slice(padded, (offset, b * block_size), (rows, block_size))
+
+        return linalg.block_coordinate_descent_rematerialized(
+            block_fn, ys, reg=reg, num_epochs=num_epochs, block_size=block_size, num_blocks=num_blocks,
+        )
+    assert source == "in_memory"
+    return linalg.block_coordinate_descent(
+        linalg.prepare_row_sharded(a), ys, reg=reg, num_epochs=num_epochs, block_size=block_size,
+    )
+
+
 @pytest.mark.parametrize("rows", [96, 93], ids=["rows_whole", "rows_padded"])
 @pytest.mark.parametrize("reg", [0.0, 1e-3], ids=["reg_floor", "reg_1e-3"])
 @pytest.mark.parametrize("num_blocks", [1, 4])
-@pytest.mark.parametrize("num_epochs", [1, 2, 5])
-def test_bcd_matches_plain_gauss_seidel(mesh, num_epochs, num_blocks, reg, rows):
+@pytest.mark.parametrize(
+    "source,num_epochs",
+    [
+        pytest.param("in_memory", 1, id="1"),
+        pytest.param("in_memory", 2, id="2"),
+        pytest.param("in_memory", 5, id="5"),
+        pytest.param("rematerialized", 1, id="rematerialized-1"),
+        pytest.param("rematerialized", 3, id="rematerialized-3"),
+        pytest.param("statistics", 1, id="statistics-1"),
+        pytest.param("statistics", 3, id="statistics-3"),
+    ],
+)
+def test_bcd_matches_plain_gauss_seidel(mesh, source, num_epochs, num_blocks, reg, rows):
     """The factor pass changes how often a block is factored, not the
-    iterates: every (epochs, blocks) form agrees with the plain loop."""
+    iterates: every (source, epochs, blocks) form of the one epoch scan
+    agrees with the plain loop."""
     from keystone_tpu.ops.learning.block import _scale_aware_reg_floor
 
     block_size = 8
@@ -156,24 +194,24 @@ def test_bcd_matches_plain_gauss_seidel(mesh, num_epochs, num_blocks, reg, rows)
     if reg == 0.0:  # what the estimator passes in for reg = 0
         reg = _scale_aware_reg_floor(a, rows)
     with use_mesh(mesh):
-        w = linalg.block_coordinate_descent(
-            linalg.prepare_row_sharded(a),
-            linalg.prepare_row_sharded(y),
-            reg=reg,
-            num_epochs=num_epochs,
-            block_size=block_size,
-        )
+        w = _bcd_by_source(source, a, y, reg, num_epochs, block_size)
     expected = _gauss_seidel(a, y, reg, num_epochs, block_size)
     np.testing.assert_allclose(np.asarray(w), expected, rtol=1e-4, atol=1e-5)
 
 
-def _lowered_bcd_functions(mesh, num_epochs, num_blocks=4, block_size=8, k=3):
-    """The lowered in-core program as (whole text, {function name: body})."""
+def _lowered_bcd_functions(mesh, num_epochs, num_blocks=4, block_size=8, k=3, source="in_memory"):
+    """The lowered program of the in-memory (or statistics) source as
+    (whole text, {function name: body})."""
     f32 = np.float32
-    a = jax.ShapeDtypeStruct((64, num_blocks * block_size), f32)
-    y = jax.ShapeDtypeStruct((64, k), f32)
+    d = num_blocks * block_size
     reg = jax.ShapeDtypeStruct((), f32)
-    text = linalg._bcd_fn(mesh, num_epochs, block_size, False).lower(a, y, reg).as_text()
+    if source == "statistics":
+        gc, cc = jax.ShapeDtypeStruct((d, d), f32), jax.ShapeDtypeStruct((d, k), f32)
+        text = linalg._bcd_gram_fn(num_epochs, block_size).lower(gc, cc, reg).as_text()
+    else:
+        a = jax.ShapeDtypeStruct((64, d), f32)
+        y = jax.ShapeDtypeStruct((64, k), f32)
+        text = linalg._bcd_fn(mesh, num_epochs, block_size, False).lower(a, y, reg).as_text()
     parts = re.split(r"\n  func\.func ", text)
     return text, {re.match(r"(?:public |private )?@(\w+)", p).group(1): p for p in parts[1:]}
 
@@ -184,20 +222,35 @@ _GRAM_DOT = r"dot_general[^\n]*-> tensor<8x8xf32>"
 _FACTOR_STACK = "tensor<4x8x8xf32>"
 
 
-def test_bcd_factors_in_a_factor_pass_ahead_of_the_epoch_scan(mesh):
-    text, functions = _lowered_bcd_functions(mesh, num_epochs=5)
+def _factor_pass_ahead_of_the_epoch_scan(text, functions):
+    """The factor pass and the epoch scan as two loops, the factor stack
+    between them: returns (epoch body, factor body)."""
     assert len(re.findall(r"stablehlo\.while", text)) == 2
     assert _FACTOR_STACK in text
     (epoch_body,) = [f for f in functions.values() if "call @_cho_solve" in f]
     (factor_body,) = [f for f in functions.values() if "call @_cholesky" in f]
-    assert epoch_body is not factor_body
-    assert len(re.findall(_GRAM_DOT, factor_body)) == 1
-    assert not re.search(_GRAM_DOT, epoch_body)
+    assert epoch_body is not factor_body and "call @_cholesky" not in epoch_body
     assert "dot_general" in epoch_body and _FACTOR_STACK in epoch_body
-    assert len(re.findall(_GRAM_DOT, text)) == 1  # one Gram site in the whole program
     # the factor pass runs once a block, the epoch scan epochs x blocks times
     assert re.search(r"constant dense<4> : tensor<i32>[^\n]*\n[^\n]*compare  LT", text)
     assert re.search(r"constant dense<20> : tensor<i32>[^\n]*\n[^\n]*compare  LT", text)
+    return epoch_body, factor_body
+
+
+def test_bcd_factors_in_a_factor_pass_ahead_of_the_epoch_scan(mesh):
+    text, functions = _lowered_bcd_functions(mesh, num_epochs=5)
+    epoch_body, factor_body = _factor_pass_ahead_of_the_epoch_scan(text, functions)
+    assert len(re.findall(_GRAM_DOT, factor_body)) == 1
+    assert not re.search(_GRAM_DOT, epoch_body)
+    assert len(re.findall(_GRAM_DOT, text)) == 1  # one Gram site in the whole program
+
+
+def test_bcd_from_gram_factors_in_a_factor_pass_ahead_of_the_epoch_scan(mesh):
+    """The statistics source takes the same factor rule: at 5 epochs its
+    4 blocks are factored once each, not 20 times."""
+    text, functions = _lowered_bcd_functions(mesh, num_epochs=5, source="statistics")
+    _factor_pass_ahead_of_the_epoch_scan(text, functions)
+    assert text.count("call @_cholesky") == 1
 
 
 def test_bcd_single_pass_is_one_loop_and_holds_no_factor_stack(mesh):
